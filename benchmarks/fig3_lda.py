@@ -464,4 +464,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     sys.exit(main())

@@ -236,4 +236,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     raise SystemExit(main())

@@ -82,10 +82,7 @@ def _tracing_active() -> bool:
     measure tracing (staged nested jits), not execution."""
     import jax
 
-    try:
-        return not jax.core.trace_state_clean()
-    except AttributeError:  # very old/new jax: assume eager
-        return False
+    return not jax.core.trace_ctx.is_top_level()
 
 
 def candidate_methods(
@@ -134,7 +131,9 @@ def measure_method(
     sparse: bool = False,
 ) -> Optional[float]:
     """Median wall-clock microseconds of one jitted (B, K) draw batch on
-    synthetic weights; ``None`` if the method fails on this shape.
+    synthetic weights; ``None`` if the method does not serve this kind of
+    workload.  A compile or run error propagates: a candidate the
+    compiler refuses is a fault to fix, not a slow candidate.
 
     ``factored=True`` times the workload the factored buckets describe:
     weights arrive as a theta-phi product, so flat-weight methods are
@@ -170,89 +169,86 @@ def measure_method(
         doc_ids = jnp.asarray(rng.integers(0, C, size=(B,)), jnp.int32)
         words = jnp.asarray(rng.integers(0, V, size=(B,)), jnp.int32)
 
-    try:
-        if method == "sparse_mh":
-            if not sparse:
-                return None
-            from repro.lda import sparse as _sparse
+    if method == "sparse_mh":
+        if not sparse:
+            return None
+        from repro.lda import sparse as _sparse
 
-            return _sparse.measure_sparse_mh(
-                B, K, iters=iters, warmup=warmup, seed=seed
-            )
-        if method == "kernel_trunc":
-            if not truncated:
-                return None
-            from repro.kernels.butterfly_sample import ops as _kops
+        return _sparse.measure_sparse_mh(
+            B, K, iters=iters, warmup=warmup, seed=seed
+        )
+    if method == "kernel_trunc":
+        if not truncated:
+            return None
+        from repro.kernels.butterfly_sample import ops as _kops
 
-            fn = jax.jit(
-                lambda w, uu: _kops.butterfly_sample_truncated(
-                    w, uu, kpm, W=W
-                )
+        fn = jax.jit(
+            lambda w, uu: _kops.butterfly_sample_truncated(
+                w, uu, kpm, W=W
             )
-            args = (w, u)
-        elif truncated and method not in KEY_METHODS and not factored:
-            from repro.sampling import transforms as _tr
+        )
+        args = (w, u)
+    elif truncated and method not in KEY_METHODS and not factored:
+        from repro.sampling import transforms as _tr
 
-            fn = jax.jit(
-                lambda w, uu: _api.sample_categorical(
-                    _tr.apply(w, trunc_chain), u=uu, method=method, W=W
-                )
+        fn = jax.jit(
+            lambda w, uu: _api.sample_categorical(
+                _tr.apply(w, trunc_chain), u=uu, method=method, W=W
             )
-            args = (w, u)
-        elif truncated and method in KEY_METHODS and not factored:
-            from repro.sampling import transforms as _tr
+        )
+        args = (w, u)
+    elif truncated and method in KEY_METHODS and not factored:
+        from repro.sampling import transforms as _tr
 
-            fn = jax.jit(
-                lambda w, k: _api.sample_categorical(
-                    _tr.apply(w, trunc_chain), key=k, method=method, W=W
-                )
+        fn = jax.jit(
+            lambda w, k: _api.sample_categorical(
+                _tr.apply(w, trunc_chain), key=k, method=method, W=W
             )
-            args = (w, key)
-        elif method in cost_model.FACTORED_METHODS:
-            if not factored:
-                return None
-            from repro.kernels.lda_draw import lda_draw_factored
+        )
+        args = (w, key)
+    elif method in cost_model.FACTORED_METHODS:
+        if not factored:
+            return None
+        from repro.kernels.lda_draw import lda_draw_factored
 
-            fn = jax.jit(
-                lambda th, ph, uu: lda_draw_factored(
-                    th, ph, doc_ids, words, uu, W=W
-                )
+        fn = jax.jit(
+            lambda th, ph, uu: lda_draw_factored(
+                th, ph, doc_ids, words, uu, W=W
             )
-            args = (theta, phi, u)
-        elif factored and method not in KEY_METHODS:
-            fn = jax.jit(
-                lambda th, ph, uu: _api.sample_categorical(
-                    th[doc_ids] * ph[words], u=uu, method=method, W=W
-                )
+        )
+        args = (theta, phi, u)
+    elif factored and method not in KEY_METHODS:
+        fn = jax.jit(
+            lambda th, ph, uu: _api.sample_categorical(
+                th[doc_ids] * ph[words], u=uu, method=method, W=W
             )
-            args = (theta, phi, u)
-        elif factored and method in KEY_METHODS:
-            fn = jax.jit(
-                lambda th, ph, k: _api.sample_categorical(
-                    th[doc_ids] * ph[words], key=k, method=method, W=W
-                )
+        )
+        args = (theta, phi, u)
+    elif factored and method in KEY_METHODS:
+        fn = jax.jit(
+            lambda th, ph, k: _api.sample_categorical(
+                th[doc_ids] * ph[words], key=k, method=method, W=W
             )
-            args = (theta, phi, key)
-        elif method in KEY_METHODS:
-            fn = jax.jit(
-                lambda w, k: _api.sample_categorical(w, key=k, method=method, W=W)
-            )
-            args = (w, key)
-        else:
-            fn = jax.jit(
-                lambda w, u: _api.sample_categorical(w, u=u, method=method, W=W)
-            )
-            args = (w, u)
-        for _ in range(max(warmup, 1)):
-            jax.block_until_ready(fn(*args))
-        times = []
-        for _ in range(max(iters, 1)):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn(*args))
-            times.append(time.perf_counter() - t0)
-        return float(np.median(times) * 1e6)
-    except Exception:
-        return None
+        )
+        args = (theta, phi, key)
+    elif method in KEY_METHODS:
+        fn = jax.jit(
+            lambda w, k: _api.sample_categorical(w, key=k, method=method, W=W)
+        )
+        args = (w, key)
+    else:
+        fn = jax.jit(
+            lambda w, u: _api.sample_categorical(w, u=u, method=method, W=W)
+        )
+        args = (w, u)
+    for _ in range(max(warmup, 1)):
+        jax.block_until_ready(fn(*args))
+    times = []
+    for _ in range(max(iters, 1)):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*args))
+        times.append(time.perf_counter() - t0)
+    return float(np.median(times) * 1e6)
 
 
 class Tuner:
@@ -394,7 +390,7 @@ class Tuner:
               factored=False, truncated=False, sparse=False):
         """Time every candidate at the bucket's representative shape (the
         blocked methods at a small W sweep around the model's guess); fall
-        back to the cost model if everything fails (e.g. OOM shapes)."""
+        back to the cost model if no candidate serves the workload."""
         import jax.numpy as jnp
 
         dtype = jnp.dtype(dtype_name)

@@ -21,18 +21,18 @@ merged order:
 The merged rank is two fixed-trip batched bisections (computed XLA-side,
 like the partition — no sort anywhere, see :mod:`ops`);
 this module's kernel is the tiled *assembly*: grid ``(Bp//tb,)``, each
-step loads a (tb, Kp) tile of pow2-padded scaled weights plus its rank
-rows and emits (prob, alias-position) with pure vector math — cumsum,
-masked reductions, and ONE gather expressed as pow2-bucketed one-hot
-lane blocks (the Mosaic-friendly form; no data-dependent loop anywhere).
+step loads a (tb, Kp) tile of pow2-padded scaled weights plus its rank,
+row-prefix and gathered light-prefix rows and emits (prob,
+alias-position) with elementwise math and masked reductions (no
+data-dependent loop anywhere).  The row prefix ``cs`` and the assembly's
+one per-row gather, ``PL(i)``, run XLA-side with the rank
+(:func:`_light_prefix`), so kernel and twin sum in the same order.
 
 ``_sweep_vals`` / ``_assemble`` are shared verbatim by the pure-XLA twin
 in :mod:`ops` — the two implementations cannot drift.
 """
 
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -41,49 +41,59 @@ import jax.experimental.pallas.tpu as pltpu
 
 from repro.kernels import runtime
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 # ---------------------------------------------------------------------------
 # Shared tile math (used by the Pallas kernel AND the XLA twin in ops.py)
 # ---------------------------------------------------------------------------
 
 
-def _sweep_vals(s_sorted: jnp.ndarray, nL: jnp.ndarray):
+def _sweep_vals(s_sorted: jnp.ndarray, nL: jnp.ndarray, cs: jnp.ndarray):
     """Per-position sweep quantities from lights-then-heavies scaled
-    weights: the position iota, light mask, inclusive prefix ``cs``, total
-    light weight ``csL``, light keys ``b`` and heavy keys ``A``."""
+    weights and their inclusive row prefix ``cs``: the position iota,
+    light mask, total light weight ``csL`` (B, 1), light keys ``b`` and
+    heavy keys ``A``."""
     B, Kp = s_sorted.shape
+    nLc = nL.reshape(B, 1)
     pos = jax.lax.broadcasted_iota(jnp.int32, (B, Kp), 1)
-    light = pos < nL[:, None]
-    cs = jnp.cumsum(s_sorted, axis=-1)
+    light = pos < nLc
     posf = pos.astype(jnp.float32)
-    csL = jnp.sum(jnp.where(light, s_sorted, 0.0), axis=-1)      # (B,)
+    csL = jnp.sum(jnp.where(light, s_sorted, 0.0), axis=-1, keepdims=True)
     b = posf - (cs - s_sorted) + 1.0
-    A = (cs - posf) + (nL.astype(jnp.float32) - csL)[:, None]
-    return pos, light, cs, csL, b, A
+    A = (cs - posf) + (nLc.astype(jnp.float32) - csL)
+    return pos, light, csL, b, A
 
 
-def _assemble(s_sorted, nL, rank, gather_rows):
-    """Closed-form table assembly from the partitioned order and the
-    merged sweep rank.  Returns ``(prob, apos)`` in sorted position space
-    (``apos`` = alias *position*; the caller maps positions back to
-    original category ids and clamps pad overflow).
+def _drained(pos, nL, rank):
+    """Heavies: (j, i) = (heavy ordinal, lights drained when it empties)."""
+    nLc = nL.reshape(-1, 1)
+    j = pos - nLc
+    return j, jnp.clip(rank - j, 0, nLc)
 
-    ``gather_rows(vals, idx)`` is the one per-row gather the heavy
-    residual needs (``PL(i) = cs[i-1]``): ``jnp.take_along_axis`` in the
-    XLA twin, pow2-bucketed one-hot lane blocks inside the kernel."""
+
+def _light_prefix(cs, nL, rank):
+    """``PL(i) = cs[i-1]`` at every heavy position's drained-light count
+    ``i`` — the one per-row gather of the assembly, done XLA-side
+    (``take_along_axis``) for the kernel and the twin alike."""
+    pos = jax.lax.broadcasted_iota(jnp.int32, cs.shape, 1)
+    _j, i = _drained(pos, nL, rank)
+    PLi = jnp.take_along_axis(cs, jnp.maximum(i - 1, 0), axis=-1)
+    return jnp.where(i > 0, PLi, 0.0)
+
+
+def _assemble(s_sorted, nL, rank, cs, PLi):
+    """Closed-form table assembly from the partitioned order, its row
+    prefix ``cs``, the merged sweep rank and the gathered light prefixes
+    ``PLi``.  Returns ``(prob, apos)`` in sorted position space (``apos``
+    = alias *position*; the caller maps positions back to original
+    category ids and clamps pad overflow)."""
     B, Kp = s_sorted.shape
-    pos, light, cs, csL, b, A = _sweep_vals(s_sorted, nL)
-    nLcol = nL[:, None]
+    pos, light, csL, b, A = _sweep_vals(s_sorted, nL, cs)
+    nLc = nL.reshape(B, 1)
     # lights: the serving heavy is the first with A > b — rank arithmetic
-    q = jnp.minimum(nLcol + (rank - pos), Kp - 1)
+    q = jnp.minimum(nLc + (rank - pos), Kp - 1)
     # heavies: lights drained when heavy j empties, then conservation
-    j = pos - nLcol
-    i = jnp.clip(rank - j, 0, nLcol)
-    PLi = jnp.where(i > 0, gather_rows(cs, jnp.maximum(i - 1, 0)), 0.0)
-    r = PLi + (cs - csL[:, None]) - (i + j).astype(jnp.float32)
+    j, i = _drained(pos, nL, rank)
+    r = PLi + (cs - csL) - (i + j).astype(jnp.float32)
     prob = jnp.where(
         light, jnp.minimum(s_sorted, 1.0), jnp.clip(r, 0.0, 1.0)
     )
@@ -96,28 +106,12 @@ def _assemble(s_sorted, nL, rank, gather_rows):
 # ---------------------------------------------------------------------------
 
 
-def _gather_rows_blocked(vals: jnp.ndarray, idx: jnp.ndarray, blk: int):
-    """``out[r, p] = vals[r, idx[r, p]]`` without dynamic indexing: the
-    lane axis is swept in pow2 buckets of width ``blk``, each contributing
-    a one-hot masked reduction — the same Mosaic-friendly gather idiom as
-    the butterfly kernels' ``_descent_tile``, bucketed so the (TB, Kp,
-    blk) mask tensor stays VMEM-sized."""
-    TB, Kp = vals.shape
-    acc = jnp.zeros((TB, Kp), jnp.float32)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, blk), 2)
-    for c in range(Kp // blk):
-        chunk = jax.lax.dynamic_slice_in_dim(vals, c * blk, blk, axis=1)
-        m = (c * blk + lane) == idx[:, :, None]                  # (TB, Kp, blk)
-        acc = acc + jnp.sum(jnp.where(m, chunk[:, None, :], 0.0), axis=2)
-    return acc
-
-
-def _assemble_kernel(s_ref, nl_ref, rank_ref, prob_ref, apos_ref, *, blk: int):
-    s = s_ref[...].astype(jnp.float32)                           # (TB, Kp)
-    nL = nl_ref[:, 0]
-    rank = rank_ref[...]
+def _assemble_kernel(
+    s_ref, nl_ref, rank_ref, cs_ref, pli_ref, prob_ref, apos_ref
+):
     prob, apos = _assemble(
-        s, nL, rank, functools.partial(_gather_rows_blocked, blk=blk)
+        s_ref[...].astype(jnp.float32), nl_ref[...], rank_ref[...],
+        cs_ref[...], pli_ref[...],
     )
     prob_ref[...] = prob
     apos_ref[...] = apos
@@ -127,32 +121,28 @@ def alias_assemble_pallas(
     s_sorted: jnp.ndarray,
     nL: jnp.ndarray,
     rank: jnp.ndarray,
+    cs: jnp.ndarray,
+    PLi: jnp.ndarray,
     tb: int = 8,
     interpret: bool | None = None,
 ):
     """Tiled table assembly: (Bp, Kp) partitioned scaled weights (Kp a
-    pow2), per-row light counts and merged ranks -> (prob, apos), both
-    (Bp, Kp).  ONE ``pallas_call``, grid ``(Bp//tb,)``."""
+    pow2), per-row light counts, merged ranks, row prefixes and gathered
+    light prefixes -> (prob, apos), both (Bp, Kp).  ONE ``pallas_call``, grid
+    ``(Bp//tb,)``."""
     interpret = runtime.resolve_interpret(interpret)
     Bp, Kp = s_sorted.shape
-    blk = min(128, Kp)
+    tile = pl.BlockSpec((tb, Kp), lambda i: (i, 0))
     prob, apos = pl.pallas_call(
-        functools.partial(_assemble_kernel, blk=blk),
+        _assemble_kernel,
         grid=(Bp // tb,),
-        in_specs=[
-            pl.BlockSpec((tb, Kp), lambda i: (i, 0)),
-            pl.BlockSpec((tb, 1), lambda i: (i, 0)),
-            pl.BlockSpec((tb, Kp), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tb, Kp), lambda i: (i, 0)),
-            pl.BlockSpec((tb, Kp), lambda i: (i, 0)),
-        ],
+        in_specs=[tile, pl.BlockSpec((tb, 1), lambda i: (i, 0)), tile, tile, tile],
+        out_specs=[tile, tile],
         out_shape=[
             jax.ShapeDtypeStruct((Bp, Kp), jnp.float32),
             jax.ShapeDtypeStruct((Bp, Kp), jnp.int32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
@@ -160,5 +150,7 @@ def alias_assemble_pallas(
         s_sorted.astype(jnp.float32),
         nL.astype(jnp.int32)[:, None],
         rank.astype(jnp.int32),
+        cs.astype(jnp.float32),
+        PLi.astype(jnp.float32),
     )
     return prob, apos

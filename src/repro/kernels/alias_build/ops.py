@@ -37,7 +37,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import runtime
-from repro.kernels.alias_build.kernel import _assemble, alias_assemble_pallas
+from repro.kernels.alias_build.kernel import (
+    _assemble,
+    _light_prefix,
+    alias_assemble_pallas,
+)
 
 
 def _resolve_impl(impl: Optional[str]) -> str:
@@ -90,7 +94,9 @@ def _partition(weights: jnp.ndarray):
     return s_sorted, order, inv, nL
 
 
-def _merged_rank(s_sorted: jnp.ndarray, nL: jnp.ndarray) -> jnp.ndarray:
+def _merged_rank(
+    s_sorted: jnp.ndarray, nL: jnp.ndarray, cs: jnp.ndarray
+) -> jnp.ndarray:
     """Each position's rank in the merged sweep order of the light keys
     ``b`` and heavy keys ``A`` (ties: A before b, then position — the
     order the sequential pack sweep visits them in).
@@ -108,7 +114,7 @@ def _merged_rank(s_sorted: jnp.ndarray, nL: jnp.ndarray) -> jnp.ndarray:
     from repro.kernels.alias_build.kernel import _sweep_vals
 
     B, Kp = s_sorted.shape
-    pos, light, _cs, _csL, b, A = _sweep_vals(s_sorted, nL)
+    pos, light, _csL, b, A = _sweep_vals(s_sorted, nL, cs)
     nLcol = nL[:, None]
     A_asc = jnp.where(light, -jnp.inf, A)    # -inf prefix, then rising A
     b_asc = jnp.where(light, b, jnp.inf)     # rising b, then +inf tail
@@ -127,10 +133,6 @@ def _merged_rank(s_sorted: jnp.ndarray, nL: jnp.ndarray) -> jnp.ndarray:
     cnt = lo - base
     rank = jnp.where(light, pos + (cnt - nLcol), (pos - nLcol) + cnt)
     return rank.astype(jnp.int32)
-
-
-def _gather_rows_xla(vals: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
-    return jnp.take_along_axis(vals, idx, axis=-1)
 
 
 @functools.partial(jax.jit, static_argnames=("tb", "impl", "interpret"))
@@ -160,7 +162,7 @@ def build_alias_tables_device(
 
     if _resolve_impl(impl) == "pallas":
         Kp = _next_pow2(K)
-        padB = (-B) % tb
+        padB = (-B) % runtime.row_tile(tb)
         # pad with s = 1 pseudo-heavies: A stays constant on the pad tail
         # (ties resolve after every real entry), so real ranks are
         # untouched and pad outputs are sliced away below
@@ -168,14 +170,19 @@ def build_alias_tables_device(
             s_sorted, ((0, padB), (0, Kp - K)), constant_values=1.0
         )
         nLp = jnp.pad(nL, (0, padB), constant_values=Kp)
-        rank = _merged_rank(sp, nLp)
+        cs = jnp.cumsum(sp, axis=-1)
+        rank = _merged_rank(sp, nLp, cs)
         prob_s, apos = alias_assemble_pallas(
-            sp, nLp, rank, tb=tb, interpret=interpret
+            sp, nLp, rank, cs, _light_prefix(cs, nLp, rank),
+            tb=runtime.row_tile(tb), interpret=interpret,
         )
         prob_s, apos = prob_s[:B, :K], apos[:B, :K]
     else:
-        rank = _merged_rank(s_sorted, nL)
-        prob_s, apos = _assemble(s_sorted, nL, rank, _gather_rows_xla)
+        cs = jnp.cumsum(s_sorted, axis=-1)
+        rank = _merged_rank(s_sorted, nL, cs)
+        prob_s, apos = _assemble(
+            s_sorted, nL, rank, cs, _light_prefix(cs, nL, rank)
+        )
 
     # position space -> original category ids, undoing the partition
     apos = jnp.minimum(apos, K - 1)

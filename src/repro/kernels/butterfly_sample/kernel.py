@@ -8,43 +8,43 @@ is (DESIGN.md §2):
   pass A  (``_blocksum_kernel``)  streams (tb, tk) weight tiles through
           VMEM and emits only the per-W-block sums — HBM: read B*K,
           write B*K/W.
-  pass B  (``_walk_kernel``)      re-reads *only the selected W-block* per
-          sample (scalar-prefetch drives the BlockSpec index_map — the
-          Pallas analogue of the data-dependent fetch the GPU warp does),
-          builds the dyadic segment table in registers (the TPU-adapted
-          butterfly; Fenwick layout) and walks it add-only, log2(W) steps
-          — HBM: read B*W.
+  pass B  (``_walk_kernel``)      re-reads *only the window holding the
+          selected W-block* per sample (scalar-prefetch drives the
+          BlockSpec index_map — the Pallas analogue of the data-dependent
+          fetch the GPU warp does), builds the dyadic segment table
+          (the TPU-adapted butterfly; Fenwick layout) and walks it
+          add-only, log2(W) steps.
 
-Total HBM traffic ~ B*K*(1 + 1/W) + B*W versus >= 3*B*K for the classic
-prefix-table route (write prefix, re-read during search with scattered
-gathers).  That x2-3 traffic reduction is the TPU translation of the
-paper's >2x speedup for K >= 200.
+Total HBM traffic ~ B*K*(1 + 1/W) + one (8, 128) window per sample versus
+>= 3*B*K for the classic prefix-table route (write prefix, re-read during
+search with scattered gathers).
 
 Tiled-grid layout (DESIGN.md §3).  Both draw-side kernels run a *tiled*
 grid rather than one grid step per sample:
 
   * ``_fused_draw_kernel`` is the one-``pallas_call`` end-to-end draw:
     grid ``(B//tb,)``, each step loads a (tb, Kp) weight tile, reduces it
-    to block sums, selects each row's W-block and walks the in-register
-    dyadic table — block selection (the running-sum/searchsorted step
-    that used to round-trip through XLA between pass A and pass B) is
-    folded into the kernel, and the whole (tb, W) tile walks its log2(W)
-    levels in lock-step on the VPU.
+    to block sums, selects each row's W-block and walks the dyadic table
+    — block selection is folded into the kernel, and the whole (tb, W)
+    tile walks its log2(W) levels in lock-step on the VPU.
   * ``_walk_kernel`` is the table-in pass B for prebuilt ``(wp, running)``
     state: grid ``(B//tb, tb)``; the inner grid dimension streams one
-    scalar-prefetch-selected W-block per sample into a (tb, W) VMEM
-    accumulator (per-row DMA is unavoidable for scattered blocks — this
-    is the coalescing the paper's warp does — but Pallas double-buffers
-    it), and the last inner step runs the vectorized selection + walk for
-    the whole tile.  Only the block *address* ``jb`` is computed outside
-    (the DMA engine needs it before the kernel body runs); stop/lo and
-    the selection arithmetic are recomputed in-kernel from the fetched
-    running-sum rows, bit-identically.
+    scalar-prefetch-selected window per sample into a (tb, window) VMEM
+    accumulator, and the last inner step runs the vectorized selection +
+    walk for the whole tile.  Only the block *address* ``jb`` is computed
+    outside (the DMA engine needs it before the kernel body runs);
+    stop/lo and the selection arithmetic are recomputed in-kernel from
+    the fetched running-sum rows.
 
-All dynamic per-row indexing inside the kernels is expressed as one-hot
-masked reductions over a ``broadcasted_iota`` — the Mosaic-friendly form
-of a gather — so the same kernel body compiles natively on TPU and runs
-under interpret mode elsewhere.
+TPU tiling.  A block's last two dimensions must be multiples of (8, 128)
+or span the array, so per-sample fetches move the aligned (8, window)
+group that holds the sample's row and pick the row in-kernel
+(``_pick_row``).  Mosaic lowers neither ``cumsum`` nor a lane-splitting
+reshape, so block sums, running sums, block extraction and the Fenwick
+build are products with small 0/1 matrices on the MXU at full f32
+precision (``_dot``); every per-row dynamic index is a one-hot masked
+reduction over a ``broadcasted_iota``.  The same kernel bodies run
+compiled on TPU and under interpret mode elsewhere.
 """
 
 from __future__ import annotations
@@ -60,39 +60,94 @@ import jax.experimental.pallas.tpu as pltpu
 from repro.kernels import rng as _rng
 from repro.kernels import runtime
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 # ---------------------------------------------------------------------------
 # Shared tile math: vectorized (TB, W) selection + dyadic walk
 # ---------------------------------------------------------------------------
 
 
+def _iota(shape, dim: int) -> jnp.ndarray:
+    return jax.lax.broadcasted_iota(jnp.int32, shape, dim)
+
+
+def _dot(a, b) -> jnp.ndarray:
+    """f32 product at full precision: with a 0/1 right operand every
+    output is an exact-product f32 sum (the MXU's default single bf16
+    pass would round the weights)."""
+    return jnp.dot(
+        a, b, precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _block_sums(w: jnp.ndarray, W: int) -> jnp.ndarray:
+    """(TB, L) -> (TB, L // W) per-W-block sums (one indicator product)."""
+    L = w.shape[1]
+    shape = (L, L // W)
+    ind = (_iota(shape, 0) // W == _iota(shape, 1)).astype(jnp.float32)
+    return _dot(w, ind)
+
+
+def _row_cumsum(x: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive prefix sums along the lanes of a (TB, n) tile: products
+    with an upper-triangular 0/1 matrix, 128 lanes at a time with a
+    carried total once n is a multiple of 128."""
+    TB, n = x.shape
+    c = 128 if n % 128 == 0 else n
+    tri = (_iota((c, c), 0) <= _iota((c, c), 1)).astype(jnp.float32)
+    if c == n:
+        return _dot(x, tri)
+    outs, carry = [], jnp.zeros((TB, 1), jnp.float32)
+    for k in range(n // c):
+        pre = _dot(x[:, k * c:(k + 1) * c], tri) + carry
+        outs.append(pre)
+        carry = pre[:, c - 1:c]
+    return jnp.concatenate(outs, axis=1)
+
+
+def _pick_row(group: jnp.ndarray, r) -> jnp.ndarray:
+    """Row ``r`` of an (8, L) row group as a (1, L) tile."""
+    sub = _iota((group.shape[0], 1), 0) == r
+    return jnp.sum(jnp.where(sub, group, 0.0), axis=0, keepdims=True)
+
+
+def _set_row(acc_ref, r, row) -> None:
+    """Write a (1, L) row into row ``r`` of a (TB, L) VMEM accumulator."""
+    sub = _iota((acc_ref.shape[0], 1), 0) == r
+    acc_ref[...] = jnp.where(sub, row, acc_ref[...])
+
+
+def _extract_block(w: jnp.ndarray, jb: jnp.ndarray, W: int) -> jnp.ndarray:
+    """(TB, L) tile, (TB, 1) block ids -> (TB, W): each row's W-block."""
+    L = w.shape[1]
+    wm = jnp.where(_iota(w.shape, 1) // W == jb, w, 0.0)
+    pick = (_iota((L, W), 0) % W == _iota((L, W), 1)).astype(jnp.float32)
+    return _dot(wm, pick)
+
+
 def _fenwick_tile(t: jnp.ndarray, W: int) -> jnp.ndarray:
-    """Blelloch up-sweep over every W-segment of a (TB, W) tile: position d
-    with ntz(d+1)=l accumulates S[d-2^l+1..d] (Fenwick layout)."""
-    TB = t.shape[0]
-    for b in range(int(np.log2(W))):
-        bit = 1 << b
-        t2 = t.reshape(TB, W // (2 * bit), 2 * bit)
-        t2 = t2.at[:, :, 2 * bit - 1].add(t2[:, :, bit - 1])
-        t = t2.reshape(TB, W)
-    return t
+    """Fenwick layout of every row of a (TB, W) tile: position d with
+    ntz(d+1)=l holds S[d-2^l+1..d] — one product with the 0/1 matrix
+    whose column d covers that range."""
+    i, d = _iota((W, W), 0), _iota((W, W), 1)
+    low = (d + 1) & -(d + 1)
+    fen = ((i <= d) & (i > d - low)).astype(jnp.float32)
+    return _dot(t, fen)
 
 
 def _descent_tile(t, stop, lo, W: int):
     """Vectorized add-only descent (Alg. 10, TPU-adapted): every row of the
     (TB, W) Fenwick tile walks its log2(W) levels in lock-step; the
-    per-row dynamic read is a one-hot masked lane reduction."""
-    TB = t.shape[0]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (TB, W), 1)
+    per-row dynamic read is a one-hot masked lane reduction.  ``stop``
+    and ``lo`` are (TB, 1); returns (TB, 1) in-block offsets."""
+    lane = _iota(t.shape, 1)
     acc = lo
-    R = jnp.zeros((TB,), jnp.int32)
+    R = jnp.zeros(stop.shape, jnp.int32)
     for b in range(int(np.log2(W)) - 1, -1, -1):
         bit = 1 << b
-        pos = R + (bit - 1)
-        y = jnp.sum(jnp.where(lane == pos[:, None], t, 0.0), axis=1)
+        y = jnp.sum(
+            jnp.where(lane == R + (bit - 1), t, 0.0), axis=1, keepdims=True
+        )
         mid = acc + y
         go_high = stop >= mid
         acc = jnp.where(go_high, mid, acc)
@@ -100,36 +155,48 @@ def _descent_tile(t, stop, lo, W: int):
     return R
 
 
-def _select_tile(running, stop, W: int):
+def _select_tile(running, stop):
     """In-kernel block-level search (the paper's Alg. 9): smallest block c
     with stop < running[c], plus the exclusive prefix ``lo`` below it.
-    ``running``: (TB, nb) running block sums; ``stop``: (TB,)."""
-    TB, nb = running.shape
+    ``running``: (TB, nb) running block sums; ``stop``: (TB, 1)."""
+    nb = running.shape[1]
     jb = jnp.clip(
-        jnp.sum((running <= stop[:, None]).astype(jnp.int32), axis=1), 0, nb - 1
+        jnp.sum((running <= stop).astype(jnp.int32), axis=1, keepdims=True),
+        0, nb - 1,
     )
-    bidx = jax.lax.broadcasted_iota(jnp.int32, (TB, nb), 1)
-    lo = jnp.sum(jnp.where(bidx == jb[:, None] - 1, running, 0.0), axis=1)
+    lo = jnp.sum(
+        jnp.where(_iota(running.shape, 1) == jb - 1, running, 0.0),
+        axis=1, keepdims=True,
+    )
     return jb, lo
+
+
+def _walk_block(running, stop, blk, W: int):
+    """Selection + walk for a tile whose rows hold the window around their
+    selected block: ``blk`` is (TB, window) with the block at lanes
+    ``(jb * W) % window``.  Returns (TB, 1) indices into [0, Kp)."""
+    jb, lo = _select_tile(running, stop)
+    sel = _extract_block(blk, (jb * W) % blk.shape[1] // W, W)
+    R = _descent_tile(_fenwick_tile(sel, W), stop, lo, W)
+    return jb * W + R
 
 
 def _draw_tile(w, u, W: int):
     """The complete fused draw for one (TB, Kp) tile already in VMEM:
     block sums -> running sums -> block selection -> Fenwick build ->
-    add-only descent.  Returns (TB,) int32 indices into [0, Kp)."""
-    TB, Kp = w.shape
-    nb = Kp // W
-    blocks = w.reshape(TB, nb, W)
-    running = jnp.cumsum(blocks.sum(axis=-1), axis=-1)          # (TB, nb)
-    stop = running[:, -1] * u
-    jb, lo = _select_tile(running, stop, W)
-    bidx = jax.lax.broadcasted_iota(jnp.int32, (TB, nb), 1)
-    sel = jnp.sum(
-        jnp.where((bidx == jb[:, None])[:, :, None], blocks, 0.0), axis=1
-    )                                                            # (TB, W)
-    t = _fenwick_tile(sel, W)
-    R = _descent_tile(t, stop, lo, W)
-    return jb * W + R
+    add-only descent.  ``u`` is (TB, 1); returns (TB, 1) int32 indices
+    into [0, Kp)."""
+    running = _row_cumsum(_block_sums(w, W))                     # (TB, nb)
+    nb = running.shape[1]
+    return _walk_block(running, running[:, nb - 1:nb] * u, w, W)
+
+
+def _window(W: int, Kp: int) -> int:
+    """Lane width of the aligned window pass B fetches around a W-block:
+    128 lanes (or W when wider) when rows are 128-aligned, else the row."""
+    if Kp % 128:
+        return Kp
+    return max(W, 128)
 
 
 # ---------------------------------------------------------------------------
@@ -137,10 +204,26 @@ def _draw_tile(w, u, W: int):
 # ---------------------------------------------------------------------------
 
 
+def _place(bs: jnp.ndarray, off, n: int) -> jnp.ndarray:
+    """(TB, m) -> (TB, n) with ``bs`` at lanes [off, off + m), zeros
+    elsewhere (one 0/1 product: no dynamic lane store needed)."""
+    m = bs.shape[1]
+    put = (_iota((m, n), 0) + off == _iota((m, n), 1)).astype(jnp.float32)
+    return _dot(bs, put)
+
+
 def _blocksum_kernel(w_ref, out_ref, *, W: int):
-    w = w_ref[...].astype(jnp.float32)
-    tb, tk = w.shape
-    out_ref[...] = w.reshape(tb, tk // W, W).sum(axis=-1)
+    # the (tb, nb) output block stays resident across the K axis; each
+    # (tb, tk) tile adds its block sums at its own lane offset
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    tk = w_ref.shape[1]
+    bs = _block_sums(w_ref[...].astype(jnp.float32), W)
+    out_ref[...] += _place(bs, j * (tk // W), out_ref.shape[1])
 
 
 def blocksums_pallas(
@@ -149,15 +232,15 @@ def blocksums_pallas(
     """(B, K) -> (B, K//W) per-block sums; B % tb == 0, K % tk == 0, tk % W == 0."""
     interpret = runtime.resolve_interpret(interpret)
     B, K = weights.shape
-    grid = (B // tb, K // tk)
+    nb = K // W
     return pl.pallas_call(
         functools.partial(_blocksum_kernel, W=W),
-        grid=grid,
+        grid=(B // tb, K // tk),
         in_specs=[pl.BlockSpec((tb, tk), lambda i, j: (i, j))],
-        out_specs=pl.BlockSpec((tb, tk // W), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((B, K // W), jnp.float32),
-        compiler_params=_COMPILER_PARAMS(
-            dimension_semantics=("parallel", "parallel"),
+        out_specs=pl.BlockSpec((tb, nb), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, nb), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(weights)
@@ -170,20 +253,34 @@ def blocksums_pallas(
 # VMEM budget for the fused draw's (tb, Kp) weight tile (fp32 bytes).
 # Beyond it the row tile shrinks, and past tb=8 the draw falls back to the
 # two-pass route, whose pass A streams (tb, tk) tiles and whose pass B
-# touches (1, W) blocks — safe at any K (vocab-scale included).
+# touches one aligned window per sample — safe at any K (vocab-scale
+# included).
 _FUSED_TILE_BYTES = 4 << 20
+# ... and for the 0/1 matrices the fused tile math builds in VMEM
+# (block indicator (Kp, Kp/W) and block extractor (Kp, W)).  The v5e
+# compiler accepts the fused kernels at every (W, Kp) on this budget's
+# edge (tests/test_tpu_compile.py), and it keeps every K <= 4096 fused.
+_FUSED_MATRIX_BYTES = 16 << 20
 
 
 def _fused_tb(tb: int, Kp: int) -> int:
+    tb = runtime.row_tile(tb)
     while tb > 8 and tb * Kp * 4 > _FUSED_TILE_BYTES:
         tb //= 2
     return tb
 
 
+def _fused_fits(tb: int, Kp: int, W: int) -> bool:
+    """Whether the one-kernel route fits VMEM for a (tb, Kp) tile."""
+    return (
+        tb * Kp * 4 <= _FUSED_TILE_BYTES
+        and Kp * (Kp // W + W) * 4 <= _FUSED_MATRIX_BYTES
+    )
+
+
 def _fused_draw_kernel(w_ref, u_ref, out_ref, *, W: int):
     w = w_ref[...].astype(jnp.float32)                 # (TB, Kp)
-    idx = _draw_tile(w, u_ref[:, 0].astype(jnp.float32), W)
-    out_ref[:, 0] = idx
+    out_ref[...] = _draw_tile(w, u_ref[...].astype(jnp.float32), W)
 
 
 def fused_draw_pallas(
@@ -203,7 +300,7 @@ def fused_draw_pallas(
         ],
         out_specs=pl.BlockSpec((tb, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Bp, 1), jnp.int32),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
@@ -214,6 +311,16 @@ def fused_draw_pallas(
 # ---------------------------------------------------------------------------
 # Fused draw with IN-KERNEL counter RNG: the (B,) uniform operand is gone
 # ---------------------------------------------------------------------------
+
+
+def _tile_uniforms(meta_ref, tb: int) -> jnp.ndarray:
+    """(tb, 1) Threefry uniforms for this grid step's global rows; the
+    (1, 3) SMEM ``meta`` block is [s0, s1, row_offset]."""
+    i = pl.program_id(0)
+    s0, s1, off = meta_ref[0, 0], meta_ref[0, 1], meta_ref[0, 2]
+    rows = off + (i * tb + _iota((tb, 1), 0)).astype(jnp.uint32)
+    b0, _ = _rng.threefry2x32(s0, s1, rows, jnp.zeros_like(rows))
+    return _rng.bits_to_uniform(b0)
 
 
 def _fused_draw_rng_kernel(meta_ref, w_ref, out_ref, *, W: int, tb: int, hw: bool):
@@ -227,19 +334,25 @@ def _fused_draw_rng_kernel(meta_ref, w_ref, out_ref, *, W: int, tb: int, hw: boo
     the default is the portable Threefry twin — ~40 vector uint32 ops,
     bit-identical to the XLA-side generator.
     """
-    i = pl.program_id(0)
-    s0, s1, off = meta_ref[0, 0], meta_ref[0, 1], meta_ref[0, 2]
-    tile0 = off + jnp.uint32(i * tb)
     if hw:
-        pltpu.prng_seed(s0, s1, tile0)
-        bits = pltpu.prng_random_bits((tb,))
+        i = pl.program_id(0)
+        s0, s1, off = meta_ref[0, 0], meta_ref[0, 1], meta_ref[0, 2]
+        pltpu.prng_seed(s0, s1, off + jnp.uint32(i * tb))
+        bits = pltpu.prng_random_bits((tb, 1))
         u = _rng.bits_to_uniform(pltpu.bitcast(bits, jnp.uint32))
     else:
-        rows = tile0 + jax.lax.broadcasted_iota(jnp.uint32, (tb, 1), 0)[:, 0]
-        b0, _ = _rng.threefry2x32(s0, s1, rows, jnp.zeros_like(rows))
-        u = _rng.bits_to_uniform(b0)
+        u = _tile_uniforms(meta_ref, tb)
     w = w_ref[...].astype(jnp.float32)
-    out_ref[:, 0] = _draw_tile(w, u, W)
+    out_ref[...] = _draw_tile(w, u, W)
+
+
+def _meta(seed, row_offset) -> jnp.ndarray:
+    return jnp.concatenate(
+        [
+            jnp.asarray(seed, jnp.uint32).reshape(2),
+            jnp.asarray(row_offset).astype(jnp.uint32).reshape(1),
+        ]
+    ).reshape(1, 3)
 
 
 def fused_draw_rng_pallas(
@@ -256,26 +369,20 @@ def fused_draw_rng_pallas(
     ``row_offset`` the first row's global id (traced scalar is fine)."""
     interpret = runtime.resolve_interpret(interpret)
     Bp, Kp = wp.shape
-    meta = jnp.concatenate(
-        [
-            jnp.asarray(seed, jnp.uint32).reshape(2),
-            jnp.asarray(row_offset).astype(jnp.uint32).reshape(1),
-        ]
-    ).reshape(1, 3)
     out = pl.pallas_call(
         functools.partial(_fused_draw_rng_kernel, W=W, tb=tb, hw=hw),
         grid=(Bp // tb,),
         in_specs=[
-            pl.BlockSpec((1, 3), lambda i: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((tb, Kp), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((tb, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Bp, 1), jnp.int32),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
-    )(meta, wp)
+    )(_meta(seed, row_offset), wp)
     return out[:, 0]
 
 
@@ -306,7 +413,7 @@ def butterfly_sample_rng_pallas(
     padK = (-K) % W
     Kp = K + padK
     tb = _fused_tb(tb, Kp)
-    if tb * Kp * 4 > _FUSED_TILE_BYTES:
+    if not _fused_fits(tb, Kp, W):
         if hw:
             # the two-pass route derives u XLA-side (the block search needs
             # it before the DMA addresses exist) — hardware bits can't be
@@ -374,7 +481,7 @@ def _trunc_tile(w, params, iters: int) -> jnp.ndarray:
     min_p <= 0) pass through; returns the masked tile.
 
     The threshold math is :func:`repro.sampling.transforms
-    .thresholds_from_params` itself — pure jnp reductions plus a
+    .threshold_column` itself — pure jnp reductions plus a
     ``fori_loop`` bisection over uint32 float bit patterns, which traces
     inside the Pallas kernel body exactly as it does in XLA.  One
     implementation means the fused mask can never drift from the twin
@@ -382,14 +489,13 @@ def _trunc_tile(w, params, iters: int) -> jnp.ndarray:
     place."""
     from repro.sampling import transforms as _tr
 
-    tau = _tr.thresholds_from_params(w, params, iters=iters)
-    return jnp.where(w >= tau[:, None], w, 0.0)
+    return jnp.where(w >= _tr.threshold_column(w, params, iters), w, 0.0)
 
 
 def _fused_trunc_draw_kernel(w_ref, u_ref, prm_ref, out_ref, *, W: int, iters: int):
     w = w_ref[...].astype(jnp.float32)
     wm = _trunc_tile(w, prm_ref[...].astype(jnp.float32), iters)
-    out_ref[:, 0] = _draw_tile(wm, u_ref[:, 0].astype(jnp.float32), W)
+    out_ref[...] = _draw_tile(wm, u_ref[...].astype(jnp.float32), W)
 
 
 def fused_trunc_draw_pallas(
@@ -417,7 +523,7 @@ def fused_trunc_draw_pallas(
         ],
         out_specs=pl.BlockSpec((tb, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Bp, 1), jnp.int32),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
@@ -431,15 +537,10 @@ def _fused_trunc_draw_rng_kernel(
     """Truncated fused draw with in-kernel counter RNG (the sharded/serve
     fast path): uniforms from (seed, global row) Threefry counters, then
     the same in-VMEM threshold + draw pipeline."""
-    i = pl.program_id(0)
-    s0, s1, off = meta_ref[0, 0], meta_ref[0, 1], meta_ref[0, 2]
-    tile0 = off + jnp.uint32(i * tb)
-    rows = tile0 + jax.lax.broadcasted_iota(jnp.uint32, (tb, 1), 0)[:, 0]
-    b0, _ = _rng.threefry2x32(s0, s1, rows, jnp.zeros_like(rows))
-    u = _rng.bits_to_uniform(b0)
+    u = _tile_uniforms(meta_ref, tb)
     w = w_ref[...].astype(jnp.float32)
     wm = _trunc_tile(w, prm_ref[...].astype(jnp.float32), iters)
-    out_ref[:, 0] = _draw_tile(wm, u, W)
+    out_ref[...] = _draw_tile(wm, u, W)
 
 
 def fused_trunc_draw_rng_pallas(
@@ -454,27 +555,21 @@ def fused_trunc_draw_rng_pallas(
 ) -> jnp.ndarray:
     interpret = runtime.resolve_interpret(interpret)
     Bp, Kp = wp.shape
-    meta = jnp.concatenate(
-        [
-            jnp.asarray(seed, jnp.uint32).reshape(2),
-            jnp.asarray(row_offset).astype(jnp.uint32).reshape(1),
-        ]
-    ).reshape(1, 3)
     out = pl.pallas_call(
         functools.partial(_fused_trunc_draw_rng_kernel, W=W, tb=tb, iters=iters),
         grid=(Bp // tb,),
         in_specs=[
-            pl.BlockSpec((1, 3), lambda i: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((tb, 3), lambda i: (i, 0)),
             pl.BlockSpec((tb, Kp), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((tb, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Bp, 1), jnp.int32),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,
-    )(meta, params, wp)
+    )(_meta(seed, row_offset), params, wp)
     return out[:, 0]
 
 
@@ -485,11 +580,16 @@ def _masked_blocksum_kernel(w_ref, tau_ref, out_ref, *, W: int):
     """Pass A over *masked* weights: the truncation mask is applied to the
     streamed (tb, tk) tile in VMEM — the masked (B, K) matrix never hits
     HBM."""
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
     w = w_ref[...].astype(jnp.float32)
-    tau = tau_ref[:, 0].astype(jnp.float32)
-    wm = jnp.where(w >= tau[:, None], w, 0.0)
-    tb, tk = w.shape
-    out_ref[...] = wm.reshape(tb, tk // W, W).sum(axis=-1)
+    wm = jnp.where(w >= tau_ref[...].astype(jnp.float32), w, 0.0)
+    tk = w.shape[1]
+    out_ref[...] += _place(_block_sums(wm, W), j * (tk // W), out_ref.shape[1])
 
 
 def masked_blocksums_pallas(
@@ -502,46 +602,127 @@ def masked_blocksums_pallas(
 ) -> jnp.ndarray:
     interpret = runtime.resolve_interpret(interpret)
     B, K = weights.shape
-    grid = (B // tb, K // tk)
+    nb = K // W
     return pl.pallas_call(
         functools.partial(_masked_blocksum_kernel, W=W),
-        grid=grid,
+        grid=(B // tb, K // tk),
         in_specs=[
             pl.BlockSpec((tb, tk), lambda i, j: (i, j)),
             pl.BlockSpec((tb, 1), lambda i, j: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((tb, tk // W), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((B, K // W), jnp.float32),
-        compiler_params=_COMPILER_PARAMS(
-            dimension_semantics=("parallel", "parallel"),
+        out_specs=pl.BlockSpec((tb, nb), lambda i, j: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, nb), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(weights, tau[:, None])
 
 
-def _walk_trunc_kernel(
-    rows_ref, jb_ref, wblk_ref, run_ref, u_ref, tau_ref, out_ref,
-    blk_acc, run_acc, *, W: int, TB: int,
+# ---------------------------------------------------------------------------
+# Pass B (table-in): tiled walk over prebuilt (wp, running) state
+# ---------------------------------------------------------------------------
+
+
+def _walk_kernel(
+    rows_ref, jb_ref, wblk_ref, run_ref, u_ref, *rest, W: int, TB: int,
+    masked: bool,
 ):
-    """Masked pass B: identical to ``_walk_kernel`` except the streamed
-    raw W-blocks are re-masked by their row's threshold before the
-    Fenwick build (the running sums arrive masked from masked pass A, so
-    stop/lo/jb are consistent with the masked distribution)."""
-    r = pl.program_id(1)
-    blk_acc[r, :] = wblk_ref[0, :].astype(jnp.float32)
-    run_acc[r, :] = run_ref[0, :].astype(jnp.float32)
+    """Stream each sample's window (the aligned (8, window) row group
+    around its scalar-prefetch-selected W-block) and its running-sum row
+    group into the tile accumulators; the last inner step selects and
+    walks the whole tile.  ``masked``: re-mask the streamed raw weights
+    by their row's threshold before the Fenwick build (the running sums
+    arrive masked from masked pass A, so stop/lo/jb are consistent)."""
+    if masked:
+        tau_ref, out_ref, blk_acc, run_acc = rest
+    else:
+        out_ref, blk_acc, run_acc = rest
+    i, r = pl.program_id(0), pl.program_id(1)
+    sub = rows_ref[i * TB + r] % 8
+    _set_row(blk_acc, r, _pick_row(wblk_ref[...].astype(jnp.float32), sub))
+    _set_row(run_acc, r, _pick_row(run_ref[...].astype(jnp.float32), sub))
 
     @pl.when(r == TB - 1)
     def _walk():
         running = run_acc[...]
-        stop = running[:, -1] * u_ref[:, 0].astype(jnp.float32)
-        jb, lo = _select_tile(running, stop, W)
+        nb = running.shape[1]
+        stop = running[:, nb - 1:nb] * u_ref[...].astype(jnp.float32)
         blk = blk_acc[...]
-        tau = tau_ref[:, 0].astype(jnp.float32)
-        blk = jnp.where(blk >= tau[:, None], blk, 0.0)
-        t = _fenwick_tile(blk, W)
-        R = _descent_tile(t, stop, lo, W)
-        out_ref[:, 0] = jb * W + R
+        if masked:
+            blk = jnp.where(blk >= tau_ref[...].astype(jnp.float32), blk, 0.0)
+        # recompute the block selection in-kernel (bit-identical to the
+        # jb operand that addressed the DMA) so lo/stop never round-trip
+        out_ref[...] = _walk_block(running, stop, blk, W)
+
+
+def _walk_call(wp, running, u, rows, jb, tau, W: int, tb: int, interpret):
+    interpret = runtime.resolve_interpret(interpret)
+    Bt = u.shape[0]
+    Kp = wp.shape[1]
+    nb = running.shape[1]
+    win = _window(W, Kp)
+
+    def at(spec_fn):
+        return lambda i, r, rows_ref, jb_ref: spec_fn(i, rows_ref[i * tb + r],
+                                                      jb_ref[i * tb + r])
+
+    in_specs = [
+        pl.BlockSpec((8, win), at(lambda i, row, b: (row // 8, b * W // win))),
+        pl.BlockSpec((8, nb), at(lambda i, row, b: (row // 8, 0))),
+        pl.BlockSpec((tb, 1), at(lambda i, row, b: (i, 0))),
+    ]
+    operands = [wp, running, u.astype(jnp.float32)[:, None]]
+    if tau is not None:
+        in_specs.append(pl.BlockSpec((tb, 1), at(lambda i, row, b: (i, 0))))
+        operands.append(tau.astype(jnp.float32)[:, None])
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(Bt // tb, tb),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((tb, 1), at(lambda i, row, b: (i, 0))),
+        scratch_shapes=[
+            pltpu.VMEM((tb, win), jnp.float32),
+            pltpu.VMEM((tb, nb), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(
+            _walk_kernel, W=W, TB=tb, masked=tau is not None
+        ),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((Bt, 1), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+        ),
+        interpret=interpret,
+    )(rows.astype(jnp.int32), jb.astype(jnp.int32), *operands)
+    return out[:, 0]
+
+
+def walk_pallas(
+    wp: jnp.ndarray,
+    running: jnp.ndarray,
+    u: jnp.ndarray,
+    rows: jnp.ndarray,
+    jb: jnp.ndarray,
+    W: int,
+    tb: int,
+    interpret: bool | None = None,
+) -> jnp.ndarray:
+    """Tiled pass B: draw sample i from row ``rows[i]`` of the prebuilt
+    ``(wp, running)`` pair, re-reading only the window around W-block
+    ``jb[i]``.
+
+    ``rows``/``jb``/``u`` all have length Bt (a multiple of ``tb``); the
+    ``rows`` indirection lets S draws per distribution share one kernel
+    launch (multi-draw tiles ``arange(B)`` S times).  ``jb`` must be the
+    block-level search result for (rows, u) — it is consumed ONLY by the
+    BlockSpec index_map (the DMA address); the selection arithmetic is
+    recomputed in-kernel from the fetched running rows.  ``wp`` and
+    ``running`` have a multiple of 8 rows.
+    """
+    return _walk_call(wp, running, u, rows, jb, None, W, tb, interpret)
 
 
 def walk_trunc_pallas(
@@ -557,53 +738,87 @@ def walk_trunc_pallas(
 ) -> jnp.ndarray:
     """Tiled masked pass B; ``tau`` has length Bt like ``u``/``rows``
     (already gathered per sample for multi-draw)."""
-    interpret = runtime.resolve_interpret(interpret)
-    Bt = u.shape[0]
-    nb = running.shape[1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(Bt // tb, tb),
-        in_specs=[
-            pl.BlockSpec(
-                (1, W), lambda i, r, rows_ref, jb_ref: (
-                    rows_ref[i * tb + r], jb_ref[i * tb + r]
-                )
-            ),
-            pl.BlockSpec(
-                (1, nb), lambda i, r, rows_ref, jb_ref: (rows_ref[i * tb + r], 0)
-            ),
-            pl.BlockSpec((tb, 1), lambda i, r, rows_ref, jb_ref: (i, 0)),
-            pl.BlockSpec((tb, 1), lambda i, r, rows_ref, jb_ref: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((tb, 1), lambda i, r, rows_ref, jb_ref: (i, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((tb, W), jnp.float32),
-            pltpu.VMEM((tb, nb), jnp.float32),
-        ],
+    return _walk_call(wp, running, u, rows, jb, tau, W, tb, interpret)
+
+
+# ---------------------------------------------------------------------------
+# Table-in/table-out halves + fused end-to-end draw (jitted entry points)
+# ---------------------------------------------------------------------------
+
+
+def _pass_a_tk(K: int, W: int, tk: int) -> int:
+    """Pass-A category tile: a multiple of W, clamped to the W-padded row
+    for small K; a lane-aligned (multiple of 128) tile otherwise."""
+    tk = max(W, min(tk, int(np.ceil(K / W)) * W))
+    if tk % W:
+        raise ValueError(f"tk={tk} must be a multiple of W={W}")
+    if tk < K and tk % 128:
+        tk = -(-tk // max(W, 128)) * max(W, 128)
+    return tk
+
+
+def _build_sums_impl(weights, W: int, tb: int, tk: int, interpret):
+    """Pass A as a table-out step: pad, blocksum, running-sum.
+
+    Returns ``(wp, running)`` — the padded weights (pass B re-reads the
+    selected W-block from them) and the (Bp, Kp//W) running block sums.
+    This pair IS the kernel strategy's reusable precomputed state (the
+    analogue of the fenwick/butterfly tables for the other variants).
+    """
+    B, K = weights.shape
+    tb = runtime.row_tile(tb)
+    tk = _pass_a_tk(K, W, tk)
+    padB = (-B) % tb
+    padK = (-K) % tk
+    wp = jnp.pad(weights, ((0, padB), (0, padK)))
+    bs = blocksums_pallas(wp, W, tb, tk, interpret=interpret)   # (Bp, Kp//W)
+    running = jnp.cumsum(bs, axis=1)
+    return wp, running
+
+
+def _block_search(running_rows, u):
+    """XLA-side block-level search producing the pass-B DMA addresses:
+    the smallest block whose running sum exceeds stop = total * u."""
+    nb = running_rows.shape[1]
+    stop = running_rows[:, -1] * u.astype(jnp.float32)
+    return jnp.clip(
+        jnp.sum(running_rows <= stop[:, None], axis=1).astype(jnp.int32),
+        0, nb - 1,
     )
-    out = pl.pallas_call(
-        functools.partial(_walk_trunc_kernel, W=W, TB=tb),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Bt, 1), jnp.int32),
-        compiler_params=_COMPILER_PARAMS(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(
-        rows.astype(jnp.int32), jb.astype(jnp.int32),
-        wp, running, u.astype(jnp.float32)[:, None],
-        tau.astype(jnp.float32)[:, None],
-    )
-    return out[:, 0]
+
+
+def _sample_rows(u, B: int, tb: int):
+    """Flatten (B,) or (S, B) uniforms into one padded sample list:
+    returns (uf, rows, Bt) with ``rows`` the source row of each sample."""
+    S = u.shape[0] if u.ndim == 2 else 1
+    uf = u.reshape(-1).astype(jnp.float32)                       # (S*B,)
+    rows = jnp.tile(jnp.arange(B, dtype=jnp.int32), S)
+    Bt = S * B
+    padT = (-Bt) % tb
+    if padT:
+        uf = jnp.pad(uf, (0, padT))
+        rows = jnp.pad(rows, (0, padT))
+    return uf, rows, Bt
+
+
+def _draw_from_sums_impl(wp, running, u, B: int, K: int, W: int, tb: int, interpret):
+    """Pass B as a table-in step.  ``u`` is (B,) for one draw per row or
+    (S, B) for S draws per row (the multi-draw decode path); ``B``/``K``
+    are the unpadded shape."""
+    tb = runtime.row_tile(tb)
+    uf, rows, Bt = _sample_rows(u, B, tb)
+    jb = _block_search(running[rows], uf)
+    idx = walk_pallas(wp, running, uf, rows, jb, W, tb, interpret=interpret)
+    idx = jnp.minimum(idx[:Bt], K - 1)
+    return idx.reshape(u.shape) if u.ndim == 2 else idx
 
 
 def _build_masked_sums_impl(weights, tau, W: int, tb: int, tk: int, interpret):
     """Masked pass A: pad, masked blocksums, running sums.  Padded rows
     carry tau = 0, so their all-zero weights stay all-zero sums."""
     B, K = weights.shape
-    tk = max(W, min(tk, int(np.ceil(K / W)) * W))
-    if tk % W:
-        raise ValueError(f"tk={tk} must be a multiple of W={W}")
+    tb = runtime.row_tile(tb)
+    tk = _pass_a_tk(K, W, tk)
     padB = (-B) % tb
     padK = (-K) % tk
     wp = jnp.pad(weights, ((0, padB), (0, padK)))
@@ -618,22 +833,14 @@ def _trunc_draw_from_sums_impl(
 ):
     """Masked pass B with the multi-draw ``rows`` indirection; mirrors
     ``_draw_from_sums_impl`` plus the per-sample threshold gather."""
-    multi = u.ndim == 2
-    S = u.shape[0] if multi else 1
-    uf = u.reshape(-1).astype(jnp.float32)
-    rows = jnp.tile(jnp.arange(B, dtype=jnp.int32), S)
-    Bt = S * B
-    padT = (-Bt) % tb
-    if padT:
-        uf = jnp.pad(uf, (0, padT))
-        rows = jnp.pad(rows, (0, padT))
+    tb = runtime.row_tile(tb)
+    uf, rows, Bt = _sample_rows(u, B, tb)
     jb = _block_search(running[rows], uf)
-    tau_s = taup[rows]
     idx = walk_trunc_pallas(
-        wp, running, uf, tau_s, rows, jb, W, tb, interpret=interpret
+        wp, running, uf, taup[rows], rows, jb, W, tb, interpret=interpret
     )
     idx = jnp.minimum(idx[:Bt], K - 1)
-    return idx.reshape(S, B) if multi else idx
+    return idx.reshape(u.shape) if u.ndim == 2 else idx
 
 
 def _pad_params(params, padB: int) -> jnp.ndarray:
@@ -674,7 +881,7 @@ def butterfly_sample_truncated_pallas(
     padK = (-K) % W
     Kp = K + padK
     tb = _fused_tb(tb, Kp)
-    if tb * Kp * 4 > _FUSED_TILE_BYTES:
+    if not _fused_fits(tb, Kp, W):
         from repro.sampling import transforms as _tr
 
         tau = _tr.thresholds_from_params(weights, params, iters=iters)
@@ -716,7 +923,7 @@ def butterfly_sample_truncated_rng_pallas(
     padK = (-K) % W
     Kp = K + padK
     tb = _fused_tb(tb, Kp)
-    if tb * Kp * 4 > _FUSED_TILE_BYTES:
+    if not _fused_fits(tb, Kp, W):
         from repro.sampling import transforms as _tr
 
         tau = _tr.thresholds_from_params(weights, params, iters=iters)
@@ -734,147 +941,6 @@ def butterfly_sample_truncated_rng_pallas(
         interpret=interpret,
     )
     return jnp.minimum(idx[:B], K - 1)
-
-
-# ---------------------------------------------------------------------------
-# Pass B (table-in): tiled walk over prebuilt (wp, running) state
-# ---------------------------------------------------------------------------
-
-
-def _walk_kernel(
-    rows_ref, jb_ref, wblk_ref, run_ref, u_ref, out_ref, blk_acc, run_acc,
-    *, W: int, TB: int,
-):
-    r = pl.program_id(1)
-    # stream this sample's scalar-prefetch-selected W-block (and its
-    # running-sum row) into the tile accumulators
-    blk_acc[r, :] = wblk_ref[0, :].astype(jnp.float32)
-    run_acc[r, :] = run_ref[0, :].astype(jnp.float32)
-
-    @pl.when(r == TB - 1)
-    def _walk():
-        running = run_acc[...]
-        stop = running[:, -1] * u_ref[:, 0].astype(jnp.float32)
-        # recompute the block selection in-kernel (bit-identical to the
-        # jb operand that addressed the DMA) so lo/stop never round-trip
-        jb, lo = _select_tile(running, stop, W)
-        t = _fenwick_tile(blk_acc[...], W)
-        R = _descent_tile(t, stop, lo, W)
-        out_ref[:, 0] = jb * W + R
-
-
-def walk_pallas(
-    wp: jnp.ndarray,
-    running: jnp.ndarray,
-    u: jnp.ndarray,
-    rows: jnp.ndarray,
-    jb: jnp.ndarray,
-    W: int,
-    tb: int,
-    interpret: bool | None = None,
-) -> jnp.ndarray:
-    """Tiled pass B: draw sample i from row ``rows[i]`` of the prebuilt
-    ``(wp, running)`` pair, re-reading only W-block ``jb[i]``.
-
-    ``rows``/``jb``/``u`` all have length Bt (a multiple of ``tb``); the
-    ``rows`` indirection lets S draws per distribution share one kernel
-    launch (multi-draw tiles ``arange(B)`` S times).  ``jb`` must be the
-    block-level search result for (rows, u) — it is consumed ONLY by the
-    BlockSpec index_map (the DMA address); the selection arithmetic is
-    recomputed in-kernel from the fetched running rows.
-    """
-    interpret = runtime.resolve_interpret(interpret)
-    Bt = u.shape[0]
-    nb = running.shape[1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(Bt // tb, tb),
-        in_specs=[
-            pl.BlockSpec(
-                (1, W), lambda i, r, rows_ref, jb_ref: (
-                    rows_ref[i * tb + r], jb_ref[i * tb + r]
-                )
-            ),
-            pl.BlockSpec(
-                (1, nb), lambda i, r, rows_ref, jb_ref: (rows_ref[i * tb + r], 0)
-            ),
-            pl.BlockSpec((tb, 1), lambda i, r, rows_ref, jb_ref: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((tb, 1), lambda i, r, rows_ref, jb_ref: (i, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((tb, W), jnp.float32),
-            pltpu.VMEM((tb, nb), jnp.float32),
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_walk_kernel, W=W, TB=tb),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Bt, 1), jnp.int32),
-        compiler_params=_COMPILER_PARAMS(
-            dimension_semantics=("parallel", "arbitrary"),
-        ),
-        interpret=interpret,
-    )(
-        rows.astype(jnp.int32), jb.astype(jnp.int32),
-        wp, running, u.astype(jnp.float32)[:, None],
-    )
-    return out[:, 0]
-
-
-# ---------------------------------------------------------------------------
-# Table-in/table-out halves + fused end-to-end draw (jitted entry points)
-# ---------------------------------------------------------------------------
-
-
-def _build_sums_impl(weights, W: int, tb: int, tk: int, interpret):
-    """Pass A as a table-out step: pad, blocksum, running-sum.
-
-    Returns ``(wp, running)`` — the padded weights (pass B re-reads the
-    selected W-block from them) and the (Bp, Kp//W) running block sums.
-    This pair IS the kernel strategy's reusable precomputed state (the
-    analogue of the fenwick/butterfly tables for the other variants).
-    """
-    B, K = weights.shape
-    tk = max(W, min(tk, int(np.ceil(K / W)) * W))
-    if tk % W:
-        raise ValueError(f"tk={tk} must be a multiple of W={W}")
-    padB = (-B) % tb
-    padK = (-K) % tk
-    wp = jnp.pad(weights, ((0, padB), (0, padK)))
-    bs = blocksums_pallas(wp, W, tb, tk, interpret=interpret)   # (Bp, Kp//W)
-    running = jnp.cumsum(bs, axis=1)
-    return wp, running
-
-
-def _block_search(running_rows, u):
-    """XLA-side block-level search producing the pass-B DMA addresses:
-    the smallest block whose running sum exceeds stop = total * u."""
-    nb = running_rows.shape[1]
-    stop = running_rows[:, -1] * u.astype(jnp.float32)
-    return jnp.clip(
-        jnp.sum(running_rows <= stop[:, None], axis=1).astype(jnp.int32),
-        0, nb - 1,
-    )
-
-
-def _draw_from_sums_impl(wp, running, u, B: int, K: int, W: int, tb: int, interpret):
-    """Pass B as a table-in step.  ``u`` is (B,) for one draw per row or
-    (S, B) for S draws per row (the multi-draw decode path); ``B``/``K``
-    are the unpadded shape."""
-    Bp = wp.shape[0]
-    multi = u.ndim == 2
-    S = u.shape[0] if multi else 1
-    uf = u.reshape(-1).astype(jnp.float32)                       # (S*B,)
-    rows = jnp.tile(jnp.arange(B, dtype=jnp.int32), S)
-    Bt = S * B
-    padT = (-Bt) % tb
-    if padT:
-        uf = jnp.pad(uf, (0, padT))
-        rows = jnp.pad(rows, (0, padT))
-    jb = _block_search(running[rows], uf)
-    idx = walk_pallas(wp, running, uf, rows, jb, W, tb, interpret=interpret)
-    idx = jnp.minimum(idx[:Bt], K - 1)
-    return idx.reshape(S, B) if multi else idx
 
 
 @functools.partial(jax.jit, static_argnames=("W", "tb", "tk", "interpret"))
@@ -920,16 +986,16 @@ def butterfly_sample_pallas(
     ONE fused pallas_call: each (tb, Kp) weight tile is loaded once and
     the block-sum/select/walk pipeline runs entirely in VMEM.  Pads B to
     a multiple of ``tb`` and K to a multiple of ``W`` (zero weights are
-    never selected).  When even a tb=8 row tile would blow the VMEM
-    budget (vocab-scale K), the draw transparently takes the two-pass
-    route — pass A streamed in (tb, tk) tiles, tiled pass B — which is
+    never selected).  When the tile would blow the VMEM budget
+    (vocab-scale K), the draw transparently takes the two-pass route —
+    pass A streamed in (tb, tk) tiles, tiled pass B — which is
     formula-identical (``test_table_in_matches_fused`` pins this).
     """
     B, K = weights.shape
     padK = (-K) % W
     Kp = K + padK
     tb = _fused_tb(tb, Kp)
-    if tb * Kp * 4 > _FUSED_TILE_BYTES:
+    if not _fused_fits(tb, Kp, W):
         wp, running = _build_sums_impl(weights, W, tb, tk, interpret)
         return _draw_from_sums_impl(wp, running, u, B, K, W, tb, interpret)
     padB = (-B) % tb

@@ -25,10 +25,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
-
 def _rounds_inplace(m: jnp.ndarray, W: int) -> jnp.ndarray:
     """log2(W) butterfly rounds on a (W, W) tile (rows=samples, cols=cats).
 
@@ -90,7 +86,7 @@ def butterfly_table_pallas(
         out_specs=pl.BlockSpec((W, W), lambda g, c: (g, c)),
         out_shape=jax.ShapeDtypeStruct((B, K), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, W), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

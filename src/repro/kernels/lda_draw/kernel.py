@@ -8,18 +8,19 @@ fusion (DESIGN.md §4):
   * the data-dependent fetches of ``theta[doc[s], :]`` and ``phi[w[s], :]``
     — the memory-coalescing problem the paper's warp-transposed loads
     solve — become **scalar-prefetch-driven BlockSpec index_maps**: the
-    doc id selects the theta row and the word id selects the phi row, and
-    the Pallas pipeline DMAs exactly those rows into VMEM (contiguous,
-    double-buffered — the hardware-native "coalesced" gather).  Theta is
-    never ``jnp.repeat``-ed to one row per word position;
+    doc id selects the theta row group and the word id the phi row group
+    (the aligned 8 rows holding the row: a TPU block's sublane dimension
+    is a multiple of 8), the Pallas pipeline DMAs them into VMEM
+    (double-buffered), and the kernel picks the row.  Theta is never
+    ``jnp.repeat``-ed to one row per word position;
   * theta row x phi row -> weights, per-W-block sums, block selection and
     the in-block dyadic walk all happen in registers/VMEM;
-  * HBM traffic per sample: theta row (K) + one phi row (K) + nothing else.
-    The unfused pipeline (materialize weights, then sample) pays >= 3K.
+  * HBM traffic per sample: one (8, Kp) group of theta and one of phi,
+    nothing written but the index.
 
 Tiled grid (DESIGN.md §3): ``grid = (B//tb, tb)``.  The inner dimension
-streams one (theta row, phi row) pair per sample into a (tb, Kp) VMEM
-product tile; the last inner step runs the whole fused draw — block sums,
+streams one (theta row, phi row) product per sample into a (tb, Kp) VMEM
+tile; the last inner step runs the whole fused draw — block sums,
 in-kernel block selection, vectorized (tb, W) dyadic walk — for the tile
 at once.  Kp (K padded to a multiple of W) must fit VMEM alongside the
 tile — true by construction for LDA (K <= ~1k topics).
@@ -31,7 +32,8 @@ Three entry points:
     sums of the theta-phi products, (B, K//W), never forming (B, K)
     (the ``lda_kernel`` Categorical variant's table build)
   * ``lda_walk_pallas``         — factored pass B: re-reads only the
-    selected W-block of each sample's theta/phi rows (table-in draw)
+    aligned window around the selected W-block of each sample's theta/phi
+    rows (table-in draw)
 """
 
 from __future__ import annotations
@@ -44,13 +46,37 @@ from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
 from repro.kernels import runtime
+from repro.kernels.butterfly_sample import kernel as _bk
 from repro.kernels.butterfly_sample.kernel import (
-    _COMPILER_PARAMS,
-    _descent_tile,
+    _block_sums,
     _draw_tile,
-    _fenwick_tile,
-    _select_tile,
+    _pick_row,
+    _row_cumsum,
+    _set_row,
+    _walk_block,
+    _window,
 )
+
+
+def _product_row(theta_ref, phi_ref, doc, word):
+    """theta[doc] * phi[word] as a (1, L) row from their (8, L) row groups
+    (the paper's line 16, fp32 accumulation)."""
+    th = _pick_row(theta_ref[...].astype(jnp.float32), doc % 8)
+    ph = _pick_row(phi_ref[...].astype(jnp.float32), word % 8)
+    return th * ph
+
+
+def _row_groups(L: int, tb: int, first, second):
+    """(8, L) row-group BlockSpecs of theta and phi, selected by the
+    scalar-prefetched doc / word id of the sample at grid step (i, r)."""
+    return [
+        pl.BlockSpec(
+            (8, L), lambda i, r, *refs: (refs[first][i * tb + r] // 8, 0)
+        ),
+        pl.BlockSpec(
+            (8, L), lambda i, r, *refs: (refs[second][i * tb + r] // 8, 0)
+        ),
+    ]
 
 
 # ---------------------------------------------------------------------------
@@ -61,16 +87,14 @@ from repro.kernels.butterfly_sample.kernel import (
 def _fused_factored_kernel(
     docs_ref, words_ref, theta_ref, phi_ref, u_ref, out_ref, w_acc, *, W: int, TB: int
 ):
-    r = pl.program_id(1)
-    # fused theta-phi product (the paper's line 16), fp32 accumulation;
+    i, r = pl.program_id(0), pl.program_id(1)
+    s = i * TB + r
     # one row of the (TB, Kp) product tile per inner grid step
-    w_acc[r, :] = theta_ref[0, :].astype(jnp.float32) * phi_ref[0, :].astype(
-        jnp.float32
-    )
+    _set_row(w_acc, r, _product_row(theta_ref, phi_ref, docs_ref[s], words_ref[s]))
 
     @pl.when(r == TB - 1)
     def _draw():
-        out_ref[:, 0] = _draw_tile(w_acc[...], u_ref[:, 0].astype(jnp.float32), W)
+        out_ref[...] = _draw_tile(w_acc[...], u_ref[...].astype(jnp.float32), W)
 
 
 def lda_fused_draw_pallas(
@@ -90,23 +114,17 @@ def lda_fused_draw_pallas(
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(Bt // tb, tb),
-        in_specs=[
-            pl.BlockSpec(
-                (1, Kp), lambda i, r, docs_ref, words_ref: (docs_ref[i * tb + r], 0)
-            ),
-            pl.BlockSpec(
-                (1, Kp), lambda i, r, docs_ref, words_ref: (words_ref[i * tb + r], 0)
-            ),
-            pl.BlockSpec((tb, 1), lambda i, r, docs_ref, words_ref: (i, 0)),
+        in_specs=_row_groups(Kp, tb, 0, 1) + [
+            pl.BlockSpec((tb, 1), lambda i, r, *_: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((tb, 1), lambda i, r, docs_ref, words_ref: (i, 0)),
+        out_specs=pl.BlockSpec((tb, 1), lambda i, r, *_: (i, 0)),
         scratch_shapes=[pltpu.VMEM((tb, Kp), jnp.float32)],
     )
     out = pl.pallas_call(
         functools.partial(_fused_factored_kernel, W=W, TB=tb),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Bt, 1), jnp.int32),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -123,12 +141,15 @@ def lda_fused_draw_pallas(
 
 
 def _factored_blocksum_kernel(
-    docs_ref, words_ref, theta_ref, phi_ref, out_ref, *, W: int
+    docs_ref, words_ref, theta_ref, phi_ref, out_ref, w_acc, *, W: int, TB: int
 ):
-    r = pl.program_id(1)
-    w = theta_ref[0, :].astype(jnp.float32) * phi_ref[0, :].astype(jnp.float32)
-    nb = w.shape[0] // W
-    out_ref[r, :] = jnp.cumsum(w.reshape(nb, W).sum(axis=-1))
+    i, r = pl.program_id(0), pl.program_id(1)
+    s = i * TB + r
+    _set_row(w_acc, r, _product_row(theta_ref, phi_ref, docs_ref[s], words_ref[s]))
+
+    @pl.when(r == TB - 1)
+    def _sums():
+        out_ref[...] = _row_cumsum(_block_sums(w_acc[...], W))
 
 
 def lda_blocksums_pallas(
@@ -149,21 +170,15 @@ def lda_blocksums_pallas(
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(Bt // tb, tb),
-        in_specs=[
-            pl.BlockSpec(
-                (1, Kp), lambda i, r, docs_ref, words_ref: (docs_ref[i * tb + r], 0)
-            ),
-            pl.BlockSpec(
-                (1, Kp), lambda i, r, docs_ref, words_ref: (words_ref[i * tb + r], 0)
-            ),
-        ],
-        out_specs=pl.BlockSpec((tb, nb), lambda i, r, docs_ref, words_ref: (i, 0)),
+        in_specs=_row_groups(Kp, tb, 0, 1),
+        out_specs=pl.BlockSpec((tb, nb), lambda i, r, *_: (i, 0)),
+        scratch_shapes=[pltpu.VMEM((tb, Kp), jnp.float32)],
     )
     return pl.pallas_call(
-        functools.partial(_factored_blocksum_kernel, W=W),
+        functools.partial(_factored_blocksum_kernel, W=W, TB=tb),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Bt, nb), jnp.float32),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -180,20 +195,17 @@ def _factored_walk_kernel(
     theta_ref, phi_ref, run_ref, u_ref, out_ref, blk_acc, run_acc,
     *, W: int, TB: int,
 ):
-    r = pl.program_id(1)
-    blk_acc[r, :] = theta_ref[0, :].astype(jnp.float32) * phi_ref[0, :].astype(
-        jnp.float32
-    )
-    run_acc[r, :] = run_ref[0, :].astype(jnp.float32)
+    i, r = pl.program_id(0), pl.program_id(1)
+    s = i * TB + r
+    _set_row(blk_acc, r, _product_row(theta_ref, phi_ref, docs_ref[s], words_ref[s]))
+    _set_row(run_acc, r, _pick_row(run_ref[...].astype(jnp.float32), rows_ref[s] % 8))
 
     @pl.when(r == TB - 1)
     def _walk():
         running = run_acc[...]
-        stop = running[:, -1] * u_ref[:, 0].astype(jnp.float32)
-        jb, lo = _select_tile(running, stop, W)
-        t = _fenwick_tile(blk_acc[...], W)
-        R = _descent_tile(t, stop, lo, W)
-        out_ref[:, 0] = jb * W + R
+        nb = running.shape[1]
+        stop = running[:, nb - 1:nb] * u_ref[...].astype(jnp.float32)
+        out_ref[...] = _walk_block(running, stop, blk_acc[...], W)
 
 
 def lda_walk_pallas(
@@ -209,38 +221,39 @@ def lda_walk_pallas(
     tb: int,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
-    """Factored table-in draw: HBM traffic 2*W (+ nb) per sample."""
+    """Factored table-in draw: per sample, one aligned window of the
+    theta and phi rows around the selected W-block (+ the running row)."""
     interpret = runtime.resolve_interpret(interpret)
     Bt = u.shape[0]
+    Kp = theta.shape[1]
     nb = running.shape[1]
+    win = _window(W, Kp)
+
+    def at(i, r, rows_ref, docs_ref, words_ref, jb_ref, which):
+        s = i * tb + r
+        col = jb_ref[s] * W // win
+        return {
+            "theta": (docs_ref[s] // 8, col),
+            "phi": (words_ref[s] // 8, col),
+            "run": (rows_ref[s] // 8, 0),
+            "tile": (i, 0),
+        }[which]
+
+    def spec(shape, which):
+        return pl.BlockSpec(shape, functools.partial(at, which=which))
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(Bt // tb, tb),
         in_specs=[
-            pl.BlockSpec(
-                (1, W), lambda i, r, rows_ref, docs_ref, words_ref, jb_ref: (
-                    docs_ref[i * tb + r], jb_ref[i * tb + r]
-                )
-            ),
-            pl.BlockSpec(
-                (1, W), lambda i, r, rows_ref, docs_ref, words_ref, jb_ref: (
-                    words_ref[i * tb + r], jb_ref[i * tb + r]
-                )
-            ),
-            pl.BlockSpec(
-                (1, nb), lambda i, r, rows_ref, docs_ref, words_ref, jb_ref: (
-                    rows_ref[i * tb + r], 0
-                )
-            ),
-            pl.BlockSpec(
-                (tb, 1), lambda i, r, rows_ref, docs_ref, words_ref, jb_ref: (i, 0)
-            ),
+            spec((8, win), "theta"),
+            spec((8, win), "phi"),
+            spec((8, nb), "run"),
+            spec((tb, 1), "tile"),
         ],
-        out_specs=pl.BlockSpec(
-            (tb, 1), lambda i, r, rows_ref, docs_ref, words_ref, jb_ref: (i, 0)
-        ),
+        out_specs=spec((tb, 1), "tile"),
         scratch_shapes=[
-            pltpu.VMEM((tb, W), jnp.float32),
+            pltpu.VMEM((tb, win), jnp.float32),
             pltpu.VMEM((tb, nb), jnp.float32),
         ],
     )
@@ -248,7 +261,7 @@ def lda_walk_pallas(
         functools.partial(_factored_walk_kernel, W=W, TB=tb),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Bt, 1), jnp.int32),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -270,32 +283,43 @@ def _pad_k(x, W: int):
     return jnp.pad(x, ((0, 0), (0, padK))) if padK else x
 
 
-def _lda_draw_impl(theta, phi, doc_ids, words, u, W: int, tb: int, interpret):
-    from repro.kernels.butterfly_sample.kernel import (
-        _block_search,
-        _fused_tb,
-        _FUSED_TILE_BYTES,
-    )
+# Samples per kernel launch.  The per-sample doc/word ids are scalar-
+# prefetched into SMEM (1 MiB on v5e), so a longer sample list — a whole
+# shard of a sharded sweep — runs as a ``lax.map`` over chunks this long.
+_SMEM_SAMPLES = 32768
 
+
+def _lda_draw_impl(theta, phi, doc_ids, words, u, W: int, tb: int, interpret):
     K = theta.shape[1]
     B = u.shape[0]
+    if B > _SMEM_SAMPLES:
+        n = -(-B // _SMEM_SAMPLES)
+        pad = n * _SMEM_SAMPLES - B
+        chunks = [
+            jnp.pad(x, (0, pad)).reshape(n, _SMEM_SAMPLES)
+            for x in (doc_ids, words, u)
+        ]
+        idx = jax.lax.map(
+            lambda c: _lda_draw_impl(theta, phi, *c, W, tb, interpret), chunks
+        )
+        return idx.reshape(-1)[:B]
     thetap = _pad_k(theta, W)
     phip = _pad_k(phi, W)
     Kp = thetap.shape[1]
-    tb = _fused_tb(tb, Kp)
+    tb = _bk._fused_tb(tb, Kp)
     padB = (-B) % tb
     if padB:
         doc_ids = jnp.pad(doc_ids, (0, padB))
         words = jnp.pad(words, (0, padB))
         u = jnp.pad(u.astype(jnp.float32), (0, padB), constant_values=0.5)
-    if tb * Kp * 4 > _FUSED_TILE_BYTES:
+    if not _bk._fused_fits(tb, Kp, W):
         # the (tb, Kp) product tile would blow VMEM: take the factored
         # two-pass route (pass A streams factor rows, pass B touches one
-        # W-block of each) — formula-identical to the fused kernel
+        # window of each) — formula-identical to the fused kernel
         running = lda_blocksums_pallas(
             thetap, phip, doc_ids, words, W=W, tb=tb, interpret=interpret
         )
-        jb = _block_search(running, u)
+        jb = _bk._block_search(running, u)
         rows = jnp.arange(u.shape[0], dtype=jnp.int32)
         idx = lda_walk_pallas(
             thetap, phip, running, u, rows, doc_ids, words, jb,

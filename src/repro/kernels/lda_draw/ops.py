@@ -233,6 +233,7 @@ def lda_build_running(
     words = words.astype(jnp.int32)
     if _resolve_impl(impl) == "pallas":
         B = doc_ids.shape[0]
+        tb = runtime.row_tile(tb)
         padB = (-B) % tb
         dp = jnp.pad(doc_ids, (0, padB)) if padB else doc_ids
         wp = jnp.pad(words, (0, padB)) if padB else words
@@ -266,6 +267,7 @@ def lda_draw_from_running(
         from repro.kernels.butterfly_sample.kernel import _block_search
 
         Bt = S * B
+        tb = runtime.row_tile(tb)
         padT = (-Bt) % tb
         if padT:
             uf = jnp.pad(uf, (0, padT))

@@ -103,9 +103,9 @@ def fold(seed: jnp.ndarray, a, b=0) -> jnp.ndarray:
 
 def bits_to_uniform(bits) -> jnp.ndarray:
     """uint32 bits -> float32 uniforms in [0, 1) (top 24 bits)."""
-    return (jnp.asarray(bits, jnp.uint32) >> np.uint32(8)).astype(
-        jnp.float32
-    ) * np.float32(2**-24)
+    # via int32: the top 24 bits fit, and Mosaic has no uint32 -> f32 cast
+    top = (jnp.asarray(bits, jnp.uint32) >> np.uint32(8)).astype(jnp.int32)
+    return top.astype(jnp.float32) * np.float32(2**-24)
 
 
 def uniform(seed: jnp.ndarray, counter0, counter1=0) -> jnp.ndarray:

@@ -49,6 +49,11 @@ def default_tb(B: int) -> int:
     return 8 if B < 1024 else 16
 
 
+def row_tile(tb: int) -> int:
+    """A row tile the TPU can block: a multiple of the 8 fp32 sublanes."""
+    return max(8, -(-tb // 8) * 8)
+
+
 def default_tk(K: int, W: int) -> int:
     """Category-tile for pass A: a multiple of W near 512 lanes, clamped
     to the padded row length so tiny K never over-pads."""

@@ -1,5 +1,6 @@
 """Serving driver: batched request decoding with the butterfly sampler.
 
+    python -m repro.launch.serve --arch qwen3-4b --continuous  # full width, bf16
     python -m repro.launch.serve --arch qwen3-4b --smoke --requests 8
     python -m repro.launch.serve --smoke --dp 2 --tp 2   # sharded decode
     python -m repro.launch.serve --smoke --continuous    # slot-recycled engine
@@ -29,6 +30,7 @@ import numpy as np
 
 from repro.configs import ARCH_IDS, get_config
 from repro.dist import sharding as shd
+from repro.launch import compile_cache
 from repro.launch.mesh import smallest_fitting_mesh
 from repro.models import build_model, init_params, logical_axes
 from repro.serve.engine import generate
@@ -36,9 +38,11 @@ from repro.serve.engine import generate
 
 def main():
     """CLI: run a small closed-loop serve session and print stats."""
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-4b", choices=ARCH_IDS)
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action="store_true",
+                    help="reduced config (CPU); default: published widths")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--prompt-len", type=int, default=16)
     ap.add_argument("--max-new", type=int, default=32)
@@ -62,7 +66,10 @@ def main():
         sampler_method=args.sampler, sampler_W=8 if args.smoke else 32,
     )
     model = build_model(cfg)
-    params = init_params(jax.random.PRNGKey(0), model.specs, jnp.float32)
+    # full-width params in bf16: qwen3-4b is ~16 GB in float32, more than
+    # one 16 GiB chip holds
+    dtype = jnp.float32 if args.smoke else jnp.bfloat16
+    params = init_params(jax.random.PRNGKey(0), model.specs, dtype)
     rng = np.random.default_rng(0)
     B = args.requests
 

@@ -37,6 +37,7 @@ from repro.dist import sharding as shd
 from repro.dist.fault import CheckpointManager, install_preemption_handler, preempted
 from repro.dist.heartbeat import MonitorFeeder, open_mailbox
 from repro.dist.monitor import StepMonitor
+from repro.launch import compile_cache
 
 
 def train_lm(args):
@@ -171,6 +172,7 @@ def train_lda(args):
 
 def main():
     """CLI entry point: parse flags, dispatch to the LM or LDA loop."""
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--app", default="lm", choices=["lm", "lda"])
     ap.add_argument("--arch", default="llama3-8b", choices=ARCH_IDS)

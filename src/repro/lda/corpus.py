@@ -124,18 +124,41 @@ def synthesize_corpus(
     true_theta = rng.dirichlet(np.full(K, doc_concentration), size=M)    # (M, K)
     lengths = np.clip(rng.poisson(avg_len, size=M), 1, max_len).astype(np.int32)
     maxN = int(lengths.max())
-    docs = np.zeros((M, maxN), np.int32)
-    mask = np.zeros((M, maxN), bool)
+    mask = np.arange(maxN)[None, :] < lengths[:, None]
+    # Inverse-cdf draws in bulk.  Each document consumes, in order, one
+    # uniform per token for its topics, then one per token for its words
+    # (grouped by ascending topic, positions in order within a group) —
+    # the stream a per-document loop of ``rng.choice(..., p=...)`` calls
+    # would read, so the corpus for a seed is the same at any scale, with
+    # one cdf build per topic instead of one per (document, topic).
+    n_tok = int(lengths.sum())
+    start = np.concatenate([[0], np.cumsum(lengths)[:-1]])
+    u = rng.random(2 * n_tok)
+    doc = np.repeat(np.arange(M), lengths)
+    pos = np.arange(n_tok) - start[doc]
+    u_topic = u[2 * start[doc] + pos]
+    u_word = u[2 * start[doc] + lengths[doc] + pos]
+    cdf_theta = np.cumsum(true_theta, axis=1)
+    cdf_theta /= cdf_theta[:, -1:]
+    topics = np.empty(n_tok, np.int64)
     for m in range(M):
-        n = lengths[m]
-        topics = rng.choice(K, size=n, p=true_theta[m])
-        # vectorized word draw per topic group
-        words = np.empty(n, np.int32)
-        for k in np.unique(topics):
-            sel = topics == k
-            words[sel] = rng.choice(V, size=sel.sum(), p=true_phi[:, k])
-        docs[m, :n] = words
-        mask[m, :n] = True
+        s = slice(start[m], start[m] + lengths[m])
+        topics[s] = cdf_theta[m].searchsorted(u_topic[s], side="right")
+    # the j-th word uniform of a document goes to the j-th token in
+    # (topic, position) order
+    order = np.lexsort((np.arange(n_tok), topics, doc))
+    tok_u = np.empty(n_tok)
+    tok_u[order] = u_word
+    cdf_phi = np.cumsum(true_phi, axis=0)
+    cdf_phi /= cdf_phi[-1:, :]
+    words = np.empty(n_tok, np.int32)
+    by_topic = np.argsort(topics, kind="stable")
+    bounds = np.searchsorted(topics[by_topic], np.arange(K + 1))
+    for k in range(K):
+        sel = by_topic[bounds[k]:bounds[k + 1]]
+        words[sel] = cdf_phi[:, k].searchsorted(tok_u[sel], side="right")
+    docs = np.zeros((M, maxN), np.int32)
+    docs[mask] = words
     return Corpus(
         docs=docs,
         lengths=lengths,

@@ -36,7 +36,6 @@ from repro.kernels import rng as _rng
 from repro.lda.gibbs import LDAState, _counts, _update_phi, _update_theta
 from repro.sampling.sharded import (
     _linear_index,
-    _shard_map,
     data_axes,
     data_size,
     row_spec,
@@ -150,12 +149,12 @@ def make_sharded_gibbs(mesh, K: int, V: int, alpha: float = 0.1,
         phi = _update_phi(k_phi, word_topic, beta)
         return LDAState(theta=theta, phi=phi, z=z, key=k_next, step=step + 1)
 
-    step_sm = _shard_map(
+    step_sm = jax.shard_map(
         shard_step,
         mesh=mesh,
         in_specs=(rs, P(), rs, P(), P(), rs, rs),
         out_specs=LDAState(theta=rs, phi=P(), z=rs, key=P(), step=P()),
-        check_rep=False,  # pallas_call has no replication rule
+        check_vma=False,  # pallas_call has no replication rule
     )
 
     @jax.jit

@@ -693,41 +693,38 @@ def measure_sparse_mh(
     seed: int = 0,
     steps: int = 2,
     cap: int = 32,
-) -> Optional[float]:
+) -> float:
     """Median wall-clock microseconds of a ``B``-token sparse MH draw at
     ``K`` topics on synthetic sparse data — what measure-mode autotune
     times for the ``sparse_mh`` candidate (cdf word proposal: the
     in-training table the arbitration concerns)."""
-    try:
-        L = 16
-        M = max(1, B // L)
-        V = 256
-        cap = min(cap, K)
-        rng = np.random.default_rng(seed)
-        key = jax.random.PRNGKey(seed)
-        theta = jax.random.dirichlet(key, jnp.full(K, 0.05), (M,))
-        phi = jax.random.dirichlet(
-            jax.random.fold_in(key, 1), jnp.full(V, 0.1), (K,)
-        ).T
-        docs = jnp.asarray(rng.integers(0, V, size=(M, L)), jnp.int32)
-        mask = jnp.ones((M, L), bool)
-        z = jnp.asarray(rng.integers(0, K, size=(M, L)), jnp.int32)
-        doc_topic, _ = _counts_scatter(z, docs, mask, K, V)
-        sp = sparse_counts(doc_topic, cap)
-        tbl_a, tbl_b = word_proposal_tables(phi, "cdf")
-        s = _rng.fold(_rng.seed_from_key(key), _rng.TAG_SPARSE_MH)
-        fn = _mh_sweep_jit(steps, cap, "cdf", min(256, M))
-        args = (
-            z, docs, mask, theta, phi, sp.ids, sp.cnt, tbl_a, tbl_b, s,
-            jnp.uint32(0), jnp.float32(0.1),
-        )
-        for _ in range(max(warmup, 1)):
-            jax.block_until_ready(fn(*args))
-        times = []
-        for _ in range(max(iters, 1)):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn(*args))
-            times.append(time.perf_counter() - t0)
-        return float(np.median(times) * 1e6)
-    except Exception:
-        return None
+    L = 16
+    M = max(1, B // L)
+    V = 256
+    cap = min(cap, K)
+    rng = np.random.default_rng(seed)
+    key = jax.random.PRNGKey(seed)
+    theta = jax.random.dirichlet(key, jnp.full(K, 0.05), (M,))
+    phi = jax.random.dirichlet(
+        jax.random.fold_in(key, 1), jnp.full(V, 0.1), (K,)
+    ).T
+    docs = jnp.asarray(rng.integers(0, V, size=(M, L)), jnp.int32)
+    mask = jnp.ones((M, L), bool)
+    z = jnp.asarray(rng.integers(0, K, size=(M, L)), jnp.int32)
+    doc_topic, _ = _counts_scatter(z, docs, mask, K, V)
+    sp = sparse_counts(doc_topic, cap)
+    tbl_a, tbl_b = word_proposal_tables(phi, "cdf")
+    s = _rng.fold(_rng.seed_from_key(key), _rng.TAG_SPARSE_MH)
+    fn = _mh_sweep_jit(steps, cap, "cdf", min(256, M))
+    args = (
+        z, docs, mask, theta, phi, sp.ids, sp.cnt, tbl_a, tbl_b, s,
+        jnp.uint32(0), jnp.float32(0.1),
+    )
+    for _ in range(max(warmup, 1)):
+        jax.block_until_ready(fn(*args))
+    times = []
+    for _ in range(max(iters, 1)):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*args))
+        times.append(time.perf_counter() - t0)
+    return float(np.median(times) * 1e6)

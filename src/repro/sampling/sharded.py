@@ -38,11 +38,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.6 exports shard_map at top level
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from repro.kernels import rng as _rng
 from repro.sampling import distribution as _dist
 from repro.sampling.distribution import Categorical
@@ -250,12 +245,12 @@ def build_sharded(plan, weights) -> Categorical:
     method, W, tb = plan.table_method, plan.W, plan.tb
     ck = ("build", method, W, tb, plan.shape, mesh_signature(mesh, plan.spec))
     fn = _cached_fn(ck, lambda: jax.jit(
-        _shard_map(
+        jax.shard_map(
             lambda w: _dist._build_state(method, w, W),
             mesh=mesh,
             in_specs=(row_spec(mesh, plan.spec),),
             out_specs=_state_specs(method, mesh, plan.spec),
-            check_rep=False,  # pallas_call has no replication rule
+            check_vma=False,  # pallas_call has no replication rule
         )
     ))
     _dist._note_build()
@@ -298,12 +293,12 @@ def draw_sharded(plan, dist: Categorical, key, num_samples: int = 1):
                 d, sd, _linear_index(mesh, plan.spec) * Bloc, num_samples
             )
 
-        sm = _shard_map(
+        sm = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(_state_specs(method, mesh, plan.spec), P()),
             out_specs=_out_spec(mesh, num_samples, plan.spec),
-            check_rep=False,  # pallas_call has no replication rule
+            check_vma=False,  # pallas_call has no replication rule
         )
         # ONE dispatch per draw: key->seed derivation lives inside the jit
         return jax.jit(lambda state, k: sm(state, _rng.seed_from_key(k)))
@@ -341,12 +336,12 @@ def sample_sharded(plan, weights, key, num_samples: int = 1):
                             tb=tb)
             return _local_draw(d, sd, row0, num_samples)
 
-        sm = _shard_map(
+        sm = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(row_spec(mesh, plan.spec), P()),
             out_specs=_out_spec(mesh, num_samples, plan.spec),
-            check_rep=False,  # pallas_call has no replication rule
+            check_vma=False,  # pallas_call has no replication rule
         )
         return jax.jit(lambda x, k: sm(x, _rng.seed_from_key(k)))
 
@@ -406,12 +401,12 @@ def sample_logits_sharded(plan, logits, key, temperature: float = 1.0,
                             tb=tb)
             return _local_draw(d, sd, row0, num_samples)
 
-        sm = _shard_map(
+        sm = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(row_spec(mesh, plan.spec), P(), P()),
             out_specs=_out_spec(mesh, num_samples, plan.spec),
-            check_rep=False,  # pallas_call has no replication rule
+            check_vma=False,  # pallas_call has no replication rule
         )
         return jax.jit(
             lambda x, t, k: sm(x, t, _rng.seed_from_key(k))
@@ -481,12 +476,12 @@ def sample_logits_truncated_sharded(
             return _local_draw(d, sd, row0, num_samples)
 
         rs = row_spec(mesh, plan.spec)
-        sm = _shard_map(
+        sm = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(rs, rs, rs, P()),
             out_specs=_out_spec(mesh, num_samples, plan.spec),
-            check_rep=False,  # pallas_call has no replication rule
+            check_vma=False,  # pallas_call has no replication rule
         )
         return jax.jit(
             lambda x, t, prm, k: sm(x, t, prm, _rng.seed_from_key(k))

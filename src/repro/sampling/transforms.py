@@ -196,7 +196,7 @@ def _bisect(lo, hi, keep_fn, iters: int):
 
     def body(_, lh):
         lo_b, hi_b = lh
-        mid_b = lo_b + (hi_b - lo_b) // jnp.uint32(2)
+        mid_b = lo_b + ((hi_b - lo_b) >> jnp.uint32(1))
         keep = keep_fn(_b2f(mid_b))
         return jnp.where(keep, mid_b, lo_b), jnp.where(keep, hi_b, mid_b)
 
@@ -207,14 +207,19 @@ def _bisect(lo, hi, keep_fn, iters: int):
 def _above_max(wf):
     """nextafter(rowmax, inf): one bit above the row maximum — the open
     upper end of the threshold bracket."""
-    return _b2f(_f2b(jnp.max(wf, axis=-1)) + jnp.uint32(1))
+    return _b2f(_f2b(jnp.max(wf, axis=-1, keepdims=True)) + jnp.uint32(1))
+
+
+# The stage helpers below work on (B, 1) columns (k/p/tau): the same code
+# then traces inside a TPU kernel body, which has no 1-D vector layouts.
 
 
 def _topk_tau(wf, k, tau0, iters: int):
     hi = _above_max(wf)
 
     def keeps(tau):
-        return jnp.sum((wf >= tau[:, None]).astype(jnp.float32), axis=-1) >= k
+        kept = jnp.sum((wf >= tau).astype(jnp.float32), axis=-1, keepdims=True)
+        return kept >= k
 
     tau = _bisect(tau0, hi, keeps, iters)
     return jnp.where(k > 0, jnp.maximum(tau, tau0), tau0)
@@ -222,18 +227,20 @@ def _topk_tau(wf, k, tau0, iters: int):
 
 def _topp_tau(wf, p, tau0, iters: int):
     hi = _above_max(wf)
-    total = jnp.sum(jnp.where(wf >= tau0[:, None], wf, 0.0), axis=-1)
-    target = p * total
+    def mass(tau):
+        return jnp.sum(jnp.where(wf >= tau, wf, 0.0), axis=-1, keepdims=True)
+
+    target = p * mass(tau0)
 
     def keeps(tau):
-        return jnp.sum(jnp.where(wf >= tau[:, None], wf, 0.0), axis=-1) >= target
+        return mass(tau) >= target
 
     tau = _bisect(tau0, hi, keeps, iters)
     return jnp.where(p < 1.0, jnp.maximum(tau, tau0), tau0)
 
 
 def _minp_tau(wf, p, tau0):
-    rowmax = jnp.max(wf, axis=-1)
+    rowmax = jnp.max(wf, axis=-1, keepdims=True)
     return jnp.where(p > 0.0, jnp.maximum(tau0, p * rowmax), tau0)
 
 
@@ -249,21 +256,21 @@ def thresholds(
     validate(transforms)
     wf = jnp.asarray(weights).astype(jnp.float32)
     B = wf.shape[0]
-    tau = jnp.zeros((B,), jnp.float32)
+    tau = jnp.zeros((B, 1), jnp.float32)
     for t in transforms:
         if isinstance(t, TopK):
-            tau = _topk_tau(wf, _row(t.k, B), tau, iters)
+            tau = _topk_tau(wf, _row(t.k, B)[:, None], tau, iters)
         elif isinstance(t, TopP):
-            tau = _topp_tau(wf, _row(t.p, B), tau, iters)
+            tau = _topp_tau(wf, _row(t.p, B)[:, None], tau, iters)
         elif isinstance(t, MinP):
-            tau = _minp_tau(wf, _row(t.p, B), tau)
+            tau = _minp_tau(wf, _row(t.p, B)[:, None], tau)
         elif isinstance(t, Temperature):
             raise ValueError(
                 "Temperature acts on logits, not weights — fold it via "
                 "apply_to_logits(transforms, logits) or the temperature= "
                 "argument"
             )
-    return tau
+    return tau[:, 0]
 
 
 def apply(weights, transforms: Sequence, iters: int = SEARCH_ITERS):
@@ -350,10 +357,15 @@ def thresholds_from_params(
     """Per-row tau from a (B, 3) ``[k, p, min_p]`` block — the XLA-side
     half of the two-pass kernel route (vocab-scale tiles compute tau here,
     then run masked pass A / masked walk; DESIGN.md §7)."""
+    return threshold_column(weights, params, iters)[:, 0]
+
+
+def threshold_column(weights, params, iters: int = SEARCH_ITERS) -> jnp.ndarray:
+    """:func:`thresholds_from_params` as a (B, 1) column — the form the
+    fused truncated kernels trace in-kernel."""
     wf = jnp.asarray(weights).astype(jnp.float32)
-    B = wf.shape[0]
     params = jnp.asarray(params, jnp.float32)
-    tau = jnp.zeros((B,), jnp.float32)
-    tau = _topk_tau(wf, params[:, 0], tau, iters)
-    tau = _topp_tau(wf, params[:, 1], tau, iters)
-    return _minp_tau(wf, params[:, 2], tau)
+    tau = jnp.zeros((wf.shape[0], 1), jnp.float32)
+    tau = _topk_tau(wf, params[:, 0:1], tau, iters)
+    tau = _topp_tau(wf, params[:, 1:2], tau, iters)
+    return _minp_tau(wf, params[:, 2:3], tau)

@@ -59,11 +59,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.6 exports shard_map at top level
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from repro import sampling
 from repro.kernels import rng as _rng
 from repro.models.model import Model
@@ -110,7 +105,6 @@ class ContinuousBatchingEngine:
         temperature: float = 1.0,
         eos_id: Optional[int] = None,
         mesh=None,
-        cache_dtype=jnp.float32,
     ):
         cfg = model.cfg
         if cfg.encoder_layers > 0 or cfg.frontend_len > 0 or cfg.meta_tokens > 0:
@@ -139,7 +133,10 @@ class ContinuousBatchingEngine:
         self._plan, self._local_plan = self._resolve_plans(B, V)
 
         # the decode cache: (L, B, S, ...) leaves, zero-initialized once;
-        # slot rows are reset in place on every admit
+        # slot rows are reset in place on every admit.  It holds K/V in
+        # the params' dtype, as prefill and ``generate`` produce them, so
+        # the layer scan's carry keeps one dtype
+        cache_dtype = jax.tree.leaves(params)[0].dtype
         self._caches = init_params(
             jax.random.PRNGKey(0), model.cache_specs(B, self.max_len),
             cache_dtype,
@@ -236,10 +233,10 @@ class ContinuousBatchingEngine:
             def local(wm_l, u_l):
                 return _dist.draw(local_plan.build(wm_l), u=u_l)
 
-            return _shard_map(
+            return jax.shard_map(
                 local, mesh=mesh,
                 in_specs=(P(rs[0], None), rs), out_specs=rs,
-                check_rep=False,  # pallas_call has no replication rule
+                check_vma=False,  # pallas_call has no replication rule
             )(wm, u)
 
         @jax.jit

@@ -64,3 +64,27 @@ def test_gibbs_with_fused_kernel():
         state = gibbs_step(state, corpus, method="lda_kernel", W=8)
     p1 = perplexity(state, corpus)
     assert np.isfinite(p1) and p1 < p0
+
+
+def test_long_sample_list_runs_in_smem_sized_chunks(monkeypatch):
+    """A sample list longer than one launch's scalar-prefetch budget (a
+    whole shard of a sharded sweep) draws the same indices in chunks."""
+    from repro.kernels.lda_draw import kernel as lk
+    from repro.kernels.lda_draw.ops import lda_draw_factored
+
+    rng = np.random.default_rng(9)
+    C, V, K, B = 12, 40, 24, 200
+    theta = jnp.array(rng.integers(1, 100, size=(C, K)).astype(np.float32))
+    phi = jnp.array(rng.integers(1, 100, size=(V, K)).astype(np.float32))
+    docs = jnp.array(rng.integers(0, C, size=(B,)), jnp.int32)
+    words = jnp.array(rng.integers(0, V, size=(B,)), jnp.int32)
+    u = jnp.array(rng.uniform(0, 1, size=(B,)).astype(np.float32))
+    whole = np.array(lk.lda_draw_docs_pallas(theta, phi, docs, words, u, W=8))
+    monkeypatch.setattr(lk, "_SMEM_SAMPLES", 64)
+    chunked = np.array(
+        lda_draw_factored(theta, phi, docs, words, u, W=8, impl="pallas")
+    )
+    np.testing.assert_array_equal(chunked, whole)
+    np.testing.assert_array_equal(
+        whole, np.array(lda_draw_ref(theta[docs], phi, words, u))
+    )

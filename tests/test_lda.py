@@ -19,6 +19,40 @@ def small_corpus():
     return synthesize_corpus(seed=0, M=96, V=120, K=8, avg_len=40, max_len=80)
 
 
+def _loop_corpus_docs(seed, M, V, K, avg_len, max_len, zipf_exponent=None):
+    """Reference: one ``rng.choice`` per document, then one per topic the
+    document uses — the loop the bulk generator replaces."""
+    from repro.lda.corpus import _topic_word_dirichlet
+
+    rng = np.random.default_rng(seed)
+    phi = _topic_word_dirichlet(rng, V, K, 0.08, zipf_exponent)
+    theta = rng.dirichlet(np.full(K, 0.25), size=M)
+    lengths = np.clip(rng.poisson(avg_len, size=M), 1, max_len)
+    docs = np.zeros((M, int(lengths.max())), np.int32)
+    for m in range(M):
+        n = lengths[m]
+        topics = rng.choice(K, size=n, p=theta[m])
+        words = np.empty(n, np.int32)
+        for k in np.unique(topics):
+            sel = topics == k
+            words[sel] = rng.choice(V, size=sel.sum(), p=phi[:, k])
+        docs[m, :n] = words
+    return docs
+
+
+@pytest.mark.parametrize("kw", [
+    dict(seed=0, M=96, V=120, K=8, avg_len=40, max_len=80),
+    dict(seed=3, M=300, V=2000, K=64, avg_len=70.5, max_len=307,
+         zipf_exponent=1.05),
+])
+def test_bulk_corpus_matches_per_document_loop(kw):
+    """The bulk generator draws the same corpus, token for token, as the
+    per-document loop for the same seed."""
+    np.testing.assert_array_equal(
+        synthesize_corpus(**kw).docs, _loop_corpus_docs(**kw)
+    )
+
+
 def test_corpus_stats(small_corpus):
     c = small_corpus
     assert c.docs.shape[0] == 96

@@ -479,6 +479,7 @@ SCRIPT = textwrap.dedent(
     out["p1"] = float(perplexity(host, corpus))
     out["theta_spec"] = str(state.theta.sharding.spec)
     out["phi_spec"] = str(state.phi.sharding.spec)
+    out["phi_replicated"] = state.phi.sharding.is_fully_replicated
 
     # mesh helpers on a real multi-device host
     from repro.launch.mesh import make_host_mesh, smallest_fitting_mesh
@@ -510,6 +511,6 @@ def test_sharded_8_devices():
     # the sweep still learns, sharded as declared
     assert res["p1"] < 0.8 * res["p0"], res
     assert "data" in res["theta_spec"], res
-    assert res["phi_spec"] == "PartitionSpec()", res
+    assert res["phi_replicated"] is True, res  # spec P() or P(None, None)
     assert res["host_mesh"] == {"data": 4, "model": 2}
     assert res["small_mesh"] == {"data": 2, "model": 1}

@@ -7,6 +7,7 @@ import json
 
 import numpy as np
 import jax
+import jax.extend
 import jax.numpy as jnp
 import pytest
 
@@ -303,9 +304,9 @@ def _all_avals(jaxpr):
                     seen.append(v.aval)
             for p in eqn.params.values():
                 for sub in jax.tree_util.tree_leaves(
-                    p, is_leaf=lambda x: isinstance(x, jax.core.ClosedJaxpr)
+                    p, is_leaf=lambda x: isinstance(x, jax.extend.core.ClosedJaxpr)
                 ):
-                    if isinstance(sub, jax.core.ClosedJaxpr):
+                    if isinstance(sub, jax.extend.core.ClosedJaxpr):
                         walk(sub.jaxpr)
                     elif hasattr(sub, "eqns"):
                         walk(sub)
