@@ -1,0 +1,23 @@
+"""The serving control: the reference's forward with every weight matrix
+rounded per output channel to fp8 (e4m3), the step below the configuration's
+bfloat16 that would tempt a later change.  It need not decode: at each
+position of the prompts and tokens the program served, the control's own
+choice (its first token where the request was greedy, its top-k where it
+sampled) is judged by the float32 reference in the program's place.
+``patch()`` turns that on in ``bench/refs/qwen3.py``."""
+
+from __future__ import annotations
+
+import contextlib
+
+from bench.harness import load_module
+
+
+@contextlib.contextmanager
+def patch():
+    ref = load_module("refs", "qwen3")
+    ref.CONTROL = True
+    try:
+        yield
+    finally:
+        ref.CONTROL = False
