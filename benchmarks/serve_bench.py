@@ -35,6 +35,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.configs.base import ModelConfig, SamplerSpec, ServeSpec
 from repro.models.model import build_model
 from repro.models.params import init_params
@@ -136,9 +137,11 @@ def summarize(done, rejected, wall, engine):
     }
 
 
-def records_from(engine, summary):
+def records_from(engine, summary, since_ns: int):
     """check_regression-gated rows: median per-decode-step and per-prefill
-    wall time under the open-loop load."""
+    wall time under the open-loop load.  A prefill's time is its
+    ``engine.admit`` span (prefill and insert, up to the host sync), of the
+    admissions that started at or after ``since_ns``."""
     B = engine.max_slots
     K = engine.model.cfg.padded_vocab
     recs = [
@@ -151,9 +154,9 @@ def records_from(engine, summary):
     recs += [
         {
             "method": "serve_prefill", "B": B, "K": K, "W": 0, "devices": 1,
-            "us": p["dt"] * 1e6, "bucket": p["bucket"],
+            "us": (a.end_ns - a.start_ns) * 1e-3, "bucket": a.attrs["bucket"],
         }
-        for p in engine.prefill_times
+        for a in obs.spans("engine.admit") if a.start_ns >= since_ns
     ]
     return recs
 
@@ -169,6 +172,7 @@ def run(n_requests=64, rate=200.0, slots=8, max_len=128, seed=0):
     post_warmup = engine.compile_stats()["decode_step_compiles"]
 
     reqs, arrivals = make_requests(n_requests, rate, max_len, seed=seed)
+    start_ns = time.perf_counter_ns()
     done, rejected, wall = asyncio.run(drive(engine, reqs, arrivals))
 
     summary = summarize(done, rejected, wall, engine)
@@ -178,7 +182,7 @@ def run(n_requests=64, rate=200.0, slots=8, max_len=128, seed=0):
             f"decode step retraced under churn: {post_warmup} -> {compiles} "
             "compiles (the zero-retrace invariant is broken)"
         )
-    return engine, summary
+    return engine, summary, start_ns
 
 
 def main(argv=None):
@@ -199,7 +203,7 @@ def main(argv=None):
         args.requests = min(args.requests, 24)
         args.max_len = min(args.max_len, 64)
 
-    engine, summary = run(
+    engine, summary, start_ns = run(
         n_requests=args.requests, rate=args.rate, slots=args.slots,
         max_len=args.max_len, seed=args.seed,
     )
@@ -226,7 +230,7 @@ def main(argv=None):
                 "slots": args.slots, "max_len": args.max_len,
                 "model": BENCH_CFG.name, "vocab": BENCH_CFG.padded_vocab,
             },
-            "records": records_from(engine, summary),
+            "records": records_from(engine, summary, start_ns),
             "summary": summary,
         }
         with open(args.json, "w") as f:

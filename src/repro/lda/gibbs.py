@@ -49,7 +49,7 @@ from typing import Callable, Dict, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import sampling
+from repro import obs, sampling
 from repro.lda.corpus import Corpus
 
 
@@ -248,28 +248,29 @@ def draw_z(
     chunk's built distribution is kept there across sweeps and refreshed
     in place (the paper's reuse pattern), at the cost of materializing
     flat weights for the non-factored strategies."""
-    if dists is None:
-        return _scan_draw_jit(method, W, chunk)(
-            state.theta, state.phi, docs, state.key
-        )
-    M, maxN = docs.shape
-    keys = jax.random.split(state.key, (M + chunk - 1) // chunk + 1)
-    outs = []
-    for ci, start in enumerate(range(0, M, chunk)):
-        end = min(start + chunk, M)
-        idx, dist = _draw_z_chunk(
-            state.theta[start:end],
-            state.phi,
-            docs[start:end],
-            keys[ci],
-            method=method,
-            W=W,
-            dist=dists.get(start),
-        )
-        if dist is not None:
-            dists[start] = dist
-        outs.append(idx)
-    return jnp.concatenate(outs, axis=0)
+    with obs.span("lda.draw_z"):
+        if dists is None:
+            return _scan_draw_jit(method, W, chunk)(
+                state.theta, state.phi, docs, state.key
+            )
+        M, maxN = docs.shape
+        keys = jax.random.split(state.key, (M + chunk - 1) // chunk + 1)
+        outs = []
+        for ci, start in enumerate(range(0, M, chunk)):
+            end = min(start + chunk, M)
+            idx, dist = _draw_z_chunk(
+                state.theta[start:end],
+                state.phi,
+                docs[start:end],
+                keys[ci],
+                method=method,
+                W=W,
+                dist=dists.get(start),
+            )
+            if dist is not None:
+                dists[start] = dist
+            outs.append(idx)
+        return jnp.concatenate(outs, axis=0)
 
 
 @functools.partial(jax.jit, static_argnames=("K", "V"))
@@ -324,7 +325,11 @@ def gibbs_step(
     (a ``repro.lda.sparse.SparseSweepCache``) on every call so the
     fixed-width sparse doc-topic counts persist across sweeps;
     ``mh_steps``/``word_proposal`` tune the MH chain (see
-    ``sparse.gibbs_step_sparse``)."""
+    ``sparse.gibbs_step_sparse``).
+
+    The host call is an ``lda.sweep`` span (``repro.obs``), indexed by the
+    ``lda.sweeps`` counter, with ``lda.upload`` (corpus to the device) and
+    ``lda.dispatch`` (the sweep's programs) children."""
     if sparse:
         from repro.lda import sparse as _sparse
 
@@ -342,20 +347,23 @@ def gibbs_step(
                 state, corpus, alpha=alpha, beta=beta, mh_steps=mh_steps,
                 word_proposal=word_proposal, cache=sparse_cache, chunk=chunk,
             )
-    docs = jnp.asarray(corpus.docs)
-    mask = jnp.asarray(corpus.mask)
     K = state.theta.shape[-1]
     V = state.phi.shape[0]
-    if dists is None:
-        return _sweep_jit(method, W, chunk, K, V)(
-            state.theta, state.phi, state.z, state.key, state.step,
-            docs, mask, jnp.float32(alpha), jnp.float32(beta),
-        )
-    z = draw_z(state, docs, method=method, W=W, chunk=chunk, dists=dists)
-    doc_topic, word_topic = _counts(z, docs, mask, K, V)
-    k_theta, k_phi, k_next = jax.random.split(state.key, 3)
-    theta = _update_theta(k_theta, doc_topic, alpha)
-    phi = _update_phi(k_phi, word_topic, beta)
+    with obs.span("lda.sweep", index=obs.count("lda.sweeps") - 1):
+        with obs.span("lda.upload"):
+            docs = jnp.asarray(corpus.docs)
+            mask = jnp.asarray(corpus.mask)
+        with obs.span("lda.dispatch"):
+            if dists is None:
+                return _sweep_jit(method, W, chunk, K, V)(
+                    state.theta, state.phi, state.z, state.key, state.step,
+                    docs, mask, jnp.float32(alpha), jnp.float32(beta),
+                )
+            z = draw_z(state, docs, method=method, W=W, chunk=chunk, dists=dists)
+            doc_topic, word_topic = _counts(z, docs, mask, K, V)
+            k_theta, k_phi, k_next = jax.random.split(state.key, 3)
+            theta = _update_theta(k_theta, doc_topic, alpha)
+            phi = _update_phi(k_phi, word_topic, beta)
     return LDAState(theta=theta, phi=phi, z=z, key=k_next, step=state.step + 1)
 
 

@@ -73,6 +73,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.kernels import rng as _rng
 from repro.lda.corpus import Corpus
 from repro.lda.gibbs import LDAState, _update_phi, _update_theta
@@ -434,6 +435,13 @@ def word_proposal_tables(
 # ---------------------------------------------------------------------------
 
 
+def _host_int(x) -> int:
+    """A device scalar read back to the host: an ``lda.sync`` span, in
+    which the host waits for every program queued before it."""
+    with obs.span("lda.sync"):
+        return int(x)
+
+
 def draw_z_sparse(
     state: LDAState,
     docs,
@@ -460,10 +468,10 @@ def draw_z_sparse(
         cache = SparseSweepCache()
     if cache.counts is None or cache.cap is None:
         doc_topic, _ = _counts_scatter(docs=docs, mask=mask, z=state.z, K=K, V=V)
-        cache.update_capacity(int(_nnz_max(doc_topic)))
+        cache.update_capacity(_host_int(_nnz_max(doc_topic)))
         cache.counts = sparse_counts(doc_topic, min(cache.cap, K))
     word_proposal = resolve_word_proposal(
-        word_proposal, K, V, tokens=int(jnp.sum(mask > 0)) * mh_steps
+        word_proposal, K, V, tokens=_host_int(jnp.sum(mask > 0)) * mh_steps
     )
     tbl_a, tbl_b = word_proposal_tables(state.phi, word_proposal)
     seed = _rng.fold(_rng.seed_from_key(state.key), _rng.TAG_SPARSE_MH)
@@ -480,7 +488,7 @@ def draw_z_sparse(
 
 
 def _stats_dict(wa, da, props) -> Dict[str, float]:
-    p = max(int(props), 1)
+    p = max(_host_int(props), 1)
     return {
         "word_accept_rate": float(int(wa) / p),
         "doc_accept_rate": float(int(da) / p),
@@ -512,37 +520,51 @@ def gibbs_step_sparse(
     ``"alias_device"`` rebuilds alias tables in-graph at parallel-sort
     cost — O(1) word proposals even though phi changes every sweep — and
     ``"auto"`` lets the cost model pick per workload (token-heavy sweeps
-    amortize the device build; see :func:`resolve_word_proposal`)."""
-    docs = jnp.asarray(corpus.docs)
-    mask = jnp.asarray(corpus.mask)
+    amortize the device build; see :func:`resolve_word_proposal`).
+
+    The host call is an ``lda.sweep`` span (``repro.obs``) as in the dense
+    ``gibbs_step``; its ``lda.sync`` children are the host readbacks that
+    size the proposal tables and the next sweep's capacity bucket."""
     K = state.theta.shape[-1]
     V = state.phi.shape[0]
     if cache is None:
         cache = SparseSweepCache()
-    if cache.counts is None or cache.cap is None:
-        doc_topic, _ = _counts_scatter(docs=docs, mask=mask, z=state.z, K=K, V=V)
-        cache.update_capacity(int(_nnz_max(doc_topic)))
-        cache.counts = sparse_counts(doc_topic, min(cache.cap, K))
-    word_proposal = resolve_word_proposal(
-        word_proposal, K, V, tokens=int(jnp.sum(mask > 0)) * mh_steps
-    )
-    tbl_a, tbl_b = word_proposal_tables(state.phi, word_proposal)
-    kz, k_theta, k_phi, k_next = jax.random.split(state.key, 4)
-    seed = _rng.fold(_rng.seed_from_key(kz), _rng.TAG_SPARSE_MH)
-    z, wa, da, props = _mh_sweep_jit(
-        mh_steps, min(cache.cap, K), word_proposal, chunk
-    )(
-        state.z, docs, mask, state.theta, state.phi,
-        cache.counts.ids, cache.counts.cnt, tbl_a, tbl_b, seed,
-        jnp.uint32(row0), jnp.float32(alpha),
-    )
-    doc_topic, word_topic = _counts_scatter(z, docs, mask, K, V)
-    theta = _update_theta(k_theta, doc_topic, alpha)
-    phi = _update_phi(k_phi, word_topic, beta)
-    # next sweep's proposal counts (and the capacity bucket they live in)
-    cache.update_capacity(int(_nnz_max(doc_topic)))
-    cache.counts = sparse_counts(doc_topic, min(cache.cap, K))
-    cache.last_stats = _stats_dict(wa, da, props)
+    with obs.span("lda.sweep", index=obs.count("lda.sweeps") - 1):
+        with obs.span("lda.upload"):
+            docs = jnp.asarray(corpus.docs)
+            mask = jnp.asarray(corpus.mask)
+        if cache.counts is None or cache.cap is None:
+            with obs.span("lda.dispatch"):
+                doc_topic, _ = _counts_scatter(
+                    docs=docs, mask=mask, z=state.z, K=K, V=V
+                )
+                nnz = _nnz_max(doc_topic)
+            cache.update_capacity(_host_int(nnz))
+            with obs.span("lda.dispatch"):
+                cache.counts = sparse_counts(doc_topic, min(cache.cap, K))
+        word_proposal = resolve_word_proposal(
+            word_proposal, K, V, tokens=_host_int(jnp.sum(mask > 0)) * mh_steps
+        )
+        with obs.span("lda.dispatch"):
+            tbl_a, tbl_b = word_proposal_tables(state.phi, word_proposal)
+            kz, k_theta, k_phi, k_next = jax.random.split(state.key, 4)
+            seed = _rng.fold(_rng.seed_from_key(kz), _rng.TAG_SPARSE_MH)
+            z, wa, da, props = _mh_sweep_jit(
+                mh_steps, min(cache.cap, K), word_proposal, chunk
+            )(
+                state.z, docs, mask, state.theta, state.phi,
+                cache.counts.ids, cache.counts.cnt, tbl_a, tbl_b, seed,
+                jnp.uint32(row0), jnp.float32(alpha),
+            )
+            doc_topic, word_topic = _counts_scatter(z, docs, mask, K, V)
+            theta = _update_theta(k_theta, doc_topic, alpha)
+            phi = _update_phi(k_phi, word_topic, beta)
+            nnz = _nnz_max(doc_topic)
+        # next sweep's proposal counts (and the capacity bucket they live in)
+        cache.update_capacity(_host_int(nnz))
+        with obs.span("lda.dispatch"):
+            cache.counts = sparse_counts(doc_topic, min(cache.cap, K))
+        cache.last_stats = _stats_dict(wa, da, props)
     return LDAState(theta=theta, phi=phi, z=z, key=k_next, step=state.step + 1)
 
 

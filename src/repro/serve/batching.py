@@ -59,7 +59,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import sampling
+from repro import obs, sampling
 from repro.kernels import rng as _rng
 from repro.models.model import Model
 from repro.models.params import init_params
@@ -83,6 +83,12 @@ def _bucket(n: int) -> int:
     """Smallest power of two >= n (prefill length buckets: bounded trace
     count, log2(max_len) distinct prefill shapes)."""
     return 1 << max(0, int(n - 1).bit_length())
+
+
+def _seed_pair(seed):
+    """A request's (2,) counter-RNG seed pair from its integer seed (a
+    program of its own, ``jit__seed_pair``, apart from the prefill)."""
+    return _rng.fold(_rng.seed_from_key(jax.random.PRNGKey(seed)), _rng.TAG_U)
 
 
 class ContinuousBatchingEngine:
@@ -159,15 +165,11 @@ class ContinuousBatchingEngine:
             lambda p, toks: model.prefill(p, {"tokens": toks})[1]
         )
         self._insert = jax.jit(self._insert_impl)
-        self._seed_pair = jax.jit(
-            lambda s: _rng.fold(
-                _rng.seed_from_key(jax.random.PRNGKey(s)), _rng.TAG_U
-            )
-        )
+        self._seed_pair = jax.jit(_seed_pair)
 
-        # metrics
-        self.step_times: List[Dict] = []     # {"dt": s, "active": n, "tokens": n}
-        self.prefill_times: List[Dict] = []  # {"dt": s, "bucket": n}
+        # metrics: {"dt": s, "active": n, "tokens": n} per decode step, dt
+        # from the start of its engine.step span to its host sync
+        self.step_times: List[Dict] = []
         self._steps = 0
         self._tokens_out = 0
 
@@ -284,6 +286,7 @@ class ContinuousBatchingEngine:
         if req.total_budget > self.max_len:
             req.state = RequestState.REJECTED
             req.finish_reason = FinishReason.REJECTED
+            obs.count("engine.rejected")
             raise ValueError(
                 f"request needs {req.total_budget} KV positions "
                 f"(prompt {req.prompt_len} + max_new {req.max_new_tokens}) "
@@ -295,6 +298,7 @@ class ContinuousBatchingEngine:
             return self.scheduler.submit(req)
         except QueueFullError:
             req.finish_reason = FinishReason.REJECTED
+            obs.count("engine.rejected")
             if req.future is not None and not req.future.done():
                 req.future.set_result(req)
             raise
@@ -323,77 +327,89 @@ class ContinuousBatchingEngine:
                 break
             self._prefill_into(slot, req)
             self.scheduler.bind(slot, req)
+            obs.count("engine.admitted")
             admitted += 1
         return admitted
 
     def _prefill_into(self, slot: int, req: Request) -> None:
+        """Prefill a request's prompt into ``slot`` and set the slot's
+        state.  The ``engine.admit`` span ends at the seed-pair readback,
+        which waits for the prefill and the insert queued before it: the
+        synced edge, stamped as ``req.prefill_time``."""
+        start = time.perf_counter_ns()
+        obs.record("engine.queue", int(req.arrival_time * 1e9), start,
+                   req=req.id)
         req.state = RequestState.PREFILLING
-        t0 = time.perf_counter()
         prefix = req.prompt[:-1]
-        if prefix.size:
-            sb = _bucket(prefix.size)
-            toks = np.zeros((1, sb), np.int32)
-            toks[0, : prefix.size] = prefix
-            pre = self._prefill(self.params, jnp.asarray(toks))
-        else:
-            # single-token prompt: no prefix — the insert still resets
-            # the slot's rows with the zero-length (all-pad) prefix
-            sb = 0
-            pre = self._empty_prefix
-        self._caches = self._insert(self._caches, pre, jnp.int32(slot))
-        req.prefill_time = time.perf_counter()
-        self.prefill_times.append({"dt": req.prefill_time - t0, "bucket": sb})
-        # slot state: the prompt's LAST token runs through the decode step
-        # at position prompt_len-1 (writes its own KV, yields the first
-        # sampled token) — prefill logits are never consumed
-        self._token[slot] = int(req.prompt[-1])
-        self._pos[slot] = req.prompt_len - 1
-        self._seeds[slot] = np.asarray(self._seed_pair(np.uint32(req.seed)))
-        self._draw_idx[slot] = 0
-        sp = req.sampling
-        self._temp[slot] = req.effective_temperature(self.temperature)
-        self._kpm[slot] = (
-            float(sp.top_k or 0),
-            float(1.0 if sp.top_p is None else sp.top_p),
-            float(sp.min_p or 0.0),
-        )
-        self._active[slot] = True
+        sb = _bucket(prefix.size) if prefix.size else 0
+        with obs.span("engine.admit", req=req.id, bucket=sb,
+                      prompt=req.prompt_len) as admit:
+            if prefix.size:
+                toks = np.zeros((1, sb), np.int32)
+                toks[0, : prefix.size] = prefix
+                pre = self._prefill(self.params, jnp.asarray(toks))
+            else:
+                # single-token prompt: no prefix — the insert still resets
+                # the slot's rows with the zero-length (all-pad) prefix
+                pre = self._empty_prefix
+            self._caches = self._insert(self._caches, pre, jnp.int32(slot))
+            # slot state: the prompt's LAST token runs through the decode
+            # step at position prompt_len-1 (writes its own KV, yields the
+            # first sampled token) — prefill logits are never consumed
+            self._token[slot] = int(req.prompt[-1])
+            self._pos[slot] = req.prompt_len - 1
+            self._seeds[slot] = np.asarray(self._seed_pair(np.uint32(req.seed)))
+            self._draw_idx[slot] = 0
+            sp = req.sampling
+            self._temp[slot] = req.effective_temperature(self.temperature)
+            self._kpm[slot] = (
+                float(sp.top_k or 0),
+                float(1.0 if sp.top_p is None else sp.top_p),
+                float(sp.min_p or 0.0),
+            )
+            self._active[slot] = True
+        req.prefill_time = admit.end_ns * 1e-9
 
     def step_once(self) -> int:
         """One batched decode step over every slot.  Returns the number of
         live tokens produced (0 when no slot is active)."""
         if not self._active.any():
             return 0
-        t0 = time.perf_counter()
-        nxt, self._caches = self._step(
-            self.params, self._caches,
-            jnp.asarray(self._token), jnp.asarray(self._pos),
-            jnp.asarray(self._seeds), jnp.asarray(self._draw_idx),
-            jnp.asarray(self._temp), jnp.asarray(self._kpm),
-        )
-        nxt_np = np.asarray(nxt)  # host sync: the step's wall-clock edge
-        now = time.perf_counter()
         live = int(self._active.sum())
+        with obs.span("engine.step", index=self._steps, live=live) as step:
+            with obs.span("engine.step.dispatch"):
+                nxt, self._caches = self._step(
+                    self.params, self._caches,
+                    jnp.asarray(self._token), jnp.asarray(self._pos),
+                    jnp.asarray(self._seeds), jnp.asarray(self._draw_idx),
+                    jnp.asarray(self._temp), jnp.asarray(self._kpm),
+                )
+            with obs.span("engine.step.wait") as wait:
+                nxt_np = np.asarray(nxt)  # host sync: the step's wall-clock edge
+            now = wait.end_ns * 1e-9
+            with obs.span("engine.step.walk"):
+                for slot in np.nonzero(self._active)[0]:
+                    req = self.scheduler.bound(int(slot))
+                    tok = int(nxt_np[slot])
+                    if not req.output_tokens:
+                        req.first_token_time = now
+                    req.output_tokens.append(tok)
+                    req.token_times.append(now)
+                    self._token[slot] = tok
+                    self._pos[slot] += 1
+                    self._draw_idx[slot] += 1
+                    eos = req.eos_id if req.eos_id is not None else self.eos_id
+                    if eos is not None and tok == eos:
+                        self._finish(int(slot), FinishReason.EOS)
+                    elif len(req.output_tokens) >= req.max_new_tokens:
+                        self._finish(int(slot), FinishReason.LENGTH)
         self.step_times.append(
-            {"dt": now - t0, "active": live, "tokens": live}
+            {"dt": (wait.end_ns - step.start_ns) * 1e-9, "active": live,
+             "tokens": live}
         )
         self._steps += 1
         self._tokens_out += live
-        for slot in np.nonzero(self._active)[0]:
-            req = self.scheduler.bound(int(slot))
-            tok = int(nxt_np[slot])
-            if not req.output_tokens:
-                req.first_token_time = now
-            req.output_tokens.append(tok)
-            req.token_times.append(now)
-            self._token[slot] = tok
-            self._pos[slot] += 1
-            self._draw_idx[slot] += 1
-            eos = req.eos_id if req.eos_id is not None else self.eos_id
-            if eos is not None and tok == eos:
-                self._finish(int(slot), FinishReason.EOS)
-            elif len(req.output_tokens) >= req.max_new_tokens:
-                self._finish(int(slot), FinishReason.LENGTH)
+        obs.count("engine.tokens", live)
         return live
 
     def _finish(self, slot: int, reason: FinishReason) -> None:
@@ -482,7 +498,6 @@ class ContinuousBatchingEngine:
 
     def reset_metrics(self) -> None:
         self.step_times.clear()
-        self.prefill_times.clear()
         self._steps = 0
         self._tokens_out = 0
 

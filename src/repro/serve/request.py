@@ -73,7 +73,7 @@ class Request:
 
     # timestamps (perf_counter seconds; -1.0 = not reached)
     arrival_time: float = -1.0
-    prefill_time: float = -1.0
+    prefill_time: float = -1.0              # prefill and insert done (synced)
     first_token_time: float = -1.0
     finish_time: float = -1.0
     token_times: List[float] = dataclasses.field(default_factory=list)
