@@ -52,9 +52,10 @@ Q_CHUNK = 256
 
 
 def _repeat_kv(k, H):
-    """(B,S,KV,hd) -> (B,S,H,hd).  Keeping q heads intact (no KV x G split)
-    lets GSPMD shard H cleanly; the repeat materializes only each shard's
-    own head group."""
+    """(B,S,KV,hd) -> (B,S,H,hd): every query head gets its own copy of
+    its group's K/V head.  Only ``_sdpa_chunked`` (prompts above
+    ``CHUNKED_THRESHOLD``) repeats; ``_sdpa`` reads the K/V heads as
+    stored."""
     KV = k.shape[2]
     if KV == H:
         return k
@@ -64,6 +65,10 @@ def _repeat_kv(k, H):
 def _sdpa(q, k, v, mask, softcap: float = 0.0, kv_sharded: bool = False):
     """q (B,Sq,H,hd)  k (B,Sk,KV,hd)  v (B,Sk,KV,hv) -> (B,Sq,H,hv).
 
+    Grouped-query contraction: q splits into (KV, G = H // KV) and each
+    group's G heads contract against their K/V head as stored, so a
+    decode step reads the cache once instead of copying it G times.
+
     fp32 scores/softmax; bf16 inputs stay bf16 on the contraction output.
     ``kv_sharded``: pin the score matrix's key axis to the cache's seq
     sharding (flash-decoding layout) so GSPMD reduces with tiny psums
@@ -71,21 +76,24 @@ def _sdpa(q, k, v, mask, softcap: float = 0.0, kv_sharded: bool = False):
     """
     from repro.dist.sharding import constrain_activation
 
-    H = q.shape[2]
-    k, v = _repeat_kv(k, H), _repeat_kv(v, H)
-    scale = 1.0 / jnp.sqrt(jnp.float32(q.shape[-1]))
-    scores = jnp.einsum("bqhe,bshe->bhqs", q, k).astype(jnp.float32) * scale
+    B, Sq, H, hd = q.shape
+    KV = k.shape[2]
+    qg = q.reshape(B, Sq, KV, H // KV, hd)
+    scale = 1.0 / jnp.sqrt(jnp.float32(hd))
+    scores = jnp.einsum("bqkge,bske->bkgqs", qg, k).astype(jnp.float32) * scale
     if kv_sharded:
-        scores = constrain_activation(scores, ("batch", None, None, "act_kv"))
+        scores = constrain_activation(scores, ("batch", None, None, None, "act_kv"))
     if softcap > 0:
         scores = jnp.tanh(scores / softcap) * softcap
     # (Sq, Sk) masks broadcast over batch; (B, Sq, Sk) masks are per-row
     # (continuous batching: each slot attends its own prefix length)
     scores = jnp.where(
-        mask[None, None] if mask.ndim == 2 else mask[:, None], scores, NEG_INF
+        mask[None, None, None] if mask.ndim == 2 else mask[:, None, None],
+        scores, NEG_INF,
     )
     probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
-    return jnp.einsum("bhqs,bshv->bqhv", probs, v)
+    out = jnp.einsum("bkgqs,bskv->bqkgv", probs, v)
+    return out.reshape(B, Sq, H, v.shape[-1])
 
 
 def _cache_update(cache_arr, new, pos):

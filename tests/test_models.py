@@ -2,6 +2,12 @@
 implementations of the same math."""
 
 import dataclasses
+import json
+import os
+import re
+import subprocess
+import sys
+import textwrap
 
 import numpy as np
 import jax
@@ -217,3 +223,119 @@ class TestDecodeConsistency:
         logits_dec, _ = m.decode(params, caches, toks[:, S:], jnp.int32(S))
         got = np.array(logits_dec)
         np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
+
+
+def _gqa_decode_inputs(num_kv_heads, B=2, S=16):
+    """A small GQA decoder (8 query heads of 16) and one decode step's
+    inputs with per-row positions, as continuous batching feeds it."""
+    cfg = ModelConfig(name="t", family="dense", num_layers=2, d_model=32,
+                      num_heads=8, num_kv_heads=num_kv_heads, head_dim=16,
+                      d_ff=32, vocab_size=V, qk_norm=True)
+    m = build_model(cfg)
+    params = init_params(jax.random.PRNGKey(0), m.specs, jnp.float32)
+    caches = init_cache(jax.random.PRNGKey(0), m.cache_specs(B, S), jnp.float32)
+    leaves, tdef = jax.tree.flatten(caches)
+    keys = jax.random.split(jax.random.PRNGKey(1), len(leaves))
+    caches = jax.tree.unflatten(
+        tdef, [jax.random.normal(k, x.shape, x.dtype) for k, x in zip(keys, leaves)]
+    )
+    toks = _toks(B, 1, seed=3)
+    pos = jnp.array([5, S - 1][:B], jnp.int32)
+    return cfg, m, (params, caches, toks, pos)
+
+
+class TestGroupedDecode:
+    """Decode attention contracts each group of H // KV query heads against
+    its K/V head as stored, with or without an activation mesh."""
+
+    def test_decode_step_reads_kv_heads_unrepeated(self, monkeypatch):
+        from repro.models import attention
+
+        cfg, m, args = _gqa_decode_inputs(num_kv_heads=2)
+        B, S = args[1]["attn"]["k"].shape[1:3]
+        H, KV, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
+        repeated = re.compile(
+            rf"broadcast_in_dim.*-> tensor<({B}x{S}x{H}x{hd}|{B}x{S}x{KV}x{H // KV}x{hd})x"
+        )
+
+        def lower_and_run():
+            step = jax.jit(lambda *a: m.decode(*a))  # traced afresh
+            hlo = step.lower(*args).as_text()
+            logits, _ = step(*args)
+            return hlo, np.asarray(logits)
+
+        hlo, got = lower_and_run()
+        assert not repeated.search(hlo)
+
+        # the repeat form: K/V copied to every query head, one head a group
+        grouped = attention._sdpa
+        monkeypatch.setattr(
+            attention, "_sdpa",
+            lambda q, k, v, *a, **kw: grouped(
+                q, attention._repeat_kv(k, H), attention._repeat_kv(v, H), *a, **kw),
+        )
+        hlo_rep, want = lower_and_run()
+        assert repeated.search(hlo_rep)
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+    def test_head_sharded_mesh_matches_unsharded(self):
+        """4 virtual devices on the model axis, parameters and caches placed
+        by their logical axes: wq shards the H = 8 query heads 4 ways.  For
+        KV = 2 each group of 4 heads spans two shards (K/V replicated, the
+        cache sharded by position), for KV = 4 it stays on one (K/V heads
+        sharded).  Either way the decode step's logits, and the full causal
+        forward's, equal those without a mesh."""
+        script = textwrap.dedent(
+            """
+            import os
+            os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+            import json, sys
+            import numpy as np
+            import jax
+            sys.path.insert(0, os.environ["TESTS_DIR"])
+            from test_models import _gqa_decode_inputs, _toks
+            from repro.dist import sharding as shd
+            from repro.models import logical_axes
+
+            from jax.sharding import Mesh
+
+            mesh = Mesh(np.array(jax.devices()).reshape(1, 4), ("data", "model"))
+
+            def run(m, params, caches, toks, pos):
+                # fresh functions: the mesh is read when they are traced
+                dec = jax.jit(lambda *a: m.decode(*a))(params, caches, toks, pos)[0]
+                fwd = jax.jit(m.apply)(params, {"tokens": _toks(2, 16, seed=4)})
+                return [np.asarray(x) for x in jax.tree.leaves((dec, fwd))]
+
+            out = {}
+            for kv in (2, 4):
+                _, m, (params, caches, toks, pos) = _gqa_decode_inputs(num_kv_heads=kv)
+                plain = run(m, params, caches, toks, pos)
+                B, S = caches["attn"]["k"].shape[1:3]
+                params = jax.device_put(
+                    params, shd.tree_shardings(params, logical_axes(m.specs), mesh))
+                caches = jax.device_put(caches, shd.tree_shardings(
+                    caches, logical_axes(m.cache_specs(B, S)), mesh))
+                shd.set_activation_sharding(mesh)
+                try:
+                    sharded = run(m, params, caches, toks, pos)
+                finally:
+                    shd.set_activation_sharding(None)
+                out[kv] = {
+                    "wq": str(params["layers"]["attn"]["wq"].sharding.spec),
+                    "max_diff": max(float(np.max(np.abs(a - b)))
+                                    for a, b in zip(sharded, plain)),
+                }
+            print(json.dumps(out))
+            """
+        )
+        here = os.path.dirname(os.path.abspath(__file__))
+        env = dict(os.environ, TESTS_DIR=here, JAX_PLATFORMS="cpu",
+                   PYTHONPATH=os.path.join(os.path.dirname(here), "src"))
+        res = subprocess.run([sys.executable, "-c", script], env=env,
+                             capture_output=True, text=True, timeout=600)
+        assert res.returncode == 0, res.stderr[-3000:]
+        out = json.loads(res.stdout.strip().splitlines()[-1])
+        for kv in ("2", "4"):
+            assert "'model'" in out[kv]["wq"], out
+            assert out[kv]["max_diff"] < 2e-5, out
