@@ -28,6 +28,8 @@ _ARCH_MODULES = {
     "arctic-480b": "arctic_480b",
     "granite-moe-1b-a400m": "granite_moe_1b_a400m",
     "pixtral-12b": "pixtral_12b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
+    "moonlight-16b-a3b-ep8": "moonlight_16b_a3b_ep8",
 }
 
 ARCH_IDS = tuple(_ARCH_MODULES)

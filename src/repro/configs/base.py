@@ -63,26 +63,47 @@ class ServeSpec:
 
 @dataclasses.dataclass(frozen=True)
 class MLAConfig:
-    q_lora_rank: int = 768
+    # None: queries projected straight from x (``wq``), no compression
+    q_lora_rank: Optional[int] = 768
     kv_lora_rank: int = 256
     qk_nope_head_dim: int = 64
     qk_rope_head_dim: int = 32
     v_head_dim: int = 64
+    # DeepSeek-V3 pairs the rope dims (2i, 2i+1): it de-interleaves them
+    # before the half-split rotation
+    rope_interleave: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
 class MoEConfig:
-    num_experts: int = 8
+    num_experts: int = 8          # the router's width
     top_k: int = 2
     expert_d_ff: int = 1024
+    # training dispatch: tokens per expert slot; 0 = no capacity (every
+    # layer runs the drop-free form of ``moe.moe_held``)
     capacity_factor: float = 1.25
     # routing group size in tokens: capacity (and the dispatch one-hots)
     # are per-group, bounding dispatch memory at O(T * group * k * cf)
     # regardless of sequence length
     group_tokens: int = 4096
-    # Arctic-style parallel dense residual MLP (0 disables)
+    # Arctic-style parallel dense residual MLP, or DeepSeek's shared
+    # experts as one MLP of their summed width (0 disables)
     dense_residual_d_ff: int = 0
     router_z_loss: float = 1e-3
+    # routing: scores softmax | sigmoid over all num_experts; choice on
+    # score + a learned per-expert bias (DeepSeek-V3 noaux_tc) when
+    # ``score_bias``; weights are the chosen scores, renormalised, times
+    # ``routed_scaling``
+    scoring: str = "softmax"
+    score_bias: bool = False
+    routed_scaling: float = 1.0
+    # the experts this chip holds: the first num_held of num_experts
+    # (expert parallelism; 0 = all)
+    num_held: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.num_held or self.num_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -119,6 +140,9 @@ class ModelConfig:
     tie_embeddings: bool = False
     embedding_scale: bool = False           # gemma2: x * sqrt(d_model)
     post_norms: bool = False                # gemma2 post-attn/post-ffn norms
+    # moe: leading layers with a dense MLP of d_ff in place of the experts
+    # (DeepSeek's first_k_dense_replace)
+    first_k_dense: int = 0
     mla: Optional[MLAConfig] = None
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None

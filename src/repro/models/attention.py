@@ -315,12 +315,18 @@ def mla_spec(cfg: ModelConfig) -> Dict[str, ParamSpec]:
     m: MLAConfig = cfg.mla
     d, h = cfg.d_model, cfg.num_heads
     qk = m.qk_nope_head_dim
+    if m.q_lora_rank is None:
+        q = {"wq": ParamSpec((d, h, qk + m.qk_rope_head_dim), ("embed", "heads", "head"))}
+    else:
+        q = {
+            "wdq": ParamSpec((d, m.q_lora_rank), ("embed", "q_lora")),
+            "q_norm": {"scale": ParamSpec((m.q_lora_rank,), ("q_lora",), init="ones")},
+            "wuq": ParamSpec(
+                (m.q_lora_rank, h, qk + m.qk_rope_head_dim), ("q_lora", "heads", "head")
+            ),
+        }
     return {
-        "wdq": ParamSpec((d, m.q_lora_rank), ("embed", "q_lora")),
-        "q_norm": {"scale": ParamSpec((m.q_lora_rank,), ("q_lora",), init="ones")},
-        "wuq": ParamSpec(
-            (m.q_lora_rank, h, qk + m.qk_rope_head_dim), ("q_lora", "heads", "head")
-        ),
+        **q,
         "wdkv": ParamSpec(
             (d, m.kv_lora_rank + m.qk_rope_head_dim), ("embed", "kv_lora")
         ),
@@ -331,14 +337,28 @@ def mla_spec(cfg: ModelConfig) -> Dict[str, ParamSpec]:
     }
 
 
+# the latent (and query-latent) RMSNorms: DeepSeek-V2/V3 build them with
+# their norm's default eps, not the config's rms_norm_eps
+MLA_NORM_EPS = 1e-6
+
+
 def _mla_latents(params, x, positions, cfg: ModelConfig):
     """x -> (c_kv (B,S,r), k_pe (B,S,rope)) with norm + RoPE applied."""
     m: MLAConfig = cfg.mla
     dkv = jnp.einsum("bsd,dr->bsr", x, params["wdkv"])
     c_kv, k_pe = dkv[..., : m.kv_lora_rank], dkv[..., m.kv_lora_rank :]
-    c_kv = _vec_rmsnorm(params["kv_norm"], c_kv, cfg.norm_eps)
-    k_pe = apply_rope(k_pe, positions, cfg.rope_theta)
-    return c_kv, k_pe
+    c_kv = _vec_rmsnorm(params["kv_norm"], c_kv, MLA_NORM_EPS)
+    return c_kv, _mla_rope(k_pe, positions, cfg)
+
+
+def _mla_rope(x, positions, cfg: ModelConfig):
+    """RoPE on the rope part of q or k.  With ``rope_interleave`` the dims
+    are first de-interleaved (pair 2i, 2i+1 becomes i, i + rope/2), as the
+    published DeepSeek-V3 code does before its half-split rotation."""
+    if cfg.mla.rope_interleave:
+        r = x.shape[-1]
+        x = x.reshape(x.shape[:-1] + (r // 2, 2)).swapaxes(-1, -2).reshape(x.shape)
+    return apply_rope(x, positions, cfg.rope_theta)
 
 
 def _vec_rmsnorm(p, x, eps):
@@ -349,10 +369,13 @@ def _vec_rmsnorm(p, x, eps):
 
 def _mla_queries(params, x, positions, cfg: ModelConfig):
     m: MLAConfig = cfg.mla
-    cq = _vec_rmsnorm(params["q_norm"], jnp.einsum("bsd,dr->bsr", x, params["wdq"]), cfg.norm_eps)
-    q = jnp.einsum("bsr,rnh->bsnh", cq, params["wuq"])
+    if m.q_lora_rank is None:
+        q = jnp.einsum("bsd,dnh->bsnh", x, params["wq"])
+    else:
+        cq = _vec_rmsnorm(params["q_norm"], jnp.einsum("bsd,dr->bsr", x, params["wdq"]), MLA_NORM_EPS)
+        q = jnp.einsum("bsr,rnh->bsnh", cq, params["wuq"])
     q_nope = q[..., : m.qk_nope_head_dim]
-    q_pe = apply_rope(q[..., m.qk_nope_head_dim :], positions, cfg.rope_theta)
+    q_pe = _mla_rope(q[..., m.qk_nope_head_dim :], positions, cfg)
     return q_nope, q_pe
 
 
@@ -403,10 +426,11 @@ def mla_attend_decode(params, x, cache, cache_pos, cfg: ModelConfig):
     q_nope, q_pe = _mla_queries(params, x, positions, cfg)
     q_c = jnp.einsum("bsnh,rnh->bsnr", q_nope, params["wuk"])
     scale = 1.0 / jnp.sqrt(jnp.float32(m.qk_nope_head_dim + m.qk_rope_head_dim))
+    f32 = jnp.float32
     scores = (
-        jnp.einsum("bsnr,btr->bnst", q_c, c_kv)
-        + jnp.einsum("bsnh,bth->bnst", q_pe, k_pe)
-    ).astype(jnp.float32) * scale
+        jnp.einsum("bsnr,btr->bnst", q_c, c_kv, preferred_element_type=f32)
+        + jnp.einsum("bsnh,bth->bnst", q_pe, k_pe, preferred_element_type=f32)
+    ) * scale
     k_pos = jnp.arange(c_kv.shape[1])
     if per_row:
         valid = k_pos[None, :] <= cache_pos[:, None]          # (B, T)

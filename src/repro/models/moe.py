@@ -16,6 +16,15 @@ the MODEL_FLOPS/HLO_FLOPs ratio (EXPERIMENTS.md §Roofline).
 same cumsum, then scatter-add dispatch / gather combine.  Identical
 semantics (same capacity dropping, same priority), no fake FLOPs.
 
+``moe_held`` (serving, and training where ``capacity_factor`` is 0):
+drop-free.  Every token is routed over all ``num_experts`` with no
+capacity, and the layer computes the part of the result that the experts
+it holds give (expert parallelism: experts 0 .. ``num_held`` - 1; the
+exchange with the chips that hold the rest is not part of it).  Each held
+expert's weights are read once and run on every token, with weight zero
+where the token did not choose it, so each row's output depends on that
+row alone.
+
 Routing is deterministic top-k — NOT sampling; the paper's butterfly
 sampler is deliberately not used here (DESIGN.md §4).
 """
@@ -34,14 +43,19 @@ from repro.models.params import ParamSpec
 
 
 def moe_spec(cfg: ModelConfig) -> Dict[str, ParamSpec]:
+    """The router over all ``num_experts`` and the weights of the held
+    experts only."""
     m: MoEConfig = cfg.moe
-    d, e, f = cfg.d_model, m.num_experts, m.expert_d_ff
-    return {
-        "router": ParamSpec((d, e), ("embed", None), scale=0.02),
+    d, e, f = cfg.d_model, m.held, m.expert_d_ff
+    spec = {
+        "router": ParamSpec((d, m.num_experts), ("embed", None), scale=0.02),
         "w_gate": ParamSpec((e, d, f), ("experts", "embed", "mlp")),
         "w_up": ParamSpec((e, d, f), ("experts", "embed", "mlp")),
         "w_down": ParamSpec((e, f, d), ("experts", "mlp", "embed")),
     }
+    if m.score_bias:
+        spec["bias"] = ParamSpec((m.num_experts,), (None,), scale=0.02)
+    return spec
 
 
 def _group(T: int, m: MoEConfig) -> Tuple[int, int]:
@@ -55,14 +69,32 @@ def _capacity(g: int, m: MoEConfig) -> int:
     return max(int(np.ceil(m.capacity_factor * g * m.top_k / m.num_experts)), 1)
 
 
-def _route(params, xg, m: MoEConfig):
-    """xg (G, g, D) -> gates (G, g, k), ids (G, g, k), aux loss (scalar)."""
+def route(params, x, m: MoEConfig):
+    """The router over all ``num_experts``, float32, x (..., D) ->
+    (logits, scores (..., E), ids, gates (..., k)).  Scores are the softmax
+    or sigmoid of the logits; the top ``top_k`` are chosen on score (+ the
+    per-expert bias when ``score_bias``); their gates are the unbiased
+    scores, renormalised, times ``routed_scaling``.  Every dispatch routes
+    through it."""
     logits = jnp.einsum(
-        "Gtd,de->Gte", xg.astype(jnp.float32), params["router"].astype(jnp.float32)
+        "...d,de->...e", x.astype(jnp.float32), params["router"].astype(jnp.float32)
     )
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, ids = jax.lax.top_k(probs, m.top_k)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    if m.scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+    choice = scores + params["bias"].astype(jnp.float32) if m.score_bias else scores
+    _, ids = jax.lax.top_k(choice, m.top_k)
+    gates = jnp.take_along_axis(scores, ids, axis=-1)
+    gates = gates / (gates.sum(-1, keepdims=True) + 1e-20) * m.routed_scaling
+    return logits, scores, ids, gates
+
+
+def _route(params, xg, m: MoEConfig):
+    """xg (G, g, D) -> gates (G, g, k), ids (G, g, k), aux loss (scalar):
+    ``route`` and the load-balance and z losses."""
+    logits, scores, ids, gates = route(params, xg, m)
+    probs = scores if m.scoring == "softmax" else scores / scores.sum(-1, keepdims=True)
     E = probs.shape[-1]
     assign1 = jax.nn.one_hot(ids[..., 0], E, dtype=jnp.float32)
     aux = E * jnp.sum(assign1.mean((0, 1)) * probs.mean((0, 1)))
@@ -139,9 +171,33 @@ def moe_block(
     dispatch_mode: str = "einsum",
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """x (B,S,D) -> (y (B,S,D), aux_loss scalar)."""
+    if cfg.moe.held != cfg.moe.num_experts:
+        raise ValueError("capacity dispatch needs every expert held")
     B, S, D = x.shape
     G, g = _group(B * S, cfg.moe)
     xg = x.reshape(G, g, D)
     fn = _moe_einsum if dispatch_mode == "einsum" else _moe_gather
     y, aux = fn(params, xg, cfg.moe, cfg.act)
     return y.reshape(B, S, D), aux
+
+
+def route_weights(params, x, m: MoEConfig) -> jnp.ndarray:
+    """Per-token routing weights over all experts, float32 (..., E): the
+    chosen experts' gates (``route``), zero elsewhere."""
+    _, _, ids, gates = route(params, x, m)
+    onehot = jax.nn.one_hot(ids, m.num_experts, dtype=jnp.float32)
+    return jnp.einsum("...ke,...k->...e", onehot, gates)
+
+
+def moe_held(params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
+    """Drop-free routed experts, x (B,S,D) -> y (B,S,D): the held experts'
+    part of the routed sum for every token (see the module docstring)."""
+    m: MoEConfig = cfg.moe
+    with jax.named_scope("moe.route"):
+        w = route_weights(params, x, m)[..., : m.held]
+    with jax.named_scope("moe.held"):
+        gate = _act(cfg.act)(jnp.einsum("bsd,edf->bsef", x, params["w_gate"]))
+        up = jnp.einsum("bsd,edf->bsef", x, params["w_up"])
+        out = jnp.einsum("bsef,efd->bsed", gate * up, params["w_down"])
+        y = jnp.einsum("bsed,bse->bsd", out.astype(jnp.float32), w)
+    return y.astype(x.dtype)

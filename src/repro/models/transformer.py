@@ -5,11 +5,14 @@ Scan keeps the HLO a single layer wide — compile times at 512 devices stay
 flat in depth — and params/caches are stacked (L, ...) pytrees, which is
 also the checkpoint layout.  Per-layer attention windows are data (an
 int32 xs vector), not structure, so gemma2's local/global alternation and
-hymba's 3-full-attention pattern don't change the traced graph.
+hymba's 3-full-attention pattern don't change the traced graph.  An MoE
+model's leading dense layers (``first_k_dense``) are a stack of their own,
+``lead``, scanned before ``layers``.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Dict, Optional, Tuple
 
@@ -87,11 +90,10 @@ def layer_cache_spec(cfg: ModelConfig, batch: int, max_len: int) -> Dict:
 
 def _attn_branch(p, h, positions, window, cfg, cache, cache_pos):
     if cfg.attention == "mla":
-        if cache is None:
-            y, c = attn.mla_attend_full(p, h, positions, cfg)
-        else:
-            y, c = attn.mla_attend_decode(p, h, cache, cache_pos, cfg)
-        return y, c
+        with jax.named_scope("mla"):
+            if cache is None:
+                return attn.mla_attend_full(p, h, positions, cfg)
+            return attn.mla_attend_decode(p, h, cache, cache_pos, cfg)
     if cache is None:
         y, kv = attn.gqa_attend(p, h, positions, cfg, causal=True, window=window)
         return y, ({"k": kv[0], "v": kv[1]} if kv is not None else None)
@@ -109,8 +111,10 @@ def layer_apply(
     window: jnp.ndarray,
     cache: Optional[Dict] = None,
     cache_pos: Optional[jnp.ndarray] = None,
+    serve: bool = False,
 ) -> Tuple[jnp.ndarray, Optional[Dict], jnp.ndarray]:
-    """One block.  Returns (x, cache_out, aux_loss)."""
+    """One block.  Returns (x, cache_out, aux_loss).  ``serve`` (prefill
+    and decode) routes MoE layers drop-free (``moe.moe_held``)."""
     aux = jnp.float32(0.0)
     cache_out: Dict = {}
     attn_cache = None if cache is None else cache.get("attn")
@@ -126,10 +130,14 @@ def layer_apply(
             cache_out["attn"] = c
         h = rmsnorm(p["ln_mlp"], x, cfg.norm_eps)
         if cfg.family == "moe":
-            y, aux_l = moe_mod.moe_block(p["moe"], h, cfg, cfg.moe_dispatch)
-            aux = aux + aux_l
+            if serve or cfg.moe.capacity_factor <= 0:
+                y = moe_mod.moe_held(p["moe"], h, cfg)
+            else:
+                y, aux_l = moe_mod.moe_block(p["moe"], h, cfg, cfg.moe_dispatch)
+                aux = aux + aux_l
             if cfg.moe.dense_residual_d_ff > 0:
-                y = y + mlp(p["dense_mlp"], h, cfg.act)
+                with jax.named_scope("moe.shared"):
+                    y = y + mlp(p["dense_mlp"], h, cfg.act)
         else:
             y = mlp(p["mlp"], h, cfg.act)
         if cfg.post_norms:
@@ -187,12 +195,34 @@ def layer_windows(cfg: ModelConfig) -> np.ndarray:
 # ---------------------------------------------------------------------------
 
 
+def stacks(cfg: ModelConfig) -> Tuple[Tuple[str, ModelConfig], ...]:
+    """(params key, config) of each scanned stack, in order: the leading
+    dense layers as ``lead`` (when ``first_k_dense``), the rest as
+    ``layers``."""
+    k = cfg.first_k_dense
+    if k == 0:
+        return (("layers", cfg),)
+    lead = dataclasses.replace(cfg, family="dense", moe=None, num_layers=k, first_k_dense=0)
+    rest = dataclasses.replace(cfg, num_layers=cfg.num_layers - k, first_k_dense=0)
+    return (("lead", lead), ("layers", rest))
+
+
 def stack_specs(cfg: ModelConfig) -> Dict:
     return stack_specs_tree(layer_spec(cfg), cfg.num_layers)
 
 
+def _stored(cfg: ModelConfig, per_stack: Dict) -> Dict:
+    """Caches as the engine holds them, from one tree per stack: keyed by
+    stack with leading dense layers, else the one stack's tree itself."""
+    return per_stack if cfg.first_k_dense else per_stack["layers"]
+
+
 def stack_cache_specs(cfg: ModelConfig, batch: int, max_len: int) -> Dict:
-    return stack_specs_tree(layer_cache_spec(cfg, batch, max_len), cfg.num_layers)
+    """Stacked (L, B, S, ...) caches (``_stored``)."""
+    return _stored(cfg, {
+        key: stack_specs_tree(layer_cache_spec(c, batch, max_len), c.num_layers)
+        for key, c in stacks(cfg)
+    })
 
 
 def stack_apply(
@@ -220,7 +250,8 @@ def stack_apply(
         if x.shape[1] > 1:  # not decode: allow seq-sharded saved carries
             x = constrain_activation(x, ("batch", "act_seq", None))
         x, cache_out, aux_l = layer_apply(
-            cfg, lp, x, positions, w, cache=lcache, cache_pos=cache_pos
+            cfg, lp, x, positions, w, cache=lcache, cache_pos=cache_pos,
+            serve=collect_cache or caches is not None,
         )
         ys = cache_out if (collect_cache or caches is not None) else None
         return (x, aux + aux_l), ys
@@ -237,6 +268,21 @@ def stack_apply(
     return x, caches_out, aux
 
 
+def lm_stacks(cfg, params, x, positions, caches=None, cache_pos=None,
+              collect_cache=False, remat="full"):
+    """``stack_apply`` over each of ``stacks(cfg)`` in turn."""
+    if caches is not None and not cfg.first_k_dense:
+        caches = {"layers": caches}
+    outs, auxs = {}, []
+    for key, c in stacks(cfg):
+        x, outs[key], a = stack_apply(
+            c, params[key], x, positions, None if caches is None else caches[key],
+            cache_pos, collect_cache, remat)
+        auxs.append(a)
+    caches_out = _stored(cfg, outs) if collect_cache or caches is not None else None
+    return x, caches_out, sum(auxs[1:], auxs[0])
+
+
 # ---------------------------------------------------------------------------
 # LM heads: specs + three entry points
 # ---------------------------------------------------------------------------
@@ -244,11 +290,9 @@ def stack_apply(
 
 def lm_specs(cfg: ModelConfig) -> Dict:
     d = cfg.d_model
-    spec = {
-        "embed": embedding_spec(cfg.padded_vocab, d),
-        "layers": stack_specs(cfg),
-        "final_norm": rmsnorm_spec(d),
-    }
+    spec = {"embed": embedding_spec(cfg.padded_vocab, d), "final_norm": rmsnorm_spec(d)}
+    for key, c in stacks(cfg):
+        spec[key] = stack_specs(c)
     if not cfg.tie_embeddings:
         spec["unembed"] = unembed_spec(cfg.padded_vocab, d)
     if cfg.meta_tokens > 0:
@@ -302,7 +346,7 @@ def lm_apply(
     x = _input_embeddings(cfg, params, tokens, frontend_embeds)
     S = x.shape[1]
     positions = jnp.arange(S)
-    x, _, aux = stack_apply(cfg, params["layers"], x, positions, remat=remat)
+    x, _, aux = lm_stacks(cfg, params, x, positions, remat=remat)
     prefix = cfg.meta_tokens + (frontend_embeds.shape[1] if frontend_embeds is not None else 0)
     if prefix > 0:
         x = x[:, prefix:]
@@ -320,9 +364,7 @@ def lm_prefill(
     x = _input_embeddings(cfg, params, tokens, frontend_embeds)
     S = x.shape[1]
     positions = jnp.arange(S)
-    x, caches, _ = stack_apply(
-        cfg, params["layers"], x, positions, collect_cache=True, remat=remat
-    )
+    x, caches, _ = lm_stacks(cfg, params, x, positions, collect_cache=True, remat=remat)
     return _logits(cfg, params, x[:, -1:, :])[:, 0, :], caches
 
 
@@ -344,13 +386,7 @@ def lm_decode(
     positions = cache_pos[None] if cache_pos.ndim == 0 else cache_pos
     if cache_pos.ndim == 1:
         positions = cache_pos[:, None]    # (B, S=1) per-row RoPE positions
-    x, caches_out, _ = stack_apply(
-        cfg,
-        params["layers"],
-        x,
-        positions,
-        caches=caches,
-        cache_pos=cache_pos,
-        remat="none",
+    x, caches_out, _ = lm_stacks(
+        cfg, params, x, positions, caches=caches, cache_pos=cache_pos, remat="none"
     )
     return _logits(cfg, params, x[:, -1:, :])[:, 0, :], caches_out

@@ -1,10 +1,10 @@
 """Continuous-batching engine (repro.serve.batching): lifecycle, admission
 control, slot-recycling bit-identity, and the zero-retrace gate.
 
-Bit-identity tests pin a dense family and a fixed sampler method: MoE
-capacity-factor dispatch couples batch rows (row i's expert capacity
-depends on its batchmates), so only dense models make "batched == one at
-a time" exact.
+Bit-identity tests pin a fixed sampler method.  Serving routes MoE layers
+drop-free (every token through each held expert, no capacity), so a row's
+output depends on that row alone and "batched == one at a time" holds for
+MoE + MLA models as for dense ones; both are pinned.
 """
 
 import asyncio
@@ -12,9 +12,12 @@ import asyncio
 import numpy as np
 import pytest
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 
+from repro.configs import get_config
 from repro.configs.base import ModelConfig, SamplerSpec
 from repro.models.model import build_model
 from repro.models.params import init_params
@@ -71,8 +74,21 @@ def test_recycling_bit_identity_vs_sequential(model_and_params):
     """3 requests churning through 2 slots produce bit-identical tokens
     to one-at-a-time runs with the same per-request seeds — the
     counter-RNG slot-isolation invariant."""
-    model, params = model_and_params
+    _assert_batched_equals_sequential(*model_and_params)
 
+
+def test_recycling_bit_identity_moe_mla():
+    """The same for an MoE + MLA model (Moonlight's block at smoke size,
+    one chip's share of its experts, a leading dense layer): drop-free
+    routing keeps batchmates out of a row's experts."""
+    cfg = dataclasses.replace(get_config("moonlight-16b-a3b-ep8", smoke=True),
+                              sampler=SamplerSpec(method="fenwick", W=8))
+    model = build_model(cfg)
+    params = init_params(jax.random.PRNGKey(1), model.specs, jnp.float32)
+    _assert_batched_equals_sequential(model, params)
+
+
+def _assert_batched_equals_sequential(model, params):
     def reqs():
         return [
             _req(i, plen=2 + i, max_new=4 + i, temperature=0.8, top_p=0.95)

@@ -7,7 +7,11 @@ tests compile each kernel with ``interpret=False`` against a described
 here, with no chip.  Shapes: the decode draw at qwen3's vocabulary
 (8 x 151936), the LDA z-draw at the paper's corpus (K=240, V=37286, one
 256-document chunk of 107-token rows), the alias build at K=4096, and the
-fused draw at the edge of its VMEM budget for each block width.
+fused draw at the edge of its VMEM budget for each block width.  One more
+compiles a whole model's serving programs, as the benchmark's MLA + MoE
+cell runs them: Moonlight-16B-A3B's EP-8 share in bf16, the decode step
+over 64 slots of a 1024-position latent cache and the largest prefill
+bucket, each within one chip's memory.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and test workers import every file.
@@ -133,3 +137,28 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_compile_cache):
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert compiled.as_text().count("tpu_custom_call") == n_kernels
+
+
+def test_moonlight_share_serving_programs_fit_one_v5e(one_chip, no_compile_cache):
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.models.params import is_spec
+
+    model = build_model(get_config("moonlight-16b-a3b-ep8"))
+
+    def shapes(tree):
+        return jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16, sharding=one_chip),
+            tree, is_leaf=is_spec)
+
+    params, caches = shapes(model.specs), shapes(model.cache_specs(64, 1024))
+    tok = jax.ShapeDtypeStruct((64, 1), i32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((64,), i32, sharding=one_chip)
+    prompt = jax.ShapeDtypeStruct((1, 256), i32, sharding=one_chip)
+    decode = jax.jit(model.decode).lower(params, caches, tok, pos).compile()
+    prefill = jax.jit(lambda p, t: model.prefill(p, {"tokens": t})[1]).lower(
+        params, prompt).compile()
+    for c in (decode, prefill):
+        m = c.memory_analysis()
+        used = m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes
+        assert used < 15 * 2**30, m
