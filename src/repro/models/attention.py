@@ -62,74 +62,141 @@ def _repeat_kv(k, H):
     return jnp.repeat(k, H // KV, axis=2)
 
 
-def _sdpa(q, k, v, mask, softcap: float = 0.0, kv_sharded: bool = False):
-    """q (B,Sq,H,hd)  k (B,Sk,KV,hd)  v (B,Sk,KV,hv) -> (B,Sq,H,hv).
+def _group_scores(q, k, softcap: float = 0.0):
+    """q (B,Sq,H,hd) against k (B,Sk,KV,hd) -> fp32 scores (B,KV,G,Sq,Sk).
 
     Grouped-query contraction: q splits into (KV, G = H // KV) and each
     group's G heads contract against their K/V head as stored, so a
     decode step reads the cache once instead of copying it G times.
-
-    fp32 scores/softmax; bf16 inputs stay bf16 on the contraction output.
-    ``kv_sharded``: pin the score matrix's key axis to the cache's seq
-    sharding (flash-decoding layout) so GSPMD reduces with tiny psums
-    instead of all-gathering the cache.
+    Scaled, and soft-capped when ``softcap`` > 0.
     """
-    from repro.dist.sharding import constrain_activation
-
     B, Sq, H, hd = q.shape
     KV = k.shape[2]
     qg = q.reshape(B, Sq, KV, H // KV, hd)
     scale = 1.0 / jnp.sqrt(jnp.float32(hd))
     scores = jnp.einsum("bqkge,bske->bkgqs", qg, k).astype(jnp.float32) * scale
-    if kv_sharded:
-        scores = constrain_activation(scores, ("batch", None, None, None, "act_kv"))
     if softcap > 0:
         scores = jnp.tanh(scores / softcap) * softcap
-    # (Sq, Sk) masks broadcast over batch; (B, Sq, Sk) masks are per-row
-    # (continuous batching: each slot attends its own prefix length)
-    scores = jnp.where(
-        mask[None, None, None] if mask.ndim == 2 else mask[:, None, None],
-        scores, NEG_INF,
-    )
-    probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
-    out = jnp.einsum("bkgqs,bskv->bqkgv", probs, v)
+    return scores
+
+
+def _group_context(probs, v):
+    """probs (B,KV,G,Sq,Sk) against v (B,Sk,KV,hv) -> (B,Sq,KV,G,hv),
+    in v's dtype."""
+    return jnp.einsum("bkgqs,bskv->bqkgv", probs.astype(v.dtype), v)
+
+
+def _sdpa(q, k, v, mask, softcap: float = 0.0):
+    """q (B,Sq,H,hd)  k (B,Sk,KV,hd)  v (B,Sk,KV,hv) -> (B,Sq,H,hv), with
+    a (Sq, Sk) mask, or (B, Sq, Sk) for one per row; fp32 scores and
+    softmax (``_group_scores``)."""
+    B, Sq, H, _ = q.shape
+    mask = mask[None, None, None] if mask.ndim == 2 else mask[:, None, None]
+    scores = jnp.where(mask, _group_scores(q, k, softcap), NEG_INF)
+    out = _group_context(jax.nn.softmax(scores, axis=-1), v)
     return out.reshape(B, Sq, H, v.shape[-1])
 
 
-def _cache_update(cache_arr, new, pos):
-    """Write one decode step into the cache.
+# cache leaves with a sequence axis, (B, S, ...) a layer and (L, B, S, ...)
+# stacked; every other cache leaf (SSM conv/state) is per-row state
+SEQ_LEAVES = frozenset({"k", "v", "c_kv", "k_pe"})
 
-    ``pos`` is the scalar write position shared by the batch, or a (B,)
-    vector of per-row positions (continuous batching: each slot writes at
-    its own sequence length).
 
-    Baseline: dynamic_update_slice (fast slice write, but GSPMD must
-    all-gather a seq-sharded cache to update at a traced position).  Under
-    the activation-sharding lever — and always for per-row positions —
-    a one-hot masked update: elementwise, so the cache never leaves its
-    shards (full read+write instead of a slice write: ~67MB/layer locally
-    vs multi-GB of all-gather per layer)."""
+def is_seq_leaf(path) -> bool:
+    """Whether a cache tree path (``tree_map_with_path``) ends in one of
+    ``SEQ_LEAVES``."""
+    return any(getattr(k, "key", None) in SEQ_LEAVES for k in path)
+
+
+def rows_in_place() -> bool:
+    """How a decode step writes its new K/V (or latent) rows, chosen when
+    the step is traced.  Without an activation mesh (every single-chip
+    engine) the layers only return their (B, 1, ...) rows, and
+    ``write_rows`` puts them into the stacked cache after the layer scan,
+    in place where the cache is donated.  Under a mesh the cache may be
+    sharded by sequence, where a write at a traced position would
+    all-gather it, so each layer rewrites its cache with an elementwise
+    one-hot ``where`` (``_cache_update``) that stays in its shards."""
     from repro.dist import sharding as shd
 
-    pos = jnp.asarray(pos)
-    if pos.ndim == 1:
-        S = cache_arr.shape[1]
-        oh = jnp.arange(S)[None, :] == pos[:, None]           # (B, S)
-        oh = oh.reshape(oh.shape + (1,) * (cache_arr.ndim - 2))
-        upd = jnp.where(oh, new.astype(cache_arr.dtype), cache_arr)
-        if shd._ACT_CTX.get("mesh") is not None:
-            axes = ("batch", "act_kv") + (None,) * (cache_arr.ndim - 2)
-            upd = shd.constrain_activation(upd, axes)
-        return upd
-    if shd._ACT_CTX.get("mesh") is None:
-        return jax.lax.dynamic_update_slice_in_dim(cache_arr, new, pos, axis=1)
-    S = cache_arr.shape[1]
-    oh = (jnp.arange(S) == pos)
-    oh = oh.reshape((1, S) + (1,) * (cache_arr.ndim - 2))
-    upd = jnp.where(oh, jnp.broadcast_to(new.astype(cache_arr.dtype), cache_arr.shape)
-                    if new.shape[1] == 1 else new.astype(cache_arr.dtype), cache_arr)
+    return shd._ACT_CTX.get("mesh") is None
+
+
+def write_rows(stack, rows, pos):
+    """Write one decode step's rows (L, B, 1, ...) into a stacked cache
+    (L, B, S, ...) at each row's position (``pos`` scalar or (B,), each
+    below S): B row writes, in place where the stack is donated."""
+    B = stack.shape[1]
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
+    rows = rows.astype(stack.dtype)
+    zero = jnp.int32(0)
+    for b in range(B):
+        start = (zero, jnp.int32(b), pos[b]) + (zero,) * (stack.ndim - 3)
+        stack = jax.lax.dynamic_update_slice(stack, rows[:, b:b + 1], start)
+    return stack
+
+
+def _cache_update(cache_arr, new, pos):
+    """A layer's cache (B, S, ...) with one decode step's rows ``new``
+    (B, 1, ...) written at ``pos`` (scalar or (B,)), for caches under an
+    activation mesh (``rows_in_place``): a one-hot masked update, so a
+    sequence-sharded cache never leaves its shards (a full read and write
+    of the local shard instead of an all-gather per layer)."""
+    from repro.dist import sharding as shd
+
+    B, S = cache_arr.shape[:2]
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
+    oh = jnp.arange(S)[None, :] == pos[:, None]               # (B, S)
+    oh = oh.reshape(oh.shape + (1,) * (cache_arr.ndim - 2))
+    upd = jnp.where(oh, new.astype(cache_arr.dtype), cache_arr)
     axes = ("batch", "act_kv") + (None,) * (cache_arr.ndim - 2)
     return shd.constrain_activation(upd, axes)
+
+
+def _decode_mask(S: int, pos, window=0):
+    """(B, S) mask of the stored positions a decode query at ``pos``
+    ((B,) or scalar, broadcast to B rows) attends besides its own token:
+    those before it, and within ``window`` of it when > 0 (may be
+    traced)."""
+    k_pos = jnp.arange(S)[None, :]
+    qp = jnp.asarray(pos, jnp.int32).reshape(-1, 1)
+    win = jnp.asarray(window, jnp.int32)
+    return (k_pos < qp) & jnp.where(win > 0, k_pos > qp - win, True)
+
+
+def _softmax_with_new(scores, new_score):
+    """One float32 softmax over the stored positions' scores (..., S),
+    masked already, and the new token's own (...): (probs over the stored
+    positions, the new token's prob)."""
+    probs = jax.nn.softmax(jnp.concatenate([scores, new_score[..., None]], -1), -1)
+    return probs[..., :-1], probs[..., -1]
+
+
+def _sdpa_decode(q, k_new, v_new, k, v, mask, softcap: float = 0.0):
+    """One decode query a row against the stored cache and its own token.
+
+    q (B,1,H,hd); k_new (B,1,KV,hd), v_new (B,1,KV,hv) the token's own;
+    k (B,S,KV,hd), v (B,S,KV,hv) the stored cache, read as it is, of
+    which ``mask`` (B, S) selects the positions before the query.  The
+    same work as ``_sdpa`` over a cache holding the new row at the
+    query's position: grouped-query contraction, fp32 scores, softcap and
+    one softmax over every attended position.  The score matrix's key
+    axis is pinned to the cache's sequence sharding under a mesh
+    (flash-decoding layout), so GSPMD reduces with small psums instead of
+    all-gathering the cache.
+    """
+    from repro.dist.sharding import constrain_activation
+
+    B, _, H, _ = q.shape
+    scores = constrain_activation(_group_scores(q, k, softcap),
+                                  ("batch", None, None, None, "act_kv"))
+    own = _group_scores(q, k_new, softcap)[..., 0]
+    scores = jnp.where(mask[:, None, None, None], scores, NEG_INF)
+    probs, p_own = _softmax_with_new(scores, own)
+    f32 = jnp.float32
+    out = (_group_context(probs, v).astype(f32)
+           + jnp.einsum("bkgq,bqkv->bqkgv", p_own, v_new.astype(f32)))
+    return out.astype(v.dtype).reshape(B, 1, H, v.shape[-1])
 
 
 def _sdpa_chunked(
@@ -211,7 +278,10 @@ def gqa_attend(
     """Self-attention over a full block (train/prefill) or one decode step.
 
     Decode mode: ``cache`` holds (k, v) of length S_max; ``cache_pos`` is the
-    scalar write position; ``positions`` is (B?, 1) the query position.
+    query's position, scalar or (B,) per row; ``positions`` is (B?, 1) the
+    same for RoPE.  The step returns the token's own (k, v) rows, which the
+    caller writes after the layer scan, or under a mesh the rewritten
+    cache (``rows_in_place``).
     """
     B, S, _ = x.shape
     q, k, v = gqa_project_qkv(params, x, positions, cfg)
@@ -227,30 +297,13 @@ def gqa_attend(
         new_cache = None
         kv_for_prefill = (k, v)
     else:
-        cache_pos = jnp.asarray(cache_pos)
-        ck = _cache_update(cache["k"], k, cache_pos)
-        cv = _cache_update(cache["v"], v, cache_pos)
-        k_pos = jnp.arange(ck.shape[1])
-        if cache_pos.ndim == 1:
-            # per-row positions: row b attends its OWN prefix k <= pos_b
-            # (and its own window), so one fixed-shape decode batch can
-            # hold sequences of different lengths — the continuous-
-            # batching invariant that keeps recycled slots isolated
-            qp = cache_pos[:, None]                           # (B, Sq=1)
-            mask = k_pos[None, None, :] <= qp[:, :, None]     # (B, Sq, Sk)
-            win = jnp.asarray(window, jnp.int32)
-            win_m = k_pos[None, None, :] > qp[:, :, None] - win
-            mask &= jnp.where(win > 0, win_m, True)
+        mask = _decode_mask(cache["k"].shape[1], cache_pos, window)
+        out = _sdpa_decode(q, k, v, cache["k"], cache["v"], mask, cfg.attn_softcap)
+        if rows_in_place():
+            new_cache = {"k": k, "v": v}
         else:
-            k_valid = k_pos <= cache_pos
-            # window relative to the *query* position (cache_pos), not k
-            # order
-            mask = attention_mask(
-                jnp.broadcast_to(cache_pos[None], positions.shape),
-                k_pos, causal=False, window=window, k_valid=k_valid,
-            )
-        out = _sdpa(q, ck, cv, mask, cfg.attn_softcap, kv_sharded=True)
-        new_cache = {"k": ck, "v": cv}
+            new_cache = {"k": _cache_update(cache["k"], k, cache_pos),
+                         "v": _cache_update(cache["v"], v, cache_pos)}
         kv_for_prefill = None
     y = jnp.einsum("bsnh,nhd->bsd", out, params["wo"])
     return y, (new_cache if cache is not None else kv_for_prefill)
@@ -410,39 +463,44 @@ def mla_attend_decode(params, x, cache, cache_pos, cfg: ModelConfig):
     q_c = q_nope @ W_uk  per head; scores = q_c . c_kv + q_pe . k_pe;
     ctx = probs . c_kv; y = (ctx @ W_uv) @ wo — the per-token cost is
     O(H*(nope*r + r)) and the cache is (r + rope) per position instead of
-    2*H*hd: the reason minicpm3 fits 32k cheaply.
+    2*H*hd: the reason minicpm3 fits 32k cheaply.  Returns the token's own
+    latent rows, or under a mesh the rewritten cache, as ``gqa_attend``.
     """
     m: MLAConfig = cfg.mla
     B, S, _ = x.shape  # S == 1
     cache_pos = jnp.asarray(cache_pos)
-    per_row = cache_pos.ndim == 1
     positions = (
-        cache_pos[:, None] if per_row
+        cache_pos[:, None] if cache_pos.ndim == 1
         else jnp.full((S,), 0, jnp.int32) + cache_pos
     )
     c_new, kpe_new = _mla_latents(params, x, positions, cfg)
-    c_kv = _cache_update(cache["c_kv"], c_new, cache_pos)
-    k_pe = _cache_update(cache["k_pe"], kpe_new, cache_pos)
+    c_kv, k_pe = cache["c_kv"], cache["k_pe"]
     q_nope, q_pe = _mla_queries(params, x, positions, cfg)
     q_c = jnp.einsum("bsnh,rnh->bsnr", q_nope, params["wuk"])
     scale = 1.0 / jnp.sqrt(jnp.float32(m.qk_nope_head_dim + m.qk_rope_head_dim))
     f32 = jnp.float32
+    # the stored positions before each row's query, then its own latents
     scores = (
         jnp.einsum("bsnr,btr->bnst", q_c, c_kv, preferred_element_type=f32)
         + jnp.einsum("bsnh,bth->bnst", q_pe, k_pe, preferred_element_type=f32)
     ) * scale
-    k_pos = jnp.arange(c_kv.shape[1])
-    if per_row:
-        valid = k_pos[None, :] <= cache_pos[:, None]          # (B, T)
-        scores = jnp.where(valid[:, None, None, :], scores, NEG_INF)
-    else:
-        valid = k_pos <= cache_pos
-        scores = jnp.where(valid[None, None, None, :], scores, NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1).astype(c_kv.dtype)
-    ctx = jnp.einsum("bnst,btr->bsnr", probs, c_kv)
+    own = (
+        jnp.einsum("bsnr,bsr->bns", q_c.astype(f32), c_new.astype(f32))
+        + jnp.einsum("bsnh,bsh->bns", q_pe.astype(f32), kpe_new.astype(f32))
+    ) * scale
+    mask = _decode_mask(c_kv.shape[1], cache_pos)
+    scores = jnp.where(mask[:, None, None, :], scores, NEG_INF)
+    probs, p_own = _softmax_with_new(scores, own)
+    ctx = (
+        jnp.einsum("bnst,btr->bsnr", probs.astype(c_kv.dtype), c_kv).astype(f32)
+        + jnp.einsum("bns,bsr->bsnr", p_own, c_new.astype(f32))
+    ).astype(c_kv.dtype)
     out = jnp.einsum("bsnr,rnh->bsnh", ctx, params["wuv"])
     y = jnp.einsum("bsnh,nhd->bsd", out, params["wo"])
-    return y, {"c_kv": c_kv, "k_pe": k_pe}
+    if rows_in_place():
+        return y, {"c_kv": c_new, "k_pe": kpe_new}
+    return y, {"c_kv": _cache_update(c_kv, c_new, cache_pos),
+               "k_pe": _cache_update(k_pe, kpe_new, cache_pos)}
 
 
 def mla_cache_spec(cfg: ModelConfig, batch: int, max_len: int):

@@ -81,6 +81,8 @@ def encode(cfg: ModelConfig, params: Dict, src_embeds: jnp.ndarray, remat: str =
 
 def _decoder_stack(cfg, params, x, positions, memory, caches=None, cache_pos=None,
                    collect_cache=False, remat="full"):
+    in_place = attn.rows_in_place()
+
     def body(carry, xs):
         x = carry
         if caches is not None:
@@ -107,16 +109,22 @@ def _decoder_stack(cfg, params, x, positions, memory, caches=None, cache_pos=Non
         x = x + mlp(lp["mlp"], h, cfg.act)
         cache_out = None
         if collect_cache or caches is not None:
-            cache_out = {
-                "self_k": self_cache["k"], "self_v": self_cache["v"],
-                "cross_k": cross_kv[0], "cross_v": cross_kv[1],
-            }
+            cache_out = {"self_k": self_cache["k"], "self_v": self_cache["v"]}
+            if caches is None or not in_place:
+                cache_out.update(cross_k=cross_kv[0], cross_v=cross_kv[1])
         return x, cache_out
 
     if remat == "full":
         body = jax.checkpoint(body, policy=jax.checkpoint_policies.nothing_saveable)
     xs = params["decoder"] if caches is None else (params["decoder"], caches)
     x, caches_out = jax.lax.scan(body, x, xs)
+    if caches is not None and in_place:
+        # the scan read the caches and gave back only the step's self rows
+        caches_out = dict(
+            caches,
+            self_k=attn.write_rows(caches["self_k"], caches_out["self_k"], cache_pos),
+            self_v=attn.write_rows(caches["self_v"], caches_out["self_v"], cache_pos),
+        )
     return x, caches_out
 
 
@@ -145,7 +153,8 @@ def encdec_prefill(cfg: ModelConfig, params: Dict, src_embeds, tgt_tokens, remat
 def encdec_decode(cfg: ModelConfig, params: Dict, caches, tokens, cache_pos):
     """One decode step against self KV + precomputed cross KV caches."""
     x = embed(params["embed"], tokens)
-    positions = cache_pos[None] if cache_pos.ndim == 0 else cache_pos
+    # (1,) shared, or (B, 1) per row as ``lm_decode`` gives RoPE
+    positions = cache_pos[None] if cache_pos.ndim == 0 else cache_pos[:, None]
     x, caches_out = _decoder_stack(
         cfg, params, x, positions, None, caches=caches, cache_pos=cache_pos,
         remat="none",

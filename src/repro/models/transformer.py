@@ -265,6 +265,12 @@ def stack_apply(
 
     xs = (params, windows) if caches is None else (params, windows, caches)
     (x, aux), caches_out = jax.lax.scan(body, (x, jnp.float32(0.0)), xs)
+    if caches is not None and attn.rows_in_place():
+        # the scan read the caches and gave back only the step's rows
+        caches_out = jax.tree_util.tree_map_with_path(
+            lambda path, c, new: (attn.write_rows(c, new, cache_pos)
+                                  if attn.is_seq_leaf(path) else new),
+            caches, caches_out)
     return x, caches_out, aux
 
 

@@ -19,7 +19,8 @@ it", applied to serving:
 * **Per-slot positions.**  Every slot decodes at its own sequence length
   — ``cache_pos`` is a (B,) traced vector, threaded down through
   ``lm_decode`` / ``gqa_attend`` / ``mla_attend_decode`` (per-row RoPE
-  angles, per-row one-hot cache writes, per-row prefix masks), so
+  angles, per-row prefix masks, each slot's new K/V row written at its
+  own position after the layer scan, into the donated cache), so
   sequences of wildly different lengths share one step.
 * **Per-slot sampling params as traced leaves.**  temperature / top-k /
   top-p / min-p ride in as (B,) / (B, 3) float operands; truncation is
@@ -51,6 +52,7 @@ jit cache size and ``sampling.plan_stats()``, and the churn test +
 from __future__ import annotations
 
 import asyncio
+import functools
 import time
 from typing import Dict, List, Optional, Sequence
 
@@ -61,6 +63,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro import obs, sampling
 from repro.kernels import rng as _rng
+from repro.models import attention as _attn
 from repro.models.model import Model
 from repro.models.params import init_params
 from repro.sampling import distribution as _dist
@@ -70,10 +73,6 @@ from repro.serve.request import FinishReason, Request, RequestState
 from repro.serve.scheduler import QueueFullError, Scheduler
 
 __all__ = ["ContinuousBatchingEngine", "QueueFullError"]
-
-# cache leaves with a (L, B, S, ...) sequence axis (axis 2 when stacked);
-# everything else (SSM conv/state) is per-row state without one
-_SEQ_LEAF_NAMES = frozenset({"k", "v", "c_kv", "k_pe"})
 
 # kpm block of a request that does not truncate: top_k=0, top_p=1, min_p=0
 _KPM_OFF = np.array([0.0, 1.0, 0.0], np.float32)
@@ -160,11 +159,14 @@ class ContinuousBatchingEngine:
         self._kpm = np.tile(_KPM_OFF, (B, 1))
         self._active = np.zeros((B,), bool)
 
+        self._rows_in_place = False   # set when the step is traced
         self._step = self._build_decode_step()
         self._prefill = jax.jit(
             lambda p, toks: model.prefill(p, {"tokens": toks})[1]
         )
-        self._insert = jax.jit(self._insert_impl)
+        # the caches are donated: each call's output takes over their
+        # buffers, and ``self._caches`` is rebound to it
+        self._insert = jax.jit(self._insert_impl, donate_argnums=0)
         self._seed_pair = jax.jit(_seed_pair)
 
         # metrics: {"dt": s, "active": n, "tokens": n} per decode step, dt
@@ -241,8 +243,10 @@ class ContinuousBatchingEngine:
                 check_vma=False,  # pallas_call has no replication rule
             )(wm, u)
 
-        @jax.jit
+        @functools.partial(jax.jit, donate_argnums=1)
         def step(params, caches, token, pos, seeds, draw_idx, temp, kpm):
+            # read as the model's cache write is chosen, when traced
+            self._rows_in_place = _attn.rows_in_place()
             logits, caches = model.decode(params, caches, token[:, None], pos)
             # per-slot stream: uniform for (request seed, token index) —
             # independent of slot id, batch mix, and device count
@@ -265,8 +269,7 @@ class ContinuousBatchingEngine:
         the slot's previous occupant survives recycling."""
 
         def upd(path, big, small):
-            names = {getattr(k, "key", None) for k in path}
-            if names & _SEQ_LEAF_NAMES:
+            if _attn.is_seq_leaf(path):
                 pad = [(0, 0)] * small.ndim
                 pad[2] = (0, big.shape[2] - small.shape[2])
                 small = jnp.pad(small, pad)
@@ -410,6 +413,8 @@ class ContinuousBatchingEngine:
         self._steps += 1
         self._tokens_out += live
         obs.count("engine.tokens", live)
+        if self._rows_in_place:
+            obs.count("engine.cache_inplace")
         return live
 
     def _finish(self, slot: int, reason: FinishReason) -> None:

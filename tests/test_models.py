@@ -267,12 +267,13 @@ class TestGroupedDecode:
         hlo, got = lower_and_run()
         assert not repeated.search(hlo)
 
-        # the repeat form: K/V copied to every query head, one head a group
-        grouped = attention._sdpa
+        # the repeat form: K/V (the cache's and the token's own) copied to
+        # every query head, one head a group
+        grouped = attention._sdpa_decode
         monkeypatch.setattr(
-            attention, "_sdpa",
-            lambda q, k, v, *a, **kw: grouped(
-                q, attention._repeat_kv(k, H), attention._repeat_kv(v, H), *a, **kw),
+            attention, "_sdpa_decode",
+            lambda q, *kv, **kw: grouped(
+                q, *(attention._repeat_kv(x, H) for x in kv[:4]), *kv[4:], **kw),
         )
         hlo_rep, want = lower_and_run()
         assert repeated.search(hlo_rep)

@@ -240,6 +240,31 @@ def test_engine_counts_rejections(model_and_params):
     assert obs.counters()["engine.rejected"] == 2
 
 
+@pytest.mark.parametrize("act_mesh", [False, True], ids=["plain", "act_mesh"])
+def test_engine_counts_each_in_place_cache_write(model_and_params, act_mesh):
+    """``engine.cache_inplace`` counts one per decode step that wrote only
+    its new K/V rows: every step of a plain engine, none under an
+    activation mesh, where the layers rewrite their (possibly
+    sequence-sharded) caches instead."""
+    from jax.sharding import Mesh
+
+    from repro.dist import sharding as shd
+
+    model, params = model_and_params
+    if act_mesh:
+        shd.set_activation_sharding(
+            Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model")))
+    try:
+        eng = ContinuousBatchingEngine(model, params, max_slots=2, max_len=24)
+        eng.run([Request(prompt=np.arange(1, 2 + i, dtype=np.int32),
+                         max_new_tokens=3 + i, seed=i) for i in range(3)])
+    finally:
+        shd.set_activation_sharding(None)
+    steps = eng.stats()["steps"]
+    assert steps == len(obs.spans("engine.step")) > 0
+    assert obs.counters().get("engine.cache_inplace", 0) == (0 if act_mesh else steps)
+
+
 def test_a_compile_in_an_engine_step_is_seen():
     """The counterpart of the zero-recompile check: a step's first call
     compiles inside its dispatch span, and the record shows it."""

@@ -11,7 +11,9 @@ fused draw at the edge of its VMEM budget for each block width.  One more
 compiles a whole model's serving programs, as the benchmark's MLA + MoE
 cell runs them: Moonlight-16B-A3B's EP-8 share in bf16, the decode step
 over 64 slots of a 1024-position latent cache and the largest prefill
-bucket, each within one chip's memory.
+bucket, each within one chip's memory.  Two more compile the serving
+decode steps of qwen3-4b and Moonlight with the cache donated, and check
+that the step writes its new rows into that cache in place.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and test workers import every file.
@@ -162,3 +164,31 @@ def test_moonlight_share_serving_programs_fit_one_v5e(one_chip, no_compile_cache
         m = c.memory_analysis()
         used = m.argument_size_in_bytes + m.output_size_in_bytes + m.temp_size_in_bytes
         assert used < 15 * 2**30, m
+
+
+@pytest.mark.parametrize("arch,slots", [("qwen3-4b", 16), ("moonlight-16b-a3b-ep8", 64)])
+def test_decode_step_writes_its_rows_in_place_on_v5e(arch, slots, one_chip,
+                                                     no_compile_cache):
+    """The benchmark's serving decode steps, as the chip's compiler lays
+    them out: with the cache donated, the output aliases the whole cache
+    and the step's temporaries stay a small fraction of it, so no layer
+    rewrite or relayout of the stacked cache came back."""
+    from repro.configs import get_config
+    from repro.models import build_model
+    from repro.models.params import is_spec
+
+    model = build_model(get_config(arch))
+
+    def shapes(tree):
+        return jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, jnp.bfloat16, sharding=one_chip),
+            tree, is_leaf=is_spec)
+
+    caches = shapes(model.cache_specs(slots, 1024))
+    cache_bytes = sum(x.size * 2 for x in jax.tree.leaves(caches))
+    tok = jax.ShapeDtypeStruct((slots, 1), i32, sharding=one_chip)
+    pos = jax.ShapeDtypeStruct((slots,), i32, sharding=one_chip)
+    m = jax.jit(model.decode, donate_argnums=1).lower(
+        shapes(model.specs), caches, tok, pos).compile().memory_analysis()
+    assert m.alias_size_in_bytes >= cache_bytes, m
+    assert m.temp_size_in_bytes < cache_bytes / 20, m
