@@ -249,16 +249,12 @@ def bench_scale(docs, vocab, topics, sweeps, sparse, iters=3, seed=0):
                     file=sys.stderr,
                 )
 
-        # training-regime rows (PR 9): phi is resampled EVERY sweep, so
-        # the word-proposal table is rebuilt every sweep and per-token
-        # time includes the build.  "auto" arbitrates by draws-per-
-        # refresh (tokens/V amortization, DESIGN.md §11) — the gate is
-        # that the auto winner's build+sweep beats the cdf baseline.
-        resolved = lda_sparse.resolve_word_proposal(
-            "auto", K, V, tokens=int(tokens)
-        )
+        # training-regime rows: phi is resampled EVERY sweep, so the
+        # word-proposal table is rebuilt every sweep and per-token time
+        # includes the build — the cdf descent against the device alias
+        # build (DESIGN.md §11)
         train_us = {}
-        for mode in dict.fromkeys(("cdf", resolved)):
+        for mode in ("cdf", "alias_device"):
             fn = lda_sparse._mh_sweep_jit(1, cap, mode, 256)
             # distinct phi per iteration defeats the digest-keyed table
             # LRU — each build is a real rebuild, as in training
@@ -282,17 +278,15 @@ def bench_scale(docs, vocab, topics, sweeps, sparse, iters=3, seed=0):
             train_us[mode] = t_train
             records.append(
                 _row(f"lda_sparse_train_{mode}", tokens, K, t_train,
-                     dict(cap=cap, resolved_auto=resolved,
-                          build_included=True))
+                     dict(cap=cap, build_included=True))
             )
-        if resolved != "cdf":
-            print(
-                f"# K={K} train (build+sweep): cdf "
-                f"{train_us['cdf']*1e3:.1f} ms, auto->{resolved} "
-                f"{train_us[resolved]*1e3:.1f} ms "
-                f"({train_us['cdf']/train_us[resolved]:.2f}x)",
-                file=sys.stderr,
-            )
+        print(
+            f"# K={K} train (build+sweep): cdf "
+            f"{train_us['cdf']*1e3:.1f} ms, alias_device "
+            f"{train_us['alias_device']*1e3:.1f} ms "
+            f"({train_us['cdf']/train_us['alias_device']:.2f}x)",
+            file=sys.stderr,
+        )
     return records
 
 
@@ -304,8 +298,7 @@ def bench_train(docs, vocab, topics, iters=3, mh_steps=4, seed=0):
     rebuild (draws-per-refresh d = tokens*mh/V above the ~2K CPU
     crossover, DESIGN.md §11) a dense K-wide sweep would take minutes
     and gates nothing.  Each timed sweep rebuilds the word-proposal
-    table from a fresh phi — the honest training cost — and "auto" must
-    pick the winner on its own.
+    table from a fresh phi — the honest training cost.
     """
     corpus = synthesize_corpus(
         seed, M=docs, V=vocab, K=min(topics, 64), avg_len=96, max_len=384,
@@ -334,11 +327,9 @@ def bench_train(docs, vocab, topics, iters=3, mh_steps=4, seed=0):
     counts = lda_sparse.sparse_counts(doc_topic, cap)
     seed_u = _rng.fold(_rng.seed_from_key(s.key), _rng.TAG_SPARSE_MH)
 
-    eff = int(tokens) * mh_steps  # proposals per table refresh
-    resolved = lda_sparse.resolve_word_proposal("auto", K, V, tokens=eff)
     records = []
     train_t = {}
-    for mode in dict.fromkeys(("cdf", resolved)):
+    for mode in ("cdf", "alias_device"):
         fn = lda_sparse._mh_sweep_jit(mh_steps, cap, mode, 256)
         # distinct phi per iteration defeats the digest-keyed table LRU
         phis = [s.phi * (1.0 + 1e-6 * i) for i in range(iters + 1)]
@@ -361,20 +352,19 @@ def bench_train(docs, vocab, topics, iters=3, mh_steps=4, seed=0):
         train_t[mode] = t_train
         records.append(
             _row(f"lda_train_{mode}_mh{mh_steps}", tokens, K, t_train,
-                 dict(cap=cap, resolved_auto=resolved, mh_steps=mh_steps,
-                      vocab=V, build_included=True))
+                 dict(cap=cap, mh_steps=mh_steps, vocab=V,
+                      build_included=True))
         )
         print(
             f"# train {mode}: {t_train*1e3:.1f} ms/sweep "
             f"({t_train*1e9/max(tokens, 1):.0f} ns/token, build included)",
             file=sys.stderr,
         )
-    if resolved != "cdf" and resolved in train_t:
-        ratio = train_t["cdf"] / train_t[resolved]
-        print(
-            f"# K={K} training sweep: auto->{resolved} {ratio:.2f}x vs cdf",
-            file=sys.stderr,
-        )
+    ratio = train_t["cdf"] / train_t["alias_device"]
+    print(
+        f"# K={K} training sweep: alias_device {ratio:.2f}x vs cdf",
+        file=sys.stderr,
+    )
     return records
 
 

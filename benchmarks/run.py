@@ -2,8 +2,7 @@
 
   fig3_lda       — paper Fig. 3 (exec time vs K, butterfly vs prefix)
   sampler_bench  — core drawing-strategy throughput grid (paper §5 micro);
-                   also writes BENCH_sampler.json for the autotune cache
-  autotune       — warm the repro.autotune tuning cache, report auto-vs-fixed
+                   also writes BENCH_sampler.json
   roofline       — §Roofline terms from the dry-run artifacts
 
 Prints ``name,us_per_call,derived`` CSV.
@@ -19,9 +18,6 @@ def main() -> None:
     if run_all or "sampler" in args:
         from benchmarks import sampler_bench
         sampler_bench.main([])
-    if run_all or "autotune" in args:
-        from benchmarks import autotune_bench
-        autotune_bench.main([])
     if run_all or "fig3" in args:
         from benchmarks import fig3_lda
         fig3_lda.main()

@@ -12,9 +12,7 @@ restatement of the paper's headline comparison; rows land under
 
 Also writes ``BENCH_sampler.json`` (path via ``--json PATH``, suppress
 with ``--no-json``) — per-method timing records in the
-``repro-autotune-bench-v1`` schema the tuning cache consumes
-(``TuningCache.ingest_records`` / ``autotune_bench --import``), so a bench
-run doubles as a pre-warm of the autotune cache.
+``repro-autotune-bench-v1`` schema that ``check_regression.py`` reads.
 """
 
 import argparse
@@ -25,9 +23,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.autotune import cost_model
-from repro.autotune.cache import BENCH_SCHEMA
 from repro.core import sample_categorical
+from repro.kernels import runtime
+
+BENCH_SCHEMA = "repro-autotune-bench-v1"
 
 METHODS = ("prefix", "butterfly", "fenwick", "two_level", "gumbel")
 
@@ -101,7 +100,7 @@ def run_fused(Bs=(4096,), Ks=(256, 1024, 4096), W=32, iters=5):
             doc_ids = jnp.array(rng.integers(0, C, B), jnp.int32)
             words = jnp.array(rng.integers(0, V, B), jnp.int32)
             u = jnp.array(rng.uniform(0, 1, B).astype(np.float32))
-            tb, _ = cost_model.default_tiles(B, K, W)
+            tb = runtime.default_tb(B)
 
             fused = jax.jit(
                 lambda th, ph, uu: lda_draw_factored(
@@ -317,18 +316,16 @@ def run_zoo(B=1024, Ks=(256, 1024, 4096), iters=5):
 def write_json(rows, fused_rows=None, path: str = "BENCH_sampler.json",
                W: int = 32, shard_rows=None, decode_rows=None,
                zoo_rows=None) -> str:
-    """Emit the rows as autotune-ingestible bench records.  Fused-vs-
-    materializing rows land both in ``records`` (the fused timing, so the
-    cache learns the factored winner) and, with their materializing
+    """Emit the rows as bench records.  Fused-vs-materializing rows land
+    both in ``records`` (the fused timing) and, with their materializing
     counterpart, under ``fused_factored``.  Every record carries a
     ``devices`` field (1 for the single-device grids; the ``--shard``
     rows record their mesh size and B is per-shard) — readers that
-    predate the field ignore it, and ``TuningCache.ingest_records``
-    buckets by it."""
+    predate the field ignore it."""
     backend = jax.default_backend()
 
     def _rec(r, W, method, us):
-        tb, tk = cost_model.default_tiles(r["B"], r["K"], W)
+        tb, tk = runtime.default_tb(r["B"]), runtime.default_tk(r["K"], W)
         rec = {
             "backend": backend, "B": r["B"], "K": r["K"],
             "W": r.get("W", W), "tb": r.get("tb", tb), "tk": r.get("tk", tk),
@@ -385,7 +382,7 @@ def write_json(rows, fused_rows=None, path: str = "BENCH_sampler.json",
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", default="BENCH_sampler.json", metavar="PATH",
-                    help="where to write the autotune-ingestible records")
+                    help="where to write the bench records")
     ap.add_argument("--no-json", action="store_true",
                     help="CSV to stdout only, write no file")
     ap.add_argument("--reuse", action="store_true",
@@ -483,7 +480,7 @@ def main(argv=None):
     if not args.no_json:
         path = write_json(rows, fused_rows, args.json, shard_rows=shard_rows,
                           decode_rows=decode_rows, zoo_rows=zoo_rows)
-        print(f"# wrote {path} ({BENCH_SCHEMA}; feed to autotune_bench --import)")
+        print(f"# wrote {path} ({BENCH_SCHEMA})")
 
 
 if __name__ == "__main__":

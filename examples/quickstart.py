@@ -22,7 +22,7 @@ weights = jnp.array(rng.gamma(0.3, size=(B, K)).astype(np.float32))
 key = jax.random.PRNGKey(42)
 
 # -- the distribution-object API (primary) ---------------------------------
-# plan once (autotune resolves here, not per draw), build the pytree
+# plan once (the method is resolved here, not per draw), build the pytree
 # Categorical once, draw from it as many times as you like
 for method in ("butterfly", "fenwick", "two_level", "prefix", "gumbel"):
     p = sampling.plan(weights.shape, method=method, W=32)
@@ -43,7 +43,7 @@ for method in ("alias_device", "radix_forest"):
     print(f"{method:12s} -> drew {idx.shape[0]} samples, "
           f"first five: {np.asarray(idx[:5])}")
 
-# what would autotune have picked for this draw-heavy frozen workload?
+# what does "auto" pick for this draw-heavy frozen workload?
 auto = sampling.plan(weights.shape, method="auto", draws=16)
 print(f"auto (draws=16) resolved -> method={auto.table_method!r}")
 

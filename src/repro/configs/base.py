@@ -15,21 +15,20 @@ from typing import Optional, Tuple
 @dataclasses.dataclass(frozen=True)
 class SamplerSpec:
     """Decode-time sampler preferences, resolved once per workload shape by
-    ``repro.sampling.plan`` (which consults ``repro.autotune`` when
-    ``method="auto"``).
+    ``repro.sampling.plan``, whose one rule turns ``method="auto"`` into
+    the fused kernel on TPU and its pure-XLA twin elsewhere.
 
     ``method``: auto | two_level | fenwick | butterfly | kernel | prefix |
-    gumbel | alias.  ``W = 0`` means "pick for me" (the tuned W under
-    auto, W ~ sqrt(K) for fixed methods).  ``draws`` is the
-    expected-uses-per-distribution hint autotune amortizes table builds
-    over (1 for decode: logits change every step).
+    gumbel | alias.  ``W = 0`` means "pick for me" (W ~ sqrt(K),
+    ``kernels.runtime.default_w``).  ``draws`` is the
+    expected-uses-per-distribution hint (1 for decode: logits change
+    every step; above 1 ``auto`` holds a Fenwick table).
 
     ``top_k``/``top_p``/``min_p`` are the model's *default* truncation
     (what its model card recommends for decode); disabled at 0 / 1.0 / 0.
     The serve engine lifts them into a ``SamplingParams`` default that
-    per-request parameters override at call time — they also shape the
-    autotune bucket (a truncating workload tunes toward the fused
-    truncated kernel; see ``repro.sampling.transforms``)."""
+    per-request parameters override at call time (see
+    ``repro.sampling.transforms``)."""
 
     method: str = "auto"
     W: int = 0

@@ -99,7 +99,7 @@ def build_alias_tables_host(weights) -> AliasTable:
     faster at (2048, 512), bit-agreeing draw semantics (leftover entries
     on either stack keep prob 1).  Weights must be concrete (it is a host
     build); the sparse-LDA sweep reaches it through the
-    ``autotune.tables`` LRU cache so per-phi builds amortize across draw
+    ``sampling.tables`` LRU cache so per-phi builds amortize across draw
     calls."""
     import numpy as np
 

@@ -3,8 +3,8 @@
 The primary API lives in :mod:`repro.sampling`: build a pytree
 :class:`~repro.sampling.Categorical` once (``from_weights`` /
 ``from_logits``) and draw from it through a compiled
-:class:`~repro.sampling.SamplerPlan` (``plan(...)`` resolves
-``repro.autotune`` once at plan time).  Migration::
+:class:`~repro.sampling.SamplerPlan` (``plan(...)`` resolves the method
+once at plan time).  Migration::
 
     # before                                   # after
     sample_categorical(w, key=k,               p = sampling.plan(w.shape)
@@ -25,10 +25,10 @@ byte-identical to the pre-redesign implementation for fixed
 ``(method, W, u)`` inputs.
 
 Methods:
-  * ``auto``      — autotuned dispatch: ``repro.autotune`` picks the best
-                    strategy for (B, K, draws, dtype, backend) from its
-                    tuning cache / cost model (the default everywhere a
-                    config doesn't say otherwise)
+  * ``auto``      — ``sampling.plan``'s rule: the fused kernel on TPU,
+                    ``two_level`` elsewhere, ``fenwick`` for a reused
+                    table (the default everywhere a config doesn't say
+                    otherwise)
   * ``butterfly`` — paper-faithful butterfly table + add/subtract walk
   * ``fenwick``   — TPU-adapted per-sample dyadic table (DESIGN.md §2)
   * ``two_level`` — fused two-pass draw: (B, K/W) block sums + one gathered
@@ -52,7 +52,7 @@ to call this shim.
 
 Repeated distributions: pass ``dist_key="..."`` (with ``draws=`` as a
 reuse hint for ``auto``) and the alias/Fenwick state is memoized in
-``repro.autotune``'s table cache across calls.  The cache keys on a cheap
+``repro.sampling.tables``' cache across calls.  The cache keys on a cheap
 content digest of the weights, so silently changed weights rebuild
 instead of serving a stale table; prefer holding a ``Categorical`` and
 calling ``dist.refreshed(new_weights)`` explicitly.
@@ -71,8 +71,6 @@ METHODS = (
 )
 
 # the variants whose built state the table cache memoizes under dist_key
-# (stays in sync with autotune.cost_model.CACHED_TABLE_METHODS: amortized
-# build cost must mean actual cross-call reuse)
 _CACHED_KINDS = ("alias", "fenwick", "alias_device", "radix_forest")
 
 
@@ -92,9 +90,9 @@ def sample_categorical(
     ``alias`` require ``key``.
 
     ``method="auto"`` resolves through ``repro.sampling.plan`` (see module
-    docstring); ``draws`` is the expected-uses-per-distribution hint it
-    amortizes table builds over, and ``dist_key`` enables cross-call table
-    reuse for the alias/fenwick strategies.  The two go together: without
+    docstring); ``draws`` is the expected-uses-per-distribution hint
+    (above 1 ``auto`` picks ``fenwick``), and ``dist_key`` enables
+    cross-call table reuse for the alias/fenwick strategies.  The two go together: without
     a ``dist_key`` nothing is reused between calls, so ``auto`` ignores
     ``draws`` rather than select a method whose amortization would never
     materialize.
@@ -127,9 +125,9 @@ def sample_categorical(
     if u is None and key is None:
         raise ValueError("need key or u")
     if dist_key is not None and p.method in _CACHED_KINDS:
-        from repro import autotune
+        from repro.sampling import tables
 
-        dist = autotune.get_table_cache().get_or_build_dist(dist_key, p, weights)
+        dist = tables.get_table_cache().get_or_build_dist(dist_key, p, weights)
     else:
         dist = p.build(weights)
     if p.method in ("gumbel", "alias", "alias_device"):
@@ -156,7 +154,7 @@ def sample_from_logits(
 
     Float logits keep their dtype through the softmax — ``bfloat16``
     logits are NOT upcast, halving the softmax's HBM traffic, and the
-    autotune cost model sees the real dtype.
+    plan records the real dtype.
     """
     from repro import sampling
 

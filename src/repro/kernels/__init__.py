@@ -3,130 +3,6 @@
 Each kernel ships as ``kernel.py`` (pl.pallas_call + explicit BlockSpec VMEM
 tiling), ``ops.py`` (jit'd public wrapper, interpret-mode fallback on CPU)
 and ``ref.py`` (pure-jnp oracle used by the allclose test sweeps).
-
-``candidates()`` is the uniform registry the autotune tuner walks: every
-kernel-backed sampling strategy, with its entry point and an availability
-predicate, so method selection never hard-codes kernel names.
+``runtime`` holds the one rule for where they compile natively and the
+default block width and tiles they launch with.
 """
-
-from __future__ import annotations
-
-import dataclasses
-from typing import Callable, Optional, Tuple
-
-
-@dataclasses.dataclass(frozen=True)
-class KernelCandidate:
-    """One kernel-backed strategy the tuner may select."""
-
-    method: str                     # name accepted by sample_categorical
-    module: str                     # repro.kernels.<pkg> that implements it
-    # is this candidate viable for (B, K, backend)?  Interpret-mode Pallas
-    # on CPU is an emulation (orders of magnitude slow) — never a candidate.
-    available: Callable[[int, int, str], bool]
-    description: str = ""
-    # factored candidates need the workload's weights as a (theta, phi)
-    # product — only offered when the caller says factored=True
-    factored: bool = False
-    # truncated candidates fold a top-k/top-p/min-p threshold pass into
-    # the draw — only offered when the caller declares a truncation chain
-    truncated: bool = False
-    # sparse candidates run the sparsity-aware MH sweep over per-doc live
-    # topics — only offered when the caller's workload is an LDA z-draw
-    # that can supply sparse doc-topic counts (sparse=True)
-    sparse: bool = False
-
-
-_REGISTRY: Tuple[KernelCandidate, ...] = (
-    KernelCandidate(
-        method="kernel",
-        module="repro.kernels.butterfly_sample",
-        # pltpu-based: compiles natively on TPU only; every other backend
-        # (including GPU) would silently run the interpret-mode emulation
-        available=lambda B, K, backend: backend == "tpu" and K >= 2,
-        description="fused tiled butterfly draw (block selection in-kernel)",
-    ),
-    KernelCandidate(
-        method="kernel_trunc",
-        module="repro.kernels.butterfly_sample",
-        available=lambda B, K, backend: backend == "tpu" and K >= 2,
-        description=(
-            "fused truncated decode draw (top-k/top-p/min-p threshold "
-            "bisection in-kernel — no sort, no (B, K) sorted copy)"
-        ),
-        truncated=True,
-    ),
-    KernelCandidate(
-        method="lda_kernel",
-        module="repro.kernels.lda_draw",
-        # viable everywhere: the Pallas kernel on TPU, the pure-XLA
-        # zero-materialization twin elsewhere (never interpret mode)
-        available=lambda B, K, backend: K >= 2,
-        description="fused factored theta-phi draw (weights never materialize)",
-        factored=True,
-    ),
-    KernelCandidate(
-        method="alias_device",
-        module="repro.kernels.alias_build",
-        # viable everywhere: the Pallas assembly kernel on TPU, the
-        # pure-XLA merged-rank twin elsewhere (never interpret mode).
-        # O(1) draws once built — the frozen-distribution strategy.
-        available=lambda B, K, backend: K >= 2,
-        description=(
-            "on-device split-based alias build (closed-jaxpr PSA "
-            "construction) + O(1) two-uniform draws"
-        ),
-    ),
-    KernelCandidate(
-        method="radix_forest",
-        module="repro.core.radix",
-        # pure-XLA on every backend: cumsum + searchsorted build, fixed
-        # clamped bisection draw (divergence-free)
-        available=lambda B, K, backend: K >= 2,
-        description=(
-            "radix-tree forest draw (root dispatch on top uniform bits + "
-            "fixed-depth clamped bisection; cheap rebuild)"
-        ),
-    ),
-    KernelCandidate(
-        method="sparse_mh",
-        module="repro.lda.sparse",
-        # pure-XLA scan (token-major compare-reduces + scalar gathers):
-        # viable on every backend; sublinear per-token cost in K
-        available=lambda B, K, backend: K >= 2,
-        description=(
-            "sparsity-aware MH-alias Gibbs sweep (WarpLDA proposals over "
-            "fixed-width sparse doc-topic counts — no (B, K) weights)"
-        ),
-        factored=True,
-        sparse=True,
-    ),
-)
-
-
-def candidates(
-    B: int, K: int, backend: Optional[str] = None, factored: bool = False,
-    truncated: bool = False, sparse: bool = False,
-) -> Tuple[str, ...]:
-    """Kernel-backed method names viable for a (B, K) draw on ``backend``
-    (default: the current JAX backend).  ``factored=True`` adds the
-    strategies that consume a (theta, phi) factorization directly;
-    ``truncated=True`` adds the fused truncated-decode strategies (the
-    workload declares a top-k/top-p/min-p chain); ``sparse=True`` adds
-    the sparsity-aware LDA sweep strategies (the workload can supply
-    per-doc sparse topic counts)."""
-    if backend is None:
-        import jax
-
-        backend = jax.default_backend()
-    return tuple(
-        c.method for c in _REGISTRY
-        if c.available(B, K, backend)
-        and (factored or not c.factored)
-        and (truncated or not c.truncated)
-        and (sparse or not c.sparse)
-    )
-
-
-def registry() -> Tuple[KernelCandidate, ...]:
-    return _REGISTRY

@@ -8,14 +8,16 @@ elsewhere".  (Previously the low-level entry points hard-defaulted to
 ``interpret=True`` even on TPU when called directly, silently running the
 Python emulation on hardware that could compile the kernel.)
 
-Tile-size defaults (``default_tb`` for the sample/row axis, ``default_tk``
-for the category axis) live here too: they are the kernel-side twins of
-the autotune cost model's ``tb``/``tk`` parameters (DESIGN.md §3), kept
-importable without pulling in jax at module import time.
+The block width and tile-size defaults (``default_w`` for W,
+``default_tb`` for the sample/row axis, ``default_tk`` for the category
+axis) live here too (DESIGN.md §3): ``repro.sampling.plan`` launches
+every kernel with them.  They stay importable without pulling in jax at
+module import time.
 """
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 
@@ -37,6 +39,15 @@ def resolve_interpret(interpret: Optional[bool]) -> bool:
     if interpret is None:
         return default_interpret()
     return bool(interpret)
+
+
+def default_w(K: int) -> int:
+    """W ~ sqrt(K) (minimizes K/W + W), rounded to a power of two in
+    [8, 128] — 128 at vocabulary scale."""
+    if K <= 64:
+        return 8
+    w = 2 ** int(round(math.log2(math.sqrt(K))))
+    return max(8, min(128, w))
 
 
 def default_tb(B: int) -> int:

@@ -4,8 +4,8 @@ One sweep =
   1. DRAW Z  — for every word position (m, i): build the K relative
      probabilities ``theta[m,k] * phi[w[m,i],k]`` and draw a topic.  This
      is the paper's hot loop; the sampling strategy is pluggable
-     (``auto`` — the default, resolved per workload by ``repro.autotune``
-     over the *factored* candidate set — or a fixed ``lda_kernel`` /
+     (``auto`` — the default, which ``sampling.plan``'s rule resolves to
+     the factored ``lda_kernel`` — or a fixed ``lda_kernel`` /
      ``butterfly`` / ``fenwick`` / ``two_level`` / ``kernel`` / ``prefix``
      / ``gumbel``).
   2. UPDATE THETA — theta[m,:] ~ Dirichlet(alpha + doc-topic counts).
@@ -17,7 +17,7 @@ jitted function whose z-draw is a single ``lax.scan`` over document
 chunks — no Python chunk loop, no per-chunk dispatch — with the old
 ``theta``/``z`` buffers donated to XLA on accelerator backends (they are
 dead after the draw, so the sweep updates in place).  When the strategy
-resolves to the factored ``lda_kernel`` path (the autotune default for
+resolves to the factored ``lda_kernel`` path (what ``auto`` picks for
 this workload), each chunk's draw consumes the (theta, phi) factors
 directly — one fused Pallas kernel on TPU, the pure-XLA twin elsewhere —
 and the ``(chunk*maxN, K)`` weight tensor NEVER exists (DESIGN.md §4).
@@ -78,11 +78,11 @@ def _chunk_weights(theta_c, phi, docs_c):
 
 
 def _chunk_plan(B: int, K: int, method: str, W, dtype: str) -> sampling.SamplerPlan:
-    """Plan a (B, K) chunk draw over the *factored* candidate set — the
-    gibbs workload always arrives as a theta-phi product, so autotune may
-    pick the fused ``lda_kernel`` path."""
+    """Plan a (B, K) chunk draw as a *factored* workload — the gibbs
+    workload always arrives as a theta-phi product, so ``auto`` picks the
+    fused ``lda_kernel`` path."""
     # gumbel consumes the PRNG key directly; every other strategy draws
-    # from key-derived uniforms, so auto resolves over the u-capable set
+    # from key-derived uniforms
     has_key = method in ("gumbel", "alias")
     return sampling.plan(
         (B, K), method=method, W=W, dtype=dtype, has_key=has_key, factored=True
@@ -304,7 +304,7 @@ def gibbs_step(
     W: int = None,
     chunk: int = 256,
     dists: Optional[Dict[int, sampling.Categorical]] = None,
-    sparse=False,
+    sparse: bool = False,
     sparse_cache=None,
     mh_steps: int = 2,
     word_proposal: str = "cdf",
@@ -320,8 +320,7 @@ def gibbs_step(
     ``sparse=True`` routes the sweep through ``repro.lda.sparse`` — the
     sparsity-aware MH-alias z-draw whose per-token cost is sublinear in K
     (same ``LDAState`` in/out, exact same target distribution).
-    ``sparse="auto"`` asks the autotuner to arbitrate dense vs sparse for
-    this (tokens, K) bucket.  Pass the same ``sparse_cache=``
+    Pass the same ``sparse_cache=``
     (a ``repro.lda.sparse.SparseSweepCache``) on every call so the
     fixed-width sparse doc-topic counts persist across sweeps;
     ``mh_steps``/``word_proposal`` tune the MH chain (see
@@ -333,20 +332,10 @@ def gibbs_step(
     if sparse:
         from repro.lda import sparse as _sparse
 
-        use_sparse = True
-        if sparse == "auto":
-            from repro import autotune
-
-            meth, _ = autotune.resolve(
-                int(corpus.total_words), state.theta.shape[-1],
-                factored=True, sparse=True,
-            )
-            use_sparse = meth in autotune.SPARSE_METHODS
-        if use_sparse:
-            return _sparse.gibbs_step_sparse(
-                state, corpus, alpha=alpha, beta=beta, mh_steps=mh_steps,
-                word_proposal=word_proposal, cache=sparse_cache, chunk=chunk,
-            )
+        return _sparse.gibbs_step_sparse(
+            state, corpus, alpha=alpha, beta=beta, mh_steps=mh_steps,
+            word_proposal=word_proposal, cache=sparse_cache, chunk=chunk,
+        )
     K = state.theta.shape[-1]
     V = state.phi.shape[0]
     with obs.span("lda.sweep", index=obs.count("lda.sweeps") - 1):

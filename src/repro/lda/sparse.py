@@ -39,7 +39,7 @@ the sweep boundary and reused across every token:
 
 * ``word_proposal="alias"`` — exact Vose tables via the row-vectorized
   host builder (``core.alias.build_alias_tables_host``), memoized in the
-  ``autotune.tables`` LRU cache keyed by phi's content digest, so
+  ``sampling.tables`` LRU cache keyed by phi's content digest, so
   repeated draws against a frozen phi never rebuild.  O(1) per proposal.
 * ``word_proposal="cdf"`` — per-word inclusive partial sums (one cumsum,
   O(VK) build, always cheap) walked by a butterfly-style dyadic descent:
@@ -50,11 +50,6 @@ the sweep boundary and reused across every token:
   resample phi every sweep rebuild in-graph at parallel-sort cost instead
   of the host's serial Vose walk (and the distributed sweep can build it
   inside ``shard_map``, which the host LRU path never could).
-* ``word_proposal="auto"`` — arbitrate ``alias_device`` vs ``cdf`` by the
-  cost model's draws-per-refresh amortization: the device build wins once
-  enough proposals are drawn per phi refresh to amortize its sort passes,
-  the descent wins for refresh-heavy/draw-light sweeps
-  (:func:`resolve_word_proposal`).
 
 The sweep never materializes a (tokens, K) tensor: every per-token
 quantity is a scalar gather or a (chunk, L, cap) compare
@@ -78,7 +73,7 @@ from repro.kernels import rng as _rng
 from repro.lda.corpus import Corpus
 from repro.lda.gibbs import LDAState, _update_phi, _update_theta
 
-WORD_PROPOSALS = ("alias", "alias_device", "cdf", "auto")
+WORD_PROPOSALS = ("alias", "alias_device", "cdf")
 
 DEFAULT_CAP_MIN = 8
 DEFAULT_CAP_MAX = 64
@@ -353,72 +348,22 @@ def _mh_sweep_jit(steps: int, cap: int, mode: str, chunk: int) -> Callable:
 # ---------------------------------------------------------------------------
 
 
-def resolve_word_proposal(
-    mode: str,
-    K: int,
-    V: int,
-    tokens: Optional[int] = None,
-    backend: Optional[str] = None,
-) -> str:
-    """Resolve ``word_proposal="auto"`` to a concrete mode.
-
-    The arbitration is draws-per-refresh amortization: ``tokens``
-    proposals (token count x mh_steps) are drawn against ``V`` per-word
-    tables before phi refreshes, so each table amortizes its build over
-    ``d = tokens / V`` draws.  The device alias build (O(1) draws) wins
-    once ``d`` covers its build passes; the cdf descent (one-cumsum
-    build, O(log K) hot gathers per draw) wins for refresh-heavy /
-    draw-light sweeps.  Unknown ``tokens`` resolves to ``cdf`` — the
-    conservative always-cheap-build choice.
-
-    On CPU the crossover is calibrated from measurement (fig3_lda at
-    K=2048, BENCH_lda.json): the gather-bound device build costs
-    ~``K * log2K * 0.055us`` per phi row against the cdf cumsum's
-    ~``K * 0.013us``, and each alias proposal saves ~``0.025us`` per
-    descent level — break-even near ``d ~ 2K``.  Accelerator backends
-    use the cost model's effective-bytes terms (the build's bisection
-    passes stream at HBM rate there, so the crossover sits orders of
-    magnitude lower)."""
-    if mode != "auto":
-        return mode
-    if not tokens:
-        return "cdf"
-    import math
-
-    from repro.autotune import cost_model as _cm
-
-    if backend is None:
-        backend = jax.default_backend()
-    d = max(1, int(tokens) // max(int(V), 1))
-    lg = math.log2(max(K, 2))
-    if backend == "cpu":
-        build_gap_us = K * (lg * 0.055 - 0.013)
-        save_us = 0.025 * lg
-        return "alias_device" if d * save_us > build_gap_us else "cdf"
-    dev = _cm.method_cost_eq("alias_device", K, draws=d, backend=backend)
-    c = 4.0  # float32 tables
-    cdf = 2.0 * K * c / d + (lg * _cm.SPARSE_DESCENT_LINE * _cm.LINE_EQ)
-    return "alias_device" if dev < cdf else "cdf"
-
-
 def word_proposal_tables(
     phi, mode: str, dist_key: str = "lda_sparse_phi"
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """(tbl_a, tbl_b) for the word proposal, built once per (phi, mode).
 
     ``alias``: exact Vose (prob, alias) via the *host* builder through
-    the autotune LRU table cache keyed by phi's content digest — a frozen
+    the ``sampling.tables`` LRU cache keyed by phi's content digest — a frozen
     phi (posterior draws, repeated ``draw_z_sparse``) never rebuilds.
     ``alias_device``: the split-based device build — a closed jaxpr, so
     it works on tracer phi (inside jit / shard_map) and rebuilds a
     per-sweep phi at parallel-sort cost; concrete phi goes through the
     same digest-keyed LRU so frozen-phi callers still skip the build.
     ``cdf``: per-word inclusive partial sums (tbl_b is a dummy scalar —
-    static shapes keep the jit cache small).  ``auto`` must be resolved
-    by :func:`resolve_word_proposal` before calling (table shape depends
-    on the concrete mode)."""
+    static shapes keep the jit cache small)."""
     if mode in ("alias", "alias_device"):
-        from repro.autotune.tables import get_table_cache
+        from repro.sampling.tables import get_table_cache
 
         kind = "alias_host" if mode == "alias" else "alias_device"
         table = get_table_cache().get_or_build(dist_key, kind, phi)
@@ -470,9 +415,6 @@ def draw_z_sparse(
         doc_topic, _ = _counts_scatter(docs=docs, mask=mask, z=state.z, K=K, V=V)
         cache.update_capacity(_host_int(_nnz_max(doc_topic)))
         cache.counts = sparse_counts(doc_topic, min(cache.cap, K))
-    word_proposal = resolve_word_proposal(
-        word_proposal, K, V, tokens=_host_int(jnp.sum(mask > 0)) * mh_steps
-    )
     tbl_a, tbl_b = word_proposal_tables(state.phi, word_proposal)
     seed = _rng.fold(_rng.seed_from_key(state.key), _rng.TAG_SPARSE_MH)
     z, wa, da, props = _mh_sweep_jit(
@@ -518,13 +460,11 @@ def gibbs_step_sparse(
     per-sweep *serial* alias construction; ``"alias"`` remains the right
     choice for frozen-phi posterior draws via :func:`draw_z_sparse`.
     ``"alias_device"`` rebuilds alias tables in-graph at parallel-sort
-    cost — O(1) word proposals even though phi changes every sweep — and
-    ``"auto"`` lets the cost model pick per workload (token-heavy sweeps
-    amortize the device build; see :func:`resolve_word_proposal`).
+    cost — O(1) word proposals even though phi changes every sweep.
 
     The host call is an ``lda.sweep`` span (``repro.obs``) as in the dense
     ``gibbs_step``; its ``lda.sync`` children are the host readbacks that
-    size the proposal tables and the next sweep's capacity bucket."""
+    size the next sweep's capacity bucket."""
     K = state.theta.shape[-1]
     V = state.phi.shape[0]
     if cache is None:
@@ -542,9 +482,6 @@ def gibbs_step_sparse(
             cache.update_capacity(_host_int(nnz))
             with obs.span("lda.dispatch"):
                 cache.counts = sparse_counts(doc_topic, min(cache.cap, K))
-        word_proposal = resolve_word_proposal(
-            word_proposal, K, V, tokens=_host_int(jnp.sum(mask > 0)) * mh_steps
-        )
         with obs.span("lda.dispatch"):
             tbl_a, tbl_b = word_proposal_tables(state.phi, word_proposal)
             kz, k_theta, k_phi, k_next = jax.random.split(state.key, 4)
@@ -620,7 +557,6 @@ class StreamingSparseLDA:
         self._z_packed: List[Optional[np.ndarray]] = [None] * source.num_shards
         self.sweeps_done = 0
         self.last_ll = None
-        self._last_tokens: Optional[int] = None  # feeds "auto" resolution
 
     def _shard_z(self, i: int, mask: np.ndarray, key) -> jnp.ndarray:
         z = np.zeros(mask.shape, np.int32)
@@ -637,14 +573,7 @@ class StreamingSparseLDA:
         """One full pass over every shard; returns throughput stats."""
         t0 = time.perf_counter()
         kz, k_theta, k_phi, k_init, self.key = jax.random.split(self.key, 5)
-        # "auto" arbitrates from the previous sweep's token count (the
-        # first sweep conservatively takes the cheap-build cdf descent)
-        mode = resolve_word_proposal(
-            self.word_proposal, self.K, self.V,
-            tokens=None if self._last_tokens is None
-            else self._last_tokens * self.mh_steps,
-        )
-        tbl_a, tbl_b = word_proposal_tables(self.phi, mode)
+        tbl_a, tbl_b = word_proposal_tables(self.phi, self.word_proposal)
         seed = _rng.fold(_rng.seed_from_key(kz), _rng.TAG_SPARSE_MH)
         wt = jnp.zeros((self.V, self.K), jnp.float32)
         ll = jnp.float32(0.0)
@@ -662,7 +591,8 @@ class StreamingSparseLDA:
             sp = sparse_counts(doc_topic, self.cap)
             row0 = i * docs.shape[0]
             z, a_w, a_d, p = _mh_sweep_jit(
-                self.mh_steps, min(self.cap, self.K), mode, self.chunk,
+                self.mh_steps, min(self.cap, self.K), self.word_proposal,
+                self.chunk,
             )(
                 z, docs, mask, theta, self.phi, sp.ids, sp.cnt,
                 tbl_a, tbl_b, seed, jnp.uint32(row0), jnp.float32(self.alpha),
@@ -684,7 +614,6 @@ class StreamingSparseLDA:
         jax.block_until_ready(self.phi)
         dt = time.perf_counter() - t0
         self.sweeps_done += 1
-        self._last_tokens = tokens
         self.last_ll = float(ll)
         return {
             "tokens": tokens,
@@ -700,53 +629,3 @@ class StreamingSparseLDA:
 def _shard_ll(theta, phi, docs, mask):
     p = jnp.einsum("mk,mnk->mn", theta, phi[docs])
     return jnp.where(mask > 0, jnp.log(jnp.maximum(p, 1e-30)), 0.0).sum()
-
-
-# ---------------------------------------------------------------------------
-# Tuner measurement hook (the sparse_mh autotune candidate)
-# ---------------------------------------------------------------------------
-
-
-def measure_sparse_mh(
-    B: int,
-    K: int,
-    iters: int = 3,
-    warmup: int = 1,
-    seed: int = 0,
-    steps: int = 2,
-    cap: int = 32,
-) -> float:
-    """Median wall-clock microseconds of a ``B``-token sparse MH draw at
-    ``K`` topics on synthetic sparse data — what measure-mode autotune
-    times for the ``sparse_mh`` candidate (cdf word proposal: the
-    in-training table the arbitration concerns)."""
-    L = 16
-    M = max(1, B // L)
-    V = 256
-    cap = min(cap, K)
-    rng = np.random.default_rng(seed)
-    key = jax.random.PRNGKey(seed)
-    theta = jax.random.dirichlet(key, jnp.full(K, 0.05), (M,))
-    phi = jax.random.dirichlet(
-        jax.random.fold_in(key, 1), jnp.full(V, 0.1), (K,)
-    ).T
-    docs = jnp.asarray(rng.integers(0, V, size=(M, L)), jnp.int32)
-    mask = jnp.ones((M, L), bool)
-    z = jnp.asarray(rng.integers(0, K, size=(M, L)), jnp.int32)
-    doc_topic, _ = _counts_scatter(z, docs, mask, K, V)
-    sp = sparse_counts(doc_topic, cap)
-    tbl_a, tbl_b = word_proposal_tables(phi, "cdf")
-    s = _rng.fold(_rng.seed_from_key(key), _rng.TAG_SPARSE_MH)
-    fn = _mh_sweep_jit(steps, cap, "cdf", min(256, M))
-    args = (
-        z, docs, mask, theta, phi, sp.ids, sp.cnt, tbl_a, tbl_b, s,
-        jnp.uint32(0), jnp.float32(0.1),
-    )
-    for _ in range(max(warmup, 1)):
-        jax.block_until_ready(fn(*args))
-    times = []
-    for _ in range(max(iters, 1)):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn(*args))
-        times.append(time.perf_counter() - t0)
-    return float(np.median(times) * 1e6)

@@ -11,7 +11,7 @@ first-class API:
   through ``jit``/``vmap``/shardings freely, refresh it with
   ``dist.refreshed(new_weights)`` when the weights change.
 * :class:`SamplerPlan` — the compiled side, from :func:`plan`, which
-  resolves ``repro.autotune`` once at plan time and exposes jitted
+  resolves the method once at plan time and exposes jitted
   ``build`` / ``draw`` / ``sample`` / ``sample_logits``.
 
 ``repro.core.sample_categorical`` / ``sample_from_logits`` remain as

@@ -227,9 +227,9 @@ class Categorical:
         """Build a distribution from (B, K) non-negative weights.
 
         ``method="auto"`` resolves through a memoized
-        :func:`repro.sampling.plan` (autotune consulted once per
+        :func:`repro.sampling.plan` (its rule runs once per
         (shape, dtype, backend)); a concrete method skips resolution.
-        ``W=None``/0 picks the cost model's W ~ sqrt(K).
+        ``W=None``/0 picks W ~ sqrt(K) (``kernels.runtime.default_w``).
         """
         weights = jnp.asarray(weights)
         if weights.ndim != 2:
@@ -260,7 +260,7 @@ class Categorical:
 
         The softmax runs in the logits' own floating dtype — ``bfloat16``
         logits stay ``bfloat16`` through ``exp`` (halving HBM traffic) and
-        autotune sees the real dtype; individual builders upcast later
+        the plan records the real dtype; individual builders upcast later
         where accumulation accuracy requires it.
 
         ``transforms`` is a truncation chain from

@@ -1,15 +1,18 @@
 """``SamplerPlan`` — the compiled side of the distribution-object API.
 
-``plan(spec_or_shape, method="auto", ...)`` resolves ``repro.autotune``
-**once at plan time** — never per draw call — and returns a hashable,
-frozen :class:`SamplerPlan` whose ``build``/``draw``/``sample`` methods
-route through the jitted kernels in :mod:`repro.sampling.distribution`.
+``plan(spec_or_shape, method="auto", ...)`` resolves the method **once
+at plan time** — never per draw call — and returns a hashable, frozen
+:class:`SamplerPlan` whose ``build``/``draw``/``sample`` methods route
+through the jitted kernels in :mod:`repro.sampling.distribution`.
+``"auto"`` is turned into a method by one rule, :func:`_resolve_auto`,
+from what the call observes (backend, truncation, factored weights,
+draws per distribution); it reads no environment variable and no file.
 Plans are memoized per (shape, dtype, requested method/W, draws, has_key,
 backend, device topology): re-planning the same workload is a dictionary
-hit, the autotune resolve counter (:func:`plan_stats`) proves the
-resolution count stays at one per distinct workload, and two topologies
-never share a plan (a mesh signature joins the key — see
-``plan(mesh=...)`` for the sharded path).
+hit, the resolve counter (:func:`plan_stats`) proves the resolution
+count stays at one per distinct workload, and two topologies never share
+a plan (a mesh signature joins the key — see ``plan(mesh=...)`` for the
+sharded path).
 
 Typical serving wiring (what ``repro.serve.engine`` does)::
 
@@ -35,14 +38,15 @@ from typing import Dict, Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import runtime
 from repro.sampling import distribution as _dist
 from repro.sampling.distribution import Categorical, KEY_VARIANTS
 
-# resolved-plan memo + counters.  "autotune_resolves" counts actual
-# tuner consultations; "plan_hits" counts memoized returns.
+# resolved-plan memo + counters.  "auto_resolves" counts how often the
+# rule ran; "plan_hits" counts memoized returns.
 _PLAN_CACHE: Dict[Tuple, "SamplerPlan"] = {}
 _PLAN_LOCK = threading.Lock()
-_STATS = {"autotune_resolves": 0, "plan_hits": 0, "plan_misses": 0}
+_STATS = {"auto_resolves": 0, "plan_hits": 0, "plan_misses": 0}
 
 
 def plan_stats() -> dict:
@@ -136,7 +140,7 @@ class SamplerPlan:
         block-sum table straight from the factors; any other resolved
         method materializes the per-sample weights first (one fused XLA
         product) and builds normally, so callers can use this entry point
-        uniformly and let autotune decide whether the sweep fuses.
+        uniformly whatever method the plan holds.
         """
         if self.mesh is not None:
             raise ValueError(
@@ -324,6 +328,28 @@ def _normalize_shape(spec_or_shape, shape) -> Tuple[int, int]:
     )
 
 
+def _resolve_auto(
+    backend: str, *, factored: bool, transforms: str, draws: int
+) -> str:
+    """The one rule behind ``method="auto"``.
+
+    A truncated draw takes the fused truncated kernel, a factored draw
+    the fused factored kernel, a table drawn from many times Fenwick's
+    cached table, and any other draw the fused kernel.  The Pallas
+    kernels run only where they compile natively; elsewhere the flat
+    draws take their pure-XLA twin, ``two_level``, and ``lda_kernel``
+    its own.  Every answer is an exact draw from the same distribution.
+    """
+    native = not runtime.default_interpret(backend)
+    if transforms:
+        return "kernel_trunc" if native else "two_level"
+    if factored:
+        return "lda_kernel"
+    if draws > 1:
+        return "fenwick"
+    return "kernel" if native else "two_level"
+
+
 def plan(
     spec_or_shape,
     method: Optional[str] = None,
@@ -346,28 +372,27 @@ def plan(
     taken from it), or a ``repro.configs.base.SamplerSpec`` (method/W/draws
     are taken from it; pass the workload via ``shape=``).
 
-    ``method="auto"`` (the default) consults ``repro.autotune`` — tuning
-    cache first, cost model on a miss — exactly once per distinct
-    (shape, dtype, draws, has_key, backend, topology): results are
-    memoized process-wide, and draw calls made through the returned plan
-    never re-resolve.  ``W`` falsy means "pick for me" (tuned W under
-    auto, W ~ sqrt(K) otherwise).
+    ``method="auto"`` (the default) is resolved by :func:`_resolve_auto`
+    exactly once per distinct (shape, dtype, draws, has_key, backend,
+    topology): results are memoized process-wide, and draw calls made
+    through the returned plan never re-resolve.  ``W`` falsy means
+    ``runtime.default_w(K)``; the tiles are ``runtime.default_tb`` of the
+    per-shard rows and ``runtime.default_tk(K, W)``.
 
     ``mesh=`` makes the plan *sharded*: (B, K) is the global workload,
     rows shard over the mesh's data axes (``spec=`` overrides the row
-    PartitionSpec), autotune resolves the **per-shard** (B/dev, K) shape,
-    and the topology signature joins the memo key and the tuning-cache
-    bucket — a plan resolved for one topology is never silently reused
-    for another.  ``devices=`` (without a mesh) tags the tuning bucket
-    for callers that are *already* per-shard, e.g. inside a shard_map
-    body (the shape is then NOT divided further).
+    PartitionSpec), the tiles are sized for the **per-shard** (B/dev, K)
+    shape, and the topology signature joins the memo key — a plan made
+    for one topology is never silently reused for another.
+    ``devices=`` (without a mesh) marks callers that are *already*
+    per-shard, e.g. inside a shard_map body (the shape is then NOT
+    divided further).
 
     ``transforms=`` declares a truncation workload: a chain (or its
     :func:`repro.sampling.transforms.signature` string, e.g. ``"kpm"``)
-    joins the memo key and the autotune v4 bucket — truncated decode
-    tunes separately (the fused ``kernel_trunc`` strategy becomes a
-    candidate) but parameter *values* stay out of the key, so per-request
-    p/k share one plan and one executable.
+    joins the memo key and lets ``auto`` pick the fused ``kernel_trunc``
+    draw, but parameter *values* stay out of the key, so per-request p/k
+    share one plan and one executable.
     """
     # unpack a SamplerSpec-shaped object (duck-typed: configs may not be
     # importable in every context this runs)
@@ -405,7 +430,7 @@ def plan(
                 f"devices={devices} contradicts the mesh's {nd} data shards"
             )
         devices = nd
-        B_res = B // nd          # autotune sees the per-shard workload
+        B_res = B // nd          # the tiles are sized per shard
         mesh_sig = _sharded.mesh_signature(mesh, spec)
     else:
         if spec is not None:
@@ -426,37 +451,25 @@ def plan(
             return hit
         _STATS["plan_misses"] += 1
 
-    resolved, resolved_w = method, W
-    tuned_tb = tuned_tk = 0
+    resolved = method
     if method == "auto":
-        from repro import autotune
-
         with _PLAN_LOCK:
-            _STATS["autotune_resolves"] += 1
-        res = autotune.get_tuner().resolve_full(
-            B_res, K, draws=draws, dtype_name=dtype_name, has_key=has_key,
-            factored=factored, devices=devices, transforms=transforms,
+            _STATS["auto_resolves"] += 1
+        resolved = _resolve_auto(
+            backend, factored=factored, transforms=transforms, draws=draws
         )
-        resolved = res.method
-        resolved_w = W or res.W
-        tuned_tb, tuned_tk = res.tb, res.tk
-    from repro.autotune import cost_model as _cm
-
-    if not resolved_w:
-        resolved_w = _cm.default_w(K)
-    if not (tuned_tb and tuned_tk):
-        tuned_tb, tuned_tk = _cm.default_tiles(B_res, K, int(resolved_w))
+    W = int(W or runtime.default_w(K))
 
     p = SamplerPlan(
         method=resolved,
-        W=int(resolved_w),
+        W=W,
         shape=(B, K),
         dtype=dtype_name,
         draws=int(draws),
         has_key=bool(has_key),
         backend=backend,
-        tb=int(tuned_tb),
-        tk=int(tuned_tk),
+        tb=runtime.default_tb(B_res),
+        tk=runtime.default_tk(K, W),
         factored=bool(factored),
         mesh=mesh,
         spec=spec,

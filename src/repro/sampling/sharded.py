@@ -23,7 +23,7 @@ so draws are bit-identical for 1, 2, or 8 devices — resharding a serving
 fleet never changes sampled tokens for a fixed key.
 
 Entry points are consumed through :class:`repro.sampling.SamplerPlan`:
-``plan(..., mesh=mesh, spec=...)`` resolves autotune for the *per-shard*
+``plan(..., mesh=mesh, spec=...)`` sizes its tiles for the *per-shard*
 (B/dev, K) workload and routes ``build``/``draw``/``sample``/
 ``sample_logits`` here.
 """

@@ -1,4 +1,4 @@
-"""Reusable distribution cache (autotune layer 3).
+"""Reusable distribution cache behind ``dist_key=``.
 
 Alias and Fenwick state are pure functions of the weight matrix — when
 the same distributions are drawn from repeatedly (a static unigram vocab
@@ -8,9 +8,7 @@ this module is a thin wrapper over :mod:`repro.sampling`: it memoizes
 built :class:`~repro.sampling.Categorical` pytrees (and, through the
 legacy :meth:`TableCache.get_or_build`, their raw table leaves) for the
 ``dist_key=`` path of the ``sample_categorical`` shim.  The cached kinds
-are exactly the ones whose state the shim reuses across calls
-(``cost_model.CACHED_TABLE_METHODS`` stays in sync — amortized build cost
-must mean actual reuse).
+are exactly the ones whose state the shim reuses across calls.
 
 Staleness: entries are keyed by a cheap **content digest** of the weights
 (shape/dtype plus two O(BK) device-side reductions — see

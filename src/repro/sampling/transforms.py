@@ -141,10 +141,9 @@ def chain(
 
 def signature(transforms: Optional[Sequence]) -> str:
     """Static signature of a chain — the transform *types* in order,
-    independent of parameter values.  Joins plan memo keys and the
-    autotune v4 bucket key (``|tr:kpm``): workloads that truncate tune
-    separately from ones that don't, but two different ``p`` values share
-    one bucket and one compiled executable."""
+    independent of parameter values.  Joins plan memo keys: workloads
+    that truncate plan separately from ones that don't, but two different
+    ``p`` values share one plan and one compiled executable."""
     if not transforms:
         return ""
     return "".join(_SIG_LETTER[type(t)] for t in transforms)

@@ -186,23 +186,14 @@ class ContinuousBatchingEngine:
         """A u-driven sampler plan for the (B, V) decode workload.
 
         The per-slot RNG streams hand the draw an explicit (B,) uniform
-        vector, so key-driven variants (gumbel / alias) can't serve here;
-        autotune resolutions landing on one fall back to butterfly."""
+        vector, so the plan is made key-less."""
         spec = self.model.cfg.sampler_spec
 
         def uplan(shape, devices=1):
-            p = sampling.plan(
+            return sampling.plan(
                 shape, method=spec.method, W=spec.W or None, dtype="float32",
                 draws=1, has_key=False, devices=devices,
             )
-            if p.method in _dist.KEY_VARIANTS or (
-                p.table_method in _dist.FACTORED_VARIANTS
-            ):
-                p = sampling.plan(
-                    shape, method="butterfly", W=spec.W or None,
-                    dtype="float32", draws=1, has_key=False, devices=devices,
-                )
-            return p
 
         if self.mesh is None:
             return uplan((B, V)), None

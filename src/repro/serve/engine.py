@@ -6,7 +6,7 @@ once) — the decode step's sampler is the paper's technique as a first-class
 serving feature.  Since the distribution-object redesign the engine builds
 a :class:`repro.sampling.SamplerPlan` in ``make_decode_step`` /
 ``make_serve_step`` / ``make_prefill_step`` — ``ModelConfig.sampler_spec``
-(a ``SamplerSpec``) is resolved through ``repro.autotune`` **once per
+(a ``SamplerSpec``) is resolved by ``sampling.plan``'s rule **once per
 (B, vocab) workload at plan time**, not re-dispatched from strings on
 every step; the jitted step then draws through the plan's compiled path.
 
@@ -80,7 +80,7 @@ jax.tree_util.register_pytree_node(
 def _sp_sig(sp: Optional["SamplingParams"]) -> str:
     """The transforms signature a SamplingParams default actually runs
     (statically-disabled stages are dropped by ``transforms.chain``), for
-    the plan memo key and autotune v4 bucket."""
+    the plan memo key."""
     if sp is None:
         return ""
     from repro.sampling import transforms as _tr
@@ -104,7 +104,7 @@ def _logits_plan(cfg: ModelConfig, B: int, V: int, dtype_name: str,
                  draws: int = 1, mesh=None, transforms: str = ""):
     """The config's sampler spec, planned for a (B, V) logits workload.
 
-    ``sampling.plan`` memoizes process-wide, so this resolves autotune on
+    ``sampling.plan`` memoizes process-wide, so this resolves the method on
     the first (shape, dtype) sighting and is a dictionary hit after —
     whether called eagerly (known batch size) or at trace time.
     ``draws`` is the per-distribution reuse hint (multi-draw decode).
@@ -112,7 +112,7 @@ def _logits_plan(cfg: ModelConfig, B: int, V: int, dtype_name: str,
     data axes and the sampler runs per shard (the topology is part of the
     plan memo key, so one engine can serve several meshes).
     ``transforms`` is the truncation-chain signature for truncated decode
-    (joins the autotune v4 bucket; parameter values stay out)."""
+    (joins the plan memo key; parameter values stay out)."""
     spec = cfg.sampler_spec
     return sampling.plan(
         (B, V), method=spec.method, W=spec.W or None, dtype=dtype_name,
@@ -131,8 +131,8 @@ def _chain_for(sp: "SamplingParams", sig: str):
     call's (possibly traced) parameter leaves.  Rebuilding the chain from
     traced leaves via ``sp.transforms()`` would resurrect statically
     dropped stages (a tracer is never "statically disabled"); selecting
-    stages by the signature keeps the executable, the plan's memo key,
-    and the autotune bucket mutually consistent."""
+    stages by the signature keeps the executable and the plan's memo key
+    mutually consistent."""
     from repro.sampling import transforms as _tr
 
     out = []
@@ -157,7 +157,7 @@ def make_decode_step(
     -> (next_token(s), logits, caches).
 
     When ``batch_size`` is known up front the sampler plan is built (and
-    autotune resolved) eagerly, before the first trace; otherwise planning
+    its method resolved) eagerly, before the first trace; otherwise planning
     happens at trace time on first use and is memoized after.
 
     ``num_samples > 1`` draws that many candidate tokens per sequence from

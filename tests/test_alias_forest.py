@@ -13,15 +13,8 @@ Gates for the two frozen-distribution variants (DESIGN.md §11):
   (dense boundary sweep);
 * the jaxpr gate: an ``alias_device`` refresh is a closed jaxpr — no
   host callback, no ``while_loop`` (the legacy serial builder's
-  signature primitive);
-* autotune arbitration: ``method="auto"`` picks ``alias_device`` for
-  frozen-distribution draw-heavy workloads, falls back to the
-  butterfly-family at small K / draws=1, and never hands a key-driven
-  method to a u-based caller;
-* the v6 tuning-cache schema round-trips v5 files.
+  signature primitive).
 """
-
-import json
 
 import numpy as np
 import jax
@@ -236,89 +229,6 @@ def test_radix_refresh_is_closed_jaxpr():
 
 
 # ---------------------------------------------------------------------------
-# Autotune arbitration + registry + cache schema
-# ---------------------------------------------------------------------------
-
-def test_registry_lists_new_strategies():
-    from repro import kernels
-
-    cands = kernels.candidates(256, 2048, "cpu")
-    assert "alias_device" in cands
-    assert "radix_forest" in cands
-
-
-def test_auto_arbitration_gating():
-    """Frozen-distribution draw-heavy workloads resolve to alias_device;
-    small-K one-shot workloads keep the butterfly-family winner; u-based
-    callers never receive a key-driven method."""
-    from repro.autotune import tuner as _tuner
-
-    t = _tuner.Tuner(mode="off", backend="cpu")
-    m, _ = t.resolve(256, 2048, draws=64)
-    assert m == "alias_device"
-    m, _ = t.resolve(256, 4096, draws=128)
-    assert m == "alias_device"
-
-    m_small, _ = t.resolve(256, 64, draws=1)
-    assert m_small in ("butterfly", "fenwick", "two_level", "kernel",
-                       "prefix"), m_small
-
-    m_u, _ = t.resolve(256, 2048, draws=64, has_key=False)
-    assert m_u not in _tuner.KEY_METHODS, m_u
-    assert "alias_device" not in _tuner.candidate_methods(
-        256, 2048, "cpu", has_key=False
-    )
-
-
-def test_cost_model_knows_new_methods():
-    from repro.autotune import cost_model as cm
-
-    for method in ("alias_device", "radix_forest"):
-        one = cm.method_cost_eq(method, 1024, draws=1, backend="cpu")
-        many = cm.method_cost_eq(method, 1024, draws=64, backend="cpu")
-        assert many < one  # build amortizes over draws-per-refresh
-        # monotone in K (the model-wide invariant)
-        assert cm.method_cost_eq(method, 2048) >= cm.method_cost_eq(
-            method, 1024
-        )
-    # the amortization whitelist stays in sync with the api's cached kinds
-    from repro.core.api import _CACHED_KINDS
-
-    assert set(_CACHED_KINDS) == set(cm.CACHED_TABLE_METHODS)
-
-
-def test_cache_v6_round_trips_v5(tmp_path):
-    from repro.autotune.cache import (
-        COMPAT_SCHEMAS, SCHEMA, TuningCache, bucket_key,
-    )
-
-    assert SCHEMA == "repro-autotune-v6"
-    assert "repro-autotune-v5" in COMPAT_SCHEMAS
-
-    k5 = bucket_key("cpu", 256, 2048, 64, "float32", sparse=True)
-    v5 = {
-        "schema": "repro-autotune-v5",
-        "entries": {
-            k5: {"method": "sparse_mh", "W": 32, "us": 10.0,
-                 "source": "measured", "tb": 8, "tk": 512},
-        },
-    }
-    p = tmp_path / "v5.json"
-    p.write_text(json.dumps(v5))
-    c = TuningCache(path=str(p))
-    assert len(c) == 1  # v5 file reads under the v6 schema
-    k6 = bucket_key("cpu", 256, 4096, 128, "float32")
-    c.put(k6, "alias_device", 64, 5.0, source="measured", tb=8, tk=512)
-    out = c.save(str(tmp_path / "v6.json"))
-    blob = json.load(open(out))
-    assert blob["schema"] == "repro-autotune-v6"
-    c2 = TuningCache(path=out)
-    assert len(c2) == 2
-    assert c2.get(k5)["method"] == "sparse_mh"  # v5 winner survives
-    assert c2.get(k6)["method"] == "alias_device"
-
-
-# ---------------------------------------------------------------------------
 # TableCache digest memoization
 # ---------------------------------------------------------------------------
 
@@ -326,7 +236,7 @@ def test_content_digest_memoized_per_instance(monkeypatch):
     """Repeated lookups on the same held matrix skip the reductions; a
     distinct instance (even with equal content) recomputes; changed
     content changes the digest."""
-    from repro.autotune import tables
+    from repro.sampling import tables
 
     w = jnp.asarray(np.random.default_rng(0).uniform(size=(8, 64)),
                     jnp.float32)
@@ -347,14 +257,12 @@ def test_content_digest_memoized_per_instance(monkeypatch):
 
 
 def test_sparse_word_proposal_alias_device_runs():
-    """The in-graph word-proposal mode: same sweep, device-built tables;
-    the auto resolver arbitrates by draws-per-refresh amortization."""
+    """The in-graph word-proposal mode: same sweep, device-built tables."""
     from repro.lda import sparse as sp
     from repro.lda.corpus import synthesize_corpus
     from repro.lda.gibbs import init_state
 
     assert "alias_device" in sp.WORD_PROPOSALS
-    assert "auto" in sp.WORD_PROPOSALS
     corpus = synthesize_corpus(0, M=24, V=64, K=8, avg_len=16, max_len=24)
     st = init_state(jax.random.PRNGKey(1), corpus, K=8)
     cache = sp.SparseSweepCache()
@@ -362,14 +270,3 @@ def test_sparse_word_proposal_alias_device_runs():
         st, corpus, word_proposal="alias_device", cache=cache
     )
     assert int(s2.step) == int(st.step) + 1
-    # arbitration direction: token-heavy amortizes the device build
-    # (CPU break-even near d ~ 2K draws per table), token-light keeps
-    # the cheap cdf build
-    assert sp.resolve_word_proposal(
-        "auto", 2048, 1000, tokens=10_000_000
-    ) == "alias_device"
-    assert sp.resolve_word_proposal("auto", 2048, 1000, tokens=512) == "cdf"
-    assert sp.resolve_word_proposal(
-        "auto", 2048, 1000, tokens=200_000
-    ) == "cdf"  # d=200 << CPU crossover: the build would not amortize
-    assert sp.resolve_word_proposal("cdf", 2048, 1000, tokens=10**7) == "cdf"

@@ -227,7 +227,7 @@ def test_streaming_sweep_improves_perplexity():
 
 
 # ---------------------------------------------------------------------------
-# Integration: gibbs_step(sparse=) and the autotune arbitration
+# Integration: gibbs_step(sparse=)
 # ---------------------------------------------------------------------------
 
 
@@ -240,32 +240,3 @@ def test_gibbs_step_sparse_flag_same_state_shape():
     assert out.phi.shape == state.phi.shape
     assert out.z.shape == state.z.shape
     assert int(out.step) == int(state.step) + 1
-
-
-def test_sparse_mh_candidate_gated_on_sparse_workloads():
-    from repro import kernels
-    from repro.autotune import cost_model
-
-    names = kernels.candidates(4096, 512, "cpu", factored=True)
-    assert "sparse_mh" not in names
-    names = kernels.candidates(4096, 512, "cpu", factored=True, sparse=True)
-    assert "sparse_mh" in names
-    with pytest.raises(ValueError):
-        cost_model.method_cost_eq("sparse_mh", 512, backend="cpu")
-    # sublinear in K: cost grows by far less than 2x when K doubles
-    c1 = cost_model.method_cost_eq("sparse_mh", 512, backend="cpu", sparse=True)
-    c2 = cost_model.method_cost_eq("sparse_mh", 1024, backend="cpu", sparse=True)
-    assert c1 < c2 < 1.5 * c1
-
-
-def test_sparse_bucket_key_isolated():
-    from repro.autotune import cache as atcache
-
-    k_dense = atcache.bucket_key(
-        "cpu", 4096, 512, 1, "float32", factored=True
-    )
-    k_sparse = atcache.bucket_key(
-        "cpu", 4096, 512, 1, "float32", factored=True, sparse=True
-    )
-    assert k_dense != k_sparse
-    assert k_sparse.endswith("|sp")

@@ -17,7 +17,7 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 
-from repro.configs import get_config
+from repro.configs import ARCH_IDS, get_config
 from repro.configs.base import ModelConfig, SamplerSpec
 from repro.models.model import build_model
 from repro.models.params import init_params
@@ -219,6 +219,32 @@ def test_rejects_non_decoder_configs():
     params = init_params(jax.random.PRNGKey(0), model.specs, jnp.float32)
     with pytest.raises(ValueError, match="decoder-only"):
         ContinuousBatchingEngine(model, params, max_slots=2)
+
+
+# -- the decode draw's plan --------------------------------------------------
+
+def _engine_serves(cfg):
+    """The engine refuses encoder, frontend and meta-token configs."""
+    return not (cfg.encoder_layers or cfg.frontend_len or cfg.meta_tokens)
+
+
+ENGINE_ARCHS = [a for a in ARCH_IDS if _engine_serves(get_config(a))]
+
+
+@pytest.mark.parametrize("arch", ENGINE_ARCHS)
+def test_engine_draw_plan_per_config(arch, monkeypatch):
+    """On TPU every config the engine serves draws through the fused
+    kernel, launched with W=128 and (tb, tk) = (8, 512) at its vocabulary."""
+    import types
+
+    cfg = get_config(arch)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    stub = types.SimpleNamespace(model=types.SimpleNamespace(cfg=cfg), mesh=None)
+    plan, local = ContinuousBatchingEngine._resolve_plans(
+        stub, cfg.serve_spec.max_slots, cfg.padded_vocab
+    )
+    assert local is None
+    assert (plan.method, plan.W, plan.tb, plan.tk) == ("kernel", 128, 8, 512)
 
 
 # -- the zero-retrace gate ---------------------------------------------------

@@ -317,64 +317,6 @@ class TestShardedPlan:
 
 
 # ---------------------------------------------------------------------------
-# Autotune: v3 topology buckets, v2 back-compat, devices in bench records
-# ---------------------------------------------------------------------------
-
-
-class TestTopologyBuckets:
-    def test_bucket_key_dev_suffix(self):
-        from repro.autotune.cache import bucket_key
-
-        base = bucket_key("cpu", 512, 1024, 1, "float32")
-        dev = bucket_key("cpu", 512, 1024, 1, "float32", devices=8)
-        assert dev == base + "|dev8"
-        assert bucket_key("cpu", 512, 1024, 1, "float32", devices=1) == base
-
-    def test_v2_cache_file_still_loads(self, tmp_path, monkeypatch):
-        from repro import autotune
-        from repro.autotune.cache import TuningCache, bucket_key
-
-        path = str(tmp_path / "autotune.json")
-        monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", path)
-        key = bucket_key("cpu", 256, 1024, 1, "float32", has_key=True)
-        v2 = {
-            "schema": "repro-autotune-v2",
-            "entries": {key: {"method": "two_level", "W": 16, "tb": 8,
-                              "tk": 512, "us": 10.0, "source": "measured"}},
-        }
-        with open(path, "w") as f:
-            json.dump(v2, f)
-        autotune.reset()
-        try:
-            c = TuningCache(path=path)
-            assert len(c) == 1
-            res = autotune.resolve_full(256, 1024)
-            assert (res.method, res.W) == ("two_level", 16)
-            # the same local shape sharded 8-ways is a different bucket:
-            # the v2 winner must not shadow it
-            res8 = autotune.resolve_full(256, 1024, devices=8)
-            assert res8.source == "model"
-        finally:
-            autotune.reset()
-
-    def test_ingest_records_devices_field(self, tmp_path):
-        from repro.autotune.cache import TuningCache, bucket_key
-
-        c = TuningCache(path=str(tmp_path / "c.json"), autoload=False)
-        n = c.ingest_records([
-            {"backend": "cpu", "B": 512, "K": 256, "method": "two_level",
-             "W": 8, "us": 5.0, "devices": 8},
-            {"backend": "cpu", "B": 512, "K": 256, "method": "two_level",
-             "W": 8, "us": 7.0},          # no devices field: dev-1 bucket
-        ])
-        assert n >= 2
-        hit = c.get(bucket_key("cpu", 512, 256, 1, "float32", devices=8))
-        assert hit and hit["us"] == 5.0
-        flat = c.get(bucket_key("cpu", 512, 256, 1, "float32"))
-        assert flat and flat["us"] == 7.0
-
-
-# ---------------------------------------------------------------------------
 # Mesh helpers (the launch satellite)
 # ---------------------------------------------------------------------------
 

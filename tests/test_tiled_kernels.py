@@ -1,9 +1,7 @@
 """Tiled-grid kernel rewrite: equivalence vs the searchsorted oracle across
 W and padding edges, the factored (zero-materialization) path end to end,
-multi-draw determinism, interpret-default routing, and the autotune v2
-tile-parameter records."""
-
-import json
+multi-draw determinism, interpret-default routing, and the tile
+parameters a plan records."""
 
 import numpy as np
 import jax
@@ -427,68 +425,27 @@ class TestInterpretDefaults:
 
 
 # ---------------------------------------------------------------------------
-# Autotune: tb/tk in v2 cache records, v1 backward compatibility
+# The plan carries the kernel's launch tiles
 # ---------------------------------------------------------------------------
 
 
-@pytest.fixture
-def fresh_autotune(tmp_path, monkeypatch):
-    from repro import autotune
-
-    path = str(tmp_path / "autotune.json")
-    monkeypatch.setenv("REPRO_AUTOTUNE_CACHE", path)
-    monkeypatch.delenv("REPRO_AUTOTUNE", raising=False)
-    autotune.reset()
-    yield path
-    autotune.reset()
-
-
 class TestTileParamsInCache:
-    def test_resolve_full_records_tiles(self, fresh_autotune):
-        from repro import autotune
+    def test_resolve_full_records_tiles(self):
+        """An auto plan records the complete launch config: W and the
+        (tb, tk) tiles the tiled kernels take, from kernels.runtime."""
+        from repro.kernels import runtime
 
-        res = autotune.resolve_full(256, 1024)
-        assert res.tb > 0 and res.tk > 0
-        assert res.tk % 1 == 0
-        blob = json.load(open(fresh_autotune))
-        assert blob["schema"] == autotune.SCHEMA == "repro-autotune-v6"
-        (entry,) = blob["entries"].values()
-        assert entry["tb"] == res.tb and entry["tk"] == res.tk
-        # a cache hit restores the full launch config
-        again = autotune.resolve_full(250, 1000)
-        assert again == res or (again.method, again.W, again.tb, again.tk) == (
-            res.method, res.W, res.tb, res.tk
+        p = sampling.plan((256, 1024), method="auto")
+        assert (p.W, p.tb, p.tk) == (
+            runtime.default_w(1024), runtime.default_tb(256),
+            runtime.default_tk(1024, runtime.default_w(1024)),
         )
-
-    def test_v1_cache_file_still_loads(self, fresh_autotune):
-        from repro import autotune
-        from repro.autotune.cache import TuningCache, bucket_key
-
-        key = bucket_key("cpu", 256, 1024, 1, "float32", has_key=True)
-        v1 = {
-            "schema": "repro-autotune-v1",
-            "entries": {key: {"method": "two_level", "W": 16, "us": 10.0,
-                              "source": "measured"}},
-        }
-        with open(fresh_autotune, "w") as f:
-            json.dump(v1, f)
-        autotune.reset()
-        c = TuningCache(path=fresh_autotune)
-        assert len(c) == 1
-        # the tuner honors the v1 winner and backfills default tiles
-        res = autotune.resolve_full(256, 1024)
-        assert (res.method, res.W) == ("two_level", 16)
-        assert res.tb > 0 and res.tk > 0
-
-    def test_factored_bucket_is_separate(self, fresh_autotune):
-        from repro import autotune
-        from repro.autotune.cache import bucket_key
-
-        assert bucket_key("cpu", 8, 8, 1, "f32", factored=True).endswith("|fac")
-        flat = autotune.resolve(512, 512, has_key=False)
-        fac = autotune.resolve(512, 512, has_key=False, factored=True)
-        assert fac[0] == "lda_kernel"
-        assert flat[0] != "lda_kernel"
+        assert (p.W, p.tb, p.tk) == (32, 8, 512)
+        # re-planning the workload restores the same launch config
+        again = sampling.plan((256, 1024), method="auto")
+        assert (again.method, again.W, again.tb, again.tk) == (
+            p.method, p.W, p.tb, p.tk
+        )
 
     def test_plan_carries_tiles(self):
         p = sampling.plan((64, 256), method="two_level", W=8)

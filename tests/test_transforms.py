@@ -475,7 +475,7 @@ def _bench_blob(times: dict) -> dict:
          "dtype": "float32", "method": m, "us": us, "devices": dev}
         for (m, B, K, dev), us in times.items()
     ]
-    return {"schema": "repro-autotune-bench-v1", "records": records}
+    return {"records": records}
 
 
 BASE_TIMES = {
@@ -577,129 +577,22 @@ class TestCheckRegression:
 
 
 # ---------------------------------------------------------------------------
-# Autotune follow-through: v4 cache, truncated candidates, compat reader
+# Truncated decode in the plan: the fused choice and the memo key
 # ---------------------------------------------------------------------------
 
 
 class TestAutotuneV4:
-    def test_bucket_key_transforms_suffix(self):
-        from repro.autotune.cache import bucket_key
-
-        plain = bucket_key("cpu", 64, 4096, 1, "float32")
-        trunc = bucket_key("cpu", 64, 4096, 1, "float32", transforms="kpm")
-        assert trunc == plain + "|tr:kpm"
-        both = bucket_key(
-            "cpu", 64, 4096, 1, "float32", devices=8, transforms="kp"
-        )
-        assert both.endswith("|dev8|tr:kp")
-
-    def test_candidates_expose_truncated_variants(self):
-        from repro import kernels
-
-        assert "kernel_trunc" not in kernels.candidates(64, 4096, "tpu")
-        assert "kernel_trunc" in kernels.candidates(
-            64, 4096, "tpu", truncated=True
-        )
-        # interpret-mode emulation is never a candidate off-TPU
-        assert "kernel_trunc" not in kernels.candidates(
-            64, 4096, "cpu", truncated=True
-        )
-
     def test_tpu_model_prefers_fused_truncated_at_vocab_scale(self):
-        from repro.autotune import cost_model as cm
-        from repro.autotune.tuner import candidate_methods
-
-        cands = candidate_methods(256, 131072, "tpu", True, transforms="kpm")
-        method, W, us = cm.choose(
-            cands, 256, 131072, backend="tpu", truncated=True
+        p = sampling.plan(
+            (256, 131072), method="auto", backend="tpu", has_key=True,
+            transforms="kpm",
         )
-        assert method == "kernel_trunc", (method, us)
-
-    def test_resolve_full_transforms_bucket(self, tmp_path, monkeypatch):
-        from repro.autotune.cache import TuningCache
-        from repro.autotune.tuner import Tuner
-
-        cache = TuningCache(path=str(tmp_path / "c.json"), autoload=False)
-        t = Tuner(cache=cache, mode="model", backend="tpu")
-        plain = t.resolve_full(512, 65536)
-        trunc = t.resolve_full(512, 65536, transforms="kpm")
-        assert trunc.method == "kernel_trunc"
-        assert plain.method != "kernel_trunc"
-        keys = [k for k, _ in cache.items()]
-        assert any(k.endswith("|tr:kpm") for k in keys), keys
-
-    def test_v4_reader_roundtrips_v1_v2_v3(self, tmp_path):
-        """The compat regression gate: v1 (no tiles), v2 (tiles), v3
-        (|dev buckets) files all load into a current-schema cache, and
-        a fresh save re-reads byte-equivalently."""
-        from repro.autotune.cache import SCHEMA, TuningCache, bucket_key
-
-        k_plain = bucket_key("cpu", 256, 1024, 1, "float32")
-        k_dev = bucket_key("cpu", 128, 1024, 1, "float32", devices=8)
-        files = {
-            "v1.json": {
-                "schema": "repro-autotune-v1",
-                "entries": {k_plain: {"method": "two_level", "W": 16,
-                                      "us": 10.0, "source": "measured"}},
-            },
-            "v2.json": {
-                "schema": "repro-autotune-v2",
-                "entries": {k_plain + "X2": {
-                    "method": "fenwick", "W": 32, "tb": 8, "tk": 512,
-                    "us": 12.0, "source": "measured"}},
-            },
-            "v3.json": {
-                "schema": "repro-autotune-v3",
-                "entries": {k_dev: {"method": "kernel", "W": 32, "tb": 16,
-                                    "tk": 512, "us": 8.0,
-                                    "source": "measured"}},
-            },
-        }
-        cache = TuningCache(path=str(tmp_path / "main.json"), autoload=False)
-        for name, blob in files.items():
-            p = tmp_path / name
-            p.write_text(json.dumps(blob))
-            c = TuningCache(path=str(p))
-            assert len(c) == 1, name
-            cache.ingest_records(blob, source="measured")
-        assert len(cache) == 3
-        # v1 entry: no tiles recorded -> resolve falls back to defaults
-        assert cache.get(k_plain)["method"] == "two_level"
-        assert "tb" not in cache.get(k_plain)
-        assert cache.get(k_dev)["tb"] == 16
-        # round-trip through a current-schema save
-        out = cache.save(str(tmp_path / "v6.json"))
-        blob4 = json.loads(open(out).read())
-        assert blob4["schema"] == SCHEMA == "repro-autotune-v6"
-        c4 = TuningCache(path=out)
-        assert len(c4) == 3
-        assert c4.get(k_dev) == cache.get(k_dev)
-        # a wrong-schema file is treated as empty, not raised
-        bad = tmp_path / "bad.json"
-        bad.write_text(json.dumps({"schema": "repro-autotune-v99",
-                                   "entries": {}}))
-        assert TuningCache(path=str(bad)).load() == 0
-
-    def test_bench_records_with_transforms_bucket_separately(self, tmp_path):
-        from repro.autotune.cache import TuningCache, bucket_key
-
-        cache = TuningCache(path=str(tmp_path / "c.json"), autoload=False)
-        n = cache.ingest_records(
-            [
-                {"backend": "tpu", "B": 256, "K": 4096, "method": "kernel",
-                 "W": 64, "us": 50.0},
-                {"backend": "tpu", "B": 256, "K": 4096,
-                 "method": "kernel_trunc", "W": 64, "us": 60.0,
-                 "transforms": "kpm"},
-            ]
+        assert p.method == "kernel_trunc", p
+        # the untruncated workload keeps the plain fused kernel
+        plain = sampling.plan(
+            (256, 131072), method="auto", backend="tpu", has_key=True
         )
-        assert n >= 2
-        plain = cache.get(bucket_key("tpu", 256, 4096, 1, "float32"))
-        trunc = cache.get(
-            bucket_key("tpu", 256, 4096, 1, "float32", transforms="kpm")
-        )
-        assert plain["method"] == "kernel"
-        assert trunc["method"] == "kernel_trunc"
+        assert plain.method == "kernel"
 
     def test_plan_memo_distinguishes_transform_signatures(self):
         sampling.reset_plans()
